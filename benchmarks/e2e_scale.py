@@ -30,9 +30,10 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-_USER_SET_PLATFORM = "JAX_PLATFORMS" in os.environ
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("TM_TABLES_CACHE_DIR", "/tmp/tm_bench_tables")
+
+from tendermint_tpu.utils.jaxenv import scope_tables_cache  # noqa: E402
+
+scope_tables_cache("bench")
 # the consensus nodes must pick the TPU provider, not the conftest CPU pin
 os.environ.pop("TM_CRYPTO_PROVIDER", None)
 
@@ -99,8 +100,10 @@ def main():
 
         # warm the device path out of the timed region, like a node
         # start does: tables + the swarm-drain bucket. Wait for the
-        # warm so the MEASURED window rides the device path, not the
-        # host fallback (start isn't gated on it in a real node).
+        # warm so the MEASURED window rides the device path (start
+        # isn't gated on it in a real node); a warm-up that misses its
+        # deadline fails the run — the host path is not measured in
+        # the device path's name.
         key, all_pk, _ = st.validators.batch_cache()
         prov.register_valset(key, all_pk)
         warm_deadline = time.monotonic() + float(
@@ -114,7 +117,7 @@ def main():
                 break
             await asyncio.sleep(1)
         else:
-            print("warm timeout: measuring host-fallback path", file=sys.stderr)
+            raise SystemExit("warm timeout: the tabled-tpl bucket never became ready")
 
         # DEFAULT timeouts: this is eval 1's deployment shape, so
         # blocks/s includes the real round timers and p2p gossip
@@ -203,12 +206,7 @@ def main():
 
 
 if __name__ == "__main__":
-    if not _USER_SET_PLATFORM:
-        os.environ.pop("JAX_PLATFORMS", None)
-    from tendermint_tpu.utils.jaxenv import force_cpu_platform, probe_accelerator
+    from tendermint_tpu.utils.jaxenv import require_accelerator
 
-    count, platform = probe_accelerator(timeout_s=90)
-    if (count == 0 or platform == "cpu") and not _USER_SET_PLATFORM:
-        print("accelerator unavailable; forcing CPU", file=sys.stderr)
-        force_cpu_platform()
+    require_accelerator("e2e_scale")
     main()

@@ -72,23 +72,30 @@ def _signed_batch(n, msg_len=96, seed=11):
 # -- device parity checks ---------------------------------------------------
 
 
-def check_shardmap_verifier(devs) -> list:
-    """The shard_map verifier: mesh vs single-device bit-equality with
-    one corrupted row per shard, an uncounted row, non-uniform powers,
-    an uneven remainder batch, and tabled negative controls."""
-    from tendermint_tpu.models.verifier import VerifierModel
+def check_shardmap_verifier(
+    devs, n: int = 1024, msg_len: int = 96, seed: int = 11, models=None
+) -> list:
+    """The shard_map verifier: mesh vs single-device bit-equality on an
+    n-row commit with one corrupted row per shard, an uncounted row,
+    non-uniform powers, an uneven remainder batch, and tabled negative
+    controls. ``models`` = (mesh model, single-device model) lets a
+    caller keep them (chip_smoke.py runs this at 10,000 rows of 160
+    bytes and then prints where the models' arrays live)."""
+    from tendermint_tpu.models.verifier import VerifierModel, _bucket
     from tendermint_tpu.parallel import make_mesh
 
     n_dev = len(devs)
     fails = []
-    mesh_m = VerifierModel(mesh=make_mesh(devs), block_on_compile=True)
-    single_m = VerifierModel(block_on_compile=True)
+    mesh_m, single_m = models or (
+        VerifierModel(mesh=make_mesh(devs), block_on_compile=True),
+        VerifierModel(block_on_compile=True),
+    )
 
-    # per-shard negatives over a bucket-exact batch
-    n = 1024
-    pk, mg, sg = _signed_batch(n)
-    shard = n // n_dev
+    # per-shard negatives: the device shards split the PADDED batch
+    pk, mg, sg = _signed_batch(n, msg_len, seed)
+    shard = _bucket(n, n_dev) // n_dev
     bad = [s * shard + (7 * s) % shard for s in range(n_dev)]
+    assert bad[-1] < n, "last shard holds only padding"
     for r in bad:
         sg[r, 9] ^= 0x20
     powers = np.arange(1, n + 1, dtype=np.int64)
@@ -114,7 +121,7 @@ def check_shardmap_verifier(devs) -> list:
 
     # uneven remainder: not divisible by the mesh size
     n2 = 137
-    pk, mg, sg = _signed_batch(n2, seed=12)
+    pk, mg, sg = _signed_batch(n2, msg_len, seed + 1)
     sg[0, 0] ^= 1
     sg[n2 - 1, 63] ^= 0x80
     powers = np.full(n2, 5, dtype=np.int64)
@@ -130,7 +137,7 @@ def check_shardmap_verifier(devs) -> list:
 
     # tabled path with negative controls
     n3 = 128
-    pk, mg, sg = _signed_batch(n3, seed=14)
+    pk, mg, sg = _signed_batch(n3, msg_len, seed + 3)
     all_pk = pk[:16].copy()
     idx = (np.arange(n3) % 16).astype(np.int32)
     sg[9] = 0
@@ -257,9 +264,7 @@ def main() -> int:
     if args.virtual:
         from tendermint_tpu.utils.jaxenv import force_cpu_platform
 
-        if not force_cpu_platform(args.virtual):
-            log("a JAX backend initialized before --virtual could apply")
-            return 2
+        force_cpu_platform(args.virtual)
 
     failures = []
 
@@ -273,6 +278,9 @@ def main() -> int:
             devs = jax.devices()
         except Exception as e:
             log(f"no jax backend: {e!r} (use --virtual N or --skip-device)")
+            return 2
+        if args.virtual and (devs[0].platform != "cpu" or len(devs) < args.virtual):
+            log("a JAX backend initialized before --virtual could apply")
             return 2
         if args.devices > 0:
             devs = devs[: args.devices]

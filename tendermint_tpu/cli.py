@@ -254,11 +254,21 @@ def cmd_testnet(args) -> None:
             p for j, p in enumerate(peers.split(",")) if j != i
         )
         cfg.p2p.allow_duplicate_ip = True
+        if not args.hostname_prefix and i > 0:
+            # every node of the 127.0.0.1 layout runs on THIS machine,
+            # and a chip belongs to one process: node0 keeps the
+            # default device provider, the others verify on the host
+            cfg.base.crypto_provider = "cpu"
         write_config_file(
             os.path.join(home, DEFAULT_CONFIG_DIR, DEFAULT_CONFIG_FILE), cfg
         )
         genesis.save_as(cfg.base.genesis_file())
     print(f"Successfully initialized {n} node directories in {out}")
+    if not args.hostname_prefix and n > 1:
+        print(
+            f'node0 keeps crypto_provider = "tpu"; node1..node{n - 1} were '
+            'given crypto_provider = "cpu" (one process per chip on one machine)'
+        )
 
 
 def cmd_light(args) -> None:

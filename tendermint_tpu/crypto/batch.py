@@ -25,6 +25,33 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 
+class RowCounts:
+    """Thread-safe (device_rows, host_rows) pair, counted by the code
+    that DOES the work: the model adds device rows once a device
+    executable's result has been read back, the host verifier adds the
+    rows it loops over. One instance is shared by a provider, its
+    models and its host verifier, so a cold bucket, an unbuilt table, a
+    sub-threshold batch and a failed device call all show up as host
+    rows — never as rows merely handed to a provider (the
+    engine_stats protocol, models/telemetry.py)."""
+
+    __slots__ = ("_lock", "device", "host")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.device = 0
+        self.host = 0
+
+    def add(self, device: int = 0, host: int = 0) -> None:
+        with self._lock:
+            self.device += device
+            self.host += host
+
+    def snapshot(self) -> Tuple[int, int]:
+        with self._lock:
+            return self.device, self.host
+
+
 class BatchVerifier:
     """Batch signature verification over rectangular u8 arrays."""
 
@@ -102,14 +129,22 @@ class BatchVerifier:
 
 
 class CPUBatchVerifier(BatchVerifier):
-    """Serial host verification -- reference-parity behavior."""
+    """Serial host verification -- reference-parity behavior.
+
+    ``row_counts`` is where the rows served here
+    are counted as host rows; a device provider passes its own so its
+    host fallbacks land in the same pair as its device rows."""
 
     name = "cpu"
+
+    def __init__(self, row_counts=None):
+        self.row_counts = row_counts if row_counts is not None else RowCounts()
 
     def verify_batch(self, pubkeys, msgs, sigs, msg_lens=None) -> np.ndarray:
         from tendermint_tpu.crypto.keys import Ed25519PubKey
 
         n = len(pubkeys)
+        self.row_counts.add(host=n)
         out = np.zeros(n, dtype=bool)
         for i in range(n):
             try:
@@ -150,10 +185,13 @@ class TPUBatchVerifier(BatchVerifier):
 
         self._verifier_model = _verifier_model
         self._block_on_compile = block_on_compile
+        # one device/host row pair for this provider, its models and
+        # its sub-min_device_batch host route (engine_stats reads it)
+        self.row_counts = RowCounts()
         self._model = _verifier_model.VerifierModel(
-            mesh=mesh, block_on_compile=block_on_compile
+            mesh=mesh, block_on_compile=block_on_compile, row_counts=self.row_counts
         )
-        self._cpu = CPUBatchVerifier()
+        self._cpu = CPUBatchVerifier(row_counts=self.row_counts)
         self.min_device_batch = min_device_batch
         self.router = router
         self._mesh_lock = threading.Lock()
@@ -186,7 +224,8 @@ class TPUBatchVerifier(BatchVerifier):
             if mesh is None:
                 return None
             model = self._verifier_model.VerifierModel(
-                mesh=mesh, block_on_compile=self._block_on_compile
+                mesh=mesh, block_on_compile=self._block_on_compile,
+                row_counts=self.row_counts,
             )
             for vk, pks in self._valsets.items():
                 model.register_valset(vk, pks)
@@ -302,6 +341,10 @@ class MeshRoutedVerifier(BatchVerifier):
     def engine_stats(self):
         fn = getattr(self.inner, "engine_stats", None)
         return fn() if fn else None
+
+    @property
+    def row_counts(self):
+        return getattr(self.inner, "row_counts", None)
 
     def verify_batch(self, pubkeys, msgs, sigs, msg_lens=None) -> np.ndarray:
         plan = self.router.plan(len(pubkeys))

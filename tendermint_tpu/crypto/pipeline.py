@@ -3,12 +3,12 @@
 The device path (crypto/batch.py -> models/verifier.py) is fast per
 CALL, but every call site blocks on its own device round trip: the
 fast-sync reactors alternate verify/apply serially, and vote ingest
-pays a dispatch per drain even when several drains race. Prior bench
-rounds measured the gap directly — the overlapped device rate runs ~5x
-faster than back-to-back synchronous calls (BENCH_r05.json:
-tabled_pipelined_ms 26.29 vs tabled_p50_ms 123.97) because a
-synchronous caller leaves the device idle during host prep and result
-readback.
+pays a dispatch per drain even when several drains race. On an
+earlier, remotely attached chip (not re-measured) the overlapped
+device rate ran ~5x faster than back-to-back synchronous calls
+(tabled_pipelined_ms 26.29 vs tabled_p50_ms 123.97; record removed in
+PR 22) because a synchronous caller leaves the device idle during host
+prep and result readback.
 
 ``PipelinedVerifier`` closes that gap without touching the kernels:
 
@@ -628,13 +628,15 @@ class PipelinedVerifier(BatchVerifier):
     def engine_stats(self) -> Dict[str, object]:
         """The unified engine-telemetry protocol (models/telemetry.py):
         bucket compile state comes from the wrapped verifier model's
-        executables + per-valset tables; ``host_rows`` counts the
-        sync-caller serial fallbacks (a liveness escape, each one a
-        whole request verified on the host path)."""
+        executables + per-valset tables; ``device_rows``/``host_rows``
+        are the INNER provider's counts of what a device executable
+        verified and what its host path served (crypto/batch.RowCounts)
+        — a row handed to the provider is not thereby a device row."""
         from tendermint_tpu.models.telemetry import breaker_view, bucket_entry
 
+        row_counts = getattr(self.inner, "row_counts", None)
+        device_rows, host_rows = row_counts.snapshot() if row_counts else (0, 0)
         with self._cv:
-            device_rows = self.device_rows
             counters = {
                 "submitted_calls": self.submitted_calls,
                 "submitted_rows": self.submitted_rows,
@@ -676,7 +678,7 @@ class PipelinedVerifier(BatchVerifier):
         return {
             "engine": "pipeline",
             "device_rows": float(device_rows),
-            "host_rows": float(counters["fallback_serial"]),
+            "host_rows": float(host_rows),
             "buckets": buckets,
             "breakers": breakers,
             "queue_wait_ms": self.queue_wait.snapshot(),

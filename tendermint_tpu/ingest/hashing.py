@@ -80,7 +80,9 @@ def _jit_fns():
             import jax
 
             from tendermint_tpu.ops import sha256 as ops
+            from tendermint_tpu.utils.jaxenv import enable_compile_cache
 
+            enable_compile_cache()
             _jitted = (jax.jit(ops.leaf_block_state), jax.jit(ops.leaf_block_update))
         return _jitted
 

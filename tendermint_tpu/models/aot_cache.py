@@ -17,7 +17,10 @@ stage name and argument shapes — any mismatch or load failure falls
 back to a normal jit compile; the cache is an optimization, never a
 correctness dependency.
 
-Disable with TM_AOT_CACHE=0; relocate with TM_AOT_CACHE_DIR.
+Disable with TM_AOT_CACHE=0. This cache and the built valset tables
+live under the compile-cache root (utils/jaxenv.compile_cache_dir:
+``<root>/aot``, ``<root>/tables``) so one externally placed directory
+warms all three; TM_AOT_CACHE_DIR / TM_TABLES_CACHE_DIR relocate each.
 """
 
 from __future__ import annotations
@@ -29,6 +32,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 
+from tendermint_tpu.utils.jaxenv import compile_cache_dir
 from tendermint_tpu.utils.log import get_logger
 
 _log = get_logger("aot-cache")
@@ -42,14 +46,9 @@ def enabled() -> bool:
 
 
 def cache_dir() -> str:
-    d = os.environ.get("TM_AOT_CACHE_DIR")
-    if not d:
-        d = os.path.join(
-            os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-            "tendermint_tpu",
-            "aot",
-        )
-    return d
+    return os.environ.get("TM_AOT_CACHE_DIR") or os.path.join(
+        compile_cache_dir(), "aot"
+    )
 
 
 def _code_digest() -> str:
@@ -150,26 +149,18 @@ def load(stage: str, args: Tuple[Any, ...]):
         if not os.path.exists(p):
             return None
         from jax.experimental.serialize_executable import deserialize_and_load
-        import inspect
         import pickle
 
         with open(p, "rb") as fh:
             payload, in_tree, out_tree, device_ids = pickle.load(fh)
         # restore the original device assignment: deserialize_and_load
         # defaults to ALL local devices, which breaks a single-device
-        # executable on a multi-device host (and vice versa). jax<=0.4.x
-        # has no execution_devices kwarg — there the loader derives the
-        # assignment from the serialized payload itself, so the blob is
-        # loaded as-is (the first-use validation in AotJit.__call__
-        # still catches an executable that can't actually dispatch).
-        params = inspect.signature(deserialize_and_load).parameters
-        if "execution_devices" in params:
-            by_id = {d.id: d for d in jax.devices()}
-            devices = [by_id[i] for i in device_ids]
-            return deserialize_and_load(
-                payload, in_tree, out_tree, execution_devices=devices
-            )
-        return deserialize_and_load(payload, in_tree, out_tree)
+        # executable on a multi-device host (and vice versa)
+        by_id = {d.id: d for d in jax.devices()}
+        return deserialize_and_load(
+            payload, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in device_ids],
+        )
     except Exception as ex:  # stale/incompatible blob: recompile
         _log.info("aot load failed (recompiling)", stage=stage, err=repr(ex))
         return None
@@ -184,9 +175,7 @@ def save(stage: str, args: Tuple[Any, ...], compiled) -> None:
         import pickle
 
         payload, in_tree, out_tree = serialize(compiled)
-        device_ids = [
-            d.id for d in compiled._executable.xla_executable.local_devices()
-        ]
+        device_ids = [d.id for d in compiled.runtime_executable().local_devices()]
         os.makedirs(cache_dir(), exist_ok=True)
         p = _path(stage, args)
         tmp = p + f".tmp.{os.getpid()}"
@@ -211,14 +200,9 @@ _TABLES_KEEP = int(os.environ.get("TM_TABLES_CACHE_KEEP", "4"))
 
 
 def tables_dir() -> str:
-    d = os.environ.get("TM_TABLES_CACHE_DIR")
-    if not d:
-        d = os.path.join(
-            os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-            "tendermint_tpu",
-            "tables",
-        )
-    return d
+    return os.environ.get("TM_TABLES_CACHE_DIR") or os.path.join(
+        compile_cache_dir(), "tables"
+    )
 
 
 _CODE_DIGEST: Optional[str] = None

@@ -26,30 +26,23 @@ the result, so padding can never flip a real row's verdict.
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
+from tendermint_tpu.ops import bls12 as ops_bls
+from tendermint_tpu.ops import ref_bls12 as ref
+from tendermint_tpu.utils import faultinject as faults
+from tendermint_tpu.utils.jaxenv import enable_compile_cache
+from tendermint_tpu.utils.log import get_logger
+from tendermint_tpu.utils.watchdog import CircuitBreaker
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-if jax.config.jax_compilation_cache_dir is None:
-    jax.config.update(
-        "jax_compilation_cache_dir", os.environ["JAX_COMPILATION_CACHE_DIR"]
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-
-from tendermint_tpu.ops import bls12 as ops_bls  # noqa: E402
-from tendermint_tpu.ops import ref_bls12 as ref  # noqa: E402
-from tendermint_tpu.utils import faultinject as faults  # noqa: E402
-from tendermint_tpu.utils.log import get_logger  # noqa: E402
-from tendermint_tpu.utils.watchdog import CircuitBreaker  # noqa: E402
+# persistent compilation cache (one directory for every engine)
+enable_compile_cache()
 
 # Row-count buckets per kernel. BLS rows are ~5 orders heavier than
 # ed25519 rows (a pairing vs a scalar mult), so buckets stay small.

@@ -39,27 +39,19 @@ import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-# Same persistent-cache bootstrap as models/verifier.py: the hasher may
-# be the first jax user in light-client / tooling processes.
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-if jax.config.jax_compilation_cache_dir is None:
-    jax.config.update(
-        "jax_compilation_cache_dir", os.environ["JAX_COMPILATION_CACHE_DIR"]
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-
-from tendermint_tpu.ops import sha256 as ops_sha  # noqa: E402
-from tendermint_tpu.utils import faultinject as faults  # noqa: E402
+from tendermint_tpu.ops import sha256 as ops_sha
+from tendermint_tpu.utils import faultinject as faults
 from tendermint_tpu.utils import trace
-from tendermint_tpu.utils.log import get_logger  # noqa: E402
-from tendermint_tpu.utils.watchdog import CircuitBreaker  # noqa: E402
+from tendermint_tpu.utils.jaxenv import enable_compile_cache
+from tendermint_tpu.utils.log import get_logger
+from tendermint_tpu.utils.watchdog import CircuitBreaker
+
+# the hasher may be the first jax user in light-client / tooling processes
+enable_compile_cache()
 
 # Leaf-count buckets (padded row counts). 10240 sits just above the 10k
 # commit-sig / validator-row shape for the same reason as the verifier's

@@ -12,8 +12,8 @@ module fixes the vocabulary; each engine implements
 
     engine_stats() -> {
         "engine":       str,            # "pipeline"|"merkle"|"bls"|"txhash"
-        "device_rows":  float,          # rows the device executed
-        "host_rows":    float,          # rows the host path served
+        "device_rows":  float,          # rows a device executable verified/hashed
+        "host_rows":    float,          # rows the host path served instead
         "buckets":      {key: {"state": "ready|compiling|failed|cold",
                                "compile_s": float|None}},
         "breakers":     {name: {"state", "state_code", "trips",

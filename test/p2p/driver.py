@@ -152,6 +152,9 @@ class ProcNet:
         return p
 
     def start_all(self):
+        # one machine, one chip, N node processes: every node is pinned
+        # to the host verifier (start() sets TM_CRYPTO_PROVIDER=cpu)
+        print(f"starting {self.n} nodes with crypto_provider=cpu (JAX_PLATFORMS=cpu)")
         for i in range(self.n):
             self.start(i)
 

@@ -306,7 +306,7 @@ def test_sim_bench_heights_per_sec_floor(bench, monkeypatch):
     out = bench.sim_bench()
     assert "sim_error" not in out, out
     assert out["sim_heights_per_sec"] >= 2.0, out
-    assert out["sim_device_sigs_per_sec"] > 0
+    assert out["sim_engine_sigs_per_sec"] > 0
     assert out["sim_12x6_multi_source_bundles"] >= 1, out
     # the recovery drill rides the section: kill-to-commit measured
     assert out.get("sim_recovery_s", 0) > 0, out
@@ -477,34 +477,6 @@ def test_mesh_bench_weak_scaling_floor(bench, monkeypatch):
     for d in (1, 8):
         if d <= len(jax.devices()):
             assert out[f"mesh_p50_ms_{d}dev"] > 0
-
-
-def test_coldstart_carry_at_most_once(bench):
-    """A failed cold-start probe carries the previous record's keys
-    exactly once; a record that already carried leaves them out (the
-    presence guard then fails the run), and a successful probe resets."""
-    _write_record(
-        bench, value=30.0, coldstart_first_verify_s=9.1, coldstart_carried=0
-    )
-    out = bench._carry_coldstart({}, "tpu")
-    assert out["coldstart_first_verify_s"] == 9.1
-    assert out["coldstart_carried"] == 1
-
-    # record that already carried once: no second carry
-    _write_record(
-        bench, value=30.0, coldstart_first_verify_s=9.1, coldstart_carried=1
-    )
-    out2 = bench._carry_coldstart({}, "tpu")
-    assert "coldstart_first_verify_s" not in out2
-    # and the presence-only guard flags the resulting line
-    fails = bench._regression_guard({"value": 30.0, "bench_n": 10000}, "tpu")
-    assert any("coldstart_first_verify_s" in f for f in fails)
-
-    # successful probe passes through untouched (no carried counter)
-    fresh = {"coldstart_first_verify_s": 8.0}
-    assert bench._carry_coldstart(dict(fresh), "tpu") == fresh
-    # cpu fallback never carries
-    assert bench._carry_coldstart({}, "cpu") == {}
 
 
 def test_guard_flags_exec_regression_and_disappearance(bench):

@@ -1,0 +1,138 @@
+"""Ask the chip's compiler, without the chip: the main-path programs at
+real width (10,240 rows, 160-byte sign bytes) compiled for a DESCRIBED
+v5e:2x2 device. A pass says the TPU compiler accepts the program and
+that it fits; it is never a chip run and says nothing about time or
+results (chip_smoke.py does that, on the chip).
+
+Rules this file keeps (on-chip-measurement guide, section 2): the
+topology is described inside a module-scoped, non-autouse fixture that
+skips where it cannot be; nothing touches jax.experimental.topologies
+at import; the persistent cache is off around the compiles (such an
+entry cannot be read back without a chip); no child process compiles.
+All such tests live in THIS file: the worker that runs it keeps the TPU
+library until it exits.
+"""
+
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from tendermint_tpu.ops import ed25519 as E
+from tendermint_tpu.parallel.mesh import BATCH_AXIS
+
+N = 10_240  # the bucket above MaxVotesCount
+HBM_BYTES = 16 * 10**9  # one v5e chip (Google Cloud documentation, "TPU v5e")
+u8, i32 = jnp.uint8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture()
+def compile_for(topo, no_persistent_cache):
+    """compile_for(fn_or_jitted, limit_s, *shapes) -> Compiled, failing
+    the test when the compile outlives its limit or the program does
+    not fit one chip's memory."""
+
+    def run(fn, limit_s, *args):
+        t0 = time.perf_counter()
+        jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
+        compiled = jitted.lower(*args).compile()
+        dt = time.perf_counter() - t0
+        m = compiled.memory_analysis()
+        need = (
+            m.generated_code_size_in_bytes + m.temp_size_in_bytes
+            + m.argument_size_in_bytes + m.output_size_in_bytes
+        )
+        assert need < HBM_BYTES, f"{need} bytes do not fit one chip"
+        assert dt < limit_s, f"compile took {dt:.0f}s, limit {limit_s}s"
+        return compiled
+
+    return run
+
+
+def shapes(sharding):
+    """S(shape, dtype) and like(eval_shape tree) bound to a sharding."""
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+    def like(tree):
+        return jax.tree_util.tree_map(lambda a: S(a.shape, a.dtype), tree)
+
+    return S, like
+
+
+def test_templated_tabled_prepare_compiles_for_v5e(topo, compile_for):
+    """Stage 1 of the live commit path: (templates, tmpl_idx, ts8)
+    materialized on device, then the dense tabled prepare."""
+    S, like = shapes(SingleDeviceSharding(topo.devices[0]))
+    tpl, tidx, ts8 = S((2, 160), u8), S((N,), i32), S((N, 8), u8)
+    compile_for(E.materialize_sign_bytes, 30, tpl, tidx, ts8)
+    mg = like(jax.eval_shape(E.materialize_sign_bytes, tpl, tidx, ts8))
+    assert mg.shape == (N, 160) and mg.dtype == u8
+    compile_for(E.verify_stage_prepare_tabled, 90, S((N, 32), u8), mg, S((N, 64), u8))
+
+
+def test_tabled_dense_scan_compiles_for_v5e(topo, compile_for):
+    """Stage 2, the dominant kernel, against a 10,240-key table
+    (~315 MB resident beside the program)."""
+    S, like = shapes(SingleDeviceSharding(topo.devices[0]))
+    tables, a_ok = like(jax.eval_shape(E.build_valset_tables, S((N, 32), u8)))
+    sd, kd, _ = like(
+        jax.eval_shape(E.verify_stage_prepare_tabled, S((N, 32), u8), S((N, 160), u8), S((N, 64), u8))
+    )
+    compile_for(E.verify_stage_scan_tabled_dense, 240, sd, kd, tables, a_ok)
+
+
+def test_finish_tally_compiles_for_v5e(topo, compile_for):
+    """Stage 3 of the generic path with the fused voting-power tally."""
+    S, like = shapes(SingleDeviceSharding(topo.devices[0]))
+    pk, mg, sg = S((N, 32), u8), S((N, 160), u8), S((N, 64), u8)
+    pre = like(jax.eval_shape(E.verify_stage_prepare, pk, mg, sg))
+    co = like(jax.eval_shape(E.verify_stage_scan, *pre[:6]))
+    compile_for(
+        E.verify_stage_finish_tally, 60,
+        *co, sg, pre[6], pre[7], S((N, E.POWER_CHUNKS), i32), S((N,), jnp.bool_),
+    )
+
+
+def test_shard_map_scan_compiles_for_four_chips(topo, compile_for):
+    """The mesh form of the generic scan: rows shard over four devices,
+    and the per-device program is the single-device one at N/4 rows."""
+    from tendermint_tpu.models.verifier import VerifierModel
+
+    mesh = Mesh(np.array(topo.devices[:4]), (BATCH_AXIS,))
+    S, like = shapes(NamedSharding(mesh, P(BATCH_AXIS)))
+    pre = like(
+        jax.eval_shape(E.verify_stage_prepare, S((N, 32), u8), S((N, 160), u8), S((N, 64), u8))
+    )
+    _, scan = VerifierModel(mesh=mesh)._stages()
+    compiled = compile_for(scan._jit, 90, *pre[:6])
+    out = compiled.output_shardings
+    assert all(s.spec == P(BATCH_AXIS) for s in jax.tree_util.tree_leaves(out)), out

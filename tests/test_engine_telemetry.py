@@ -3,6 +3,7 @@ protocol on all four engines, the bucket/breaker/queue-wait views, the
 tendermint_engine_* family fed from snapshots, and the flattened
 counters the height ledger diffs per height."""
 
+import numpy as np
 import pytest
 
 from tendermint_tpu.models.telemetry import (
@@ -134,11 +135,77 @@ def test_pipeline_engine_stats():
         assert pv.verify_batch(pk, mg, sg).all()
         st = pv.engine_stats()
     _assert_protocol(st, "pipeline")
-    assert st["device_rows"] == 8.0
+    # a host-only inner provider: every row it served is a HOST row —
+    # handing a row to the provider does not make it a device row
+    assert st["device_rows"] == 0.0
+    assert st["host_rows"] == 8.0
     assert st["counters"]["dispatched_bundles"] >= 1
     # the queue-wait histogram observed every bundle, tracing OFF
     assert st["queue_wait_ms"]["count"] >= 1
     assert st["queue_wait_ms"]["sum_ms"] >= 0
+
+
+def _row_case_warm_blocking(v, batch):
+    pk, mg, sg = batch
+    assert v.verify_batch(pk, mg, sg).all()
+    return (len(pk), 0)
+
+
+def _row_case_cold_nonblocking(v, batch):
+    # stub the background compile (a daemon XLA-compile thread killed at
+    # interpreter exit aborts the process — tests/test_tpu_provider.py)
+    v.model._compile_async = lambda *a: None
+    pk, mg, sg = batch
+    assert v.verify_batch(pk, mg, sg).all()
+    ok, tally = v.verify_commit_batch(
+        pk, mg, sg, np.full(len(pk), 5, np.int64), np.ones(len(pk), bool)
+    )
+    assert ok.all() and tally == 5 * len(pk)
+    return (0, 2 * len(pk))
+
+
+def _row_case_sub_min_device_batch(v, batch):
+    pk, mg, sg = (a[:1] for a in batch)
+    assert v.verify_batch(pk, mg, sg).all()
+    return (0, 1)
+
+
+def _row_case_device_fault(v, batch):
+    from tendermint_tpu.utils import faultinject as faults
+
+    faults.arm("device.verify", "raise")
+    try:
+        with pytest.raises(faults.InjectedFault):
+            v.verify_batch(*batch)
+    finally:
+        faults.disarm("device.verify")
+    return (0, 0)
+
+
+@pytest.mark.parametrize(
+    "block_on_compile,case",
+    [
+        (True, _row_case_warm_blocking),
+        (False, _row_case_cold_nonblocking),
+        (True, _row_case_sub_min_device_batch),
+        (True, _row_case_device_fault),
+    ],
+    ids=["warm-blocking", "cold-nonblocking", "sub-min-device-batch", "device-fault"],
+)
+def test_verifier_row_counts_say_where_rows_ran(block_on_compile, case):
+    """device_rows / host_rows count what a device executable verified
+    and what the host served — counted by the provider that did the
+    work, and published unchanged by the pipeline's engine_stats()."""
+    import bench
+    from tendermint_tpu.crypto.batch import TPUBatchVerifier
+    from tendermint_tpu.crypto.pipeline import PipelinedVerifier, SigCache
+
+    v = TPUBatchVerifier(block_on_compile=block_on_compile)
+    want = case(v, bench.make_batch(4, seed=12))
+    assert v.row_counts.snapshot() == want
+    with PipelinedVerifier(v, cache=SigCache()) as pv:
+        st = pv.engine_stats()
+    assert (st["device_rows"], st["host_rows"]) == tuple(map(float, want))
 
 
 def test_pipeline_engine_stats_mixed_arity_bucket_keys():

@@ -246,7 +246,9 @@ def test_verify_traffic_batches_across_nodes():
     counters = eng["counters"]
     assert counters["multi_source_bundles"] >= 1
     assert counters["max_bundle_sources"] > 1
-    assert eng["device_rows"] > 0
+    # the shared engine's inner provider is the host verifier: every
+    # row it verified is a host row, none a device row
+    assert eng["host_rows"] > 0 and eng["device_rows"] == 0
     assert res.net["preverified_rows"] > 0
     # per-node caches were actually consulted and hit by inline ingest
     assert sum(c.hits for c in sim.node_caches) > 0
