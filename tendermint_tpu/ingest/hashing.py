@@ -3,9 +3,9 @@
 ``mempool.tx_key`` is plain ``sha256(tx)`` — no merkle leaf prefix — so
 the merkle engine's packer (ops/sha256.pack_leaf_blocks) can't be
 reused directly, but its kernels can: ``leaf_block_state`` /
-``leaf_block_update`` compress pre-padded 64-byte blocks row-parallel
-with one compression per dispatch (the XLA:CPU fusion discipline from
-ops/sha256.py), and ``state_to_digests`` materializes bytes host-side.
+``leaf_block_update`` compress pre-padded 64-byte blocks row-parallel,
+one block column per dispatch (so executables are keyed by row count
+only), and ``state_to_digests`` materializes bytes host-side.
 This module owns the prefix-free packer plus a small bucketed engine in
 the models/hasher.py mold: leaf-count buckets are powers of two with
 logical-count masking of pad rows, executables compile in a background

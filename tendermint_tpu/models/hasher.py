@@ -23,13 +23,13 @@ Latency discipline mirrors models/verifier.py:
   on XLA (same contract as VerifierModel._get_fn).
 
 The dispatch chain per tree: one leaf-state dispatch per block column,
-then per level merkle_inner_first + merkle_inner_tail, until the level
-width reaches HOST_TAIL_WIDTH — the narrow top of the tree is
-latency-bound serial work where per-dispatch overhead beats compute,
-so hashlib finishes it (and the root path's device->host transfer is
-one (8, tail) state array). ops/sha256.py explains why the chain is
-many small graphs instead of one fused tree program (XLA:CPU fusion
-collapses past one compression per graph / one output root).
+then one merkle_inner dispatch per level, until the level width reaches
+HOST_TAIL_WIDTH — the narrow top of the tree is latency-bound serial
+work where per-dispatch overhead beats compute, so hashlib finishes it
+(and the root path's device->host transfer is one (8, tail) state
+array). The chain is one small program per level width rather than one
+fused tree program so that executables are keyed by width only and any
+leaf count in a bucket reuses them.
 """
 
 from __future__ import annotations
@@ -127,8 +127,7 @@ class MerkleHasher:
         # jits are shared across buckets; jax specializes per shape
         self._leaf_state = jax.jit(ops_sha.leaf_block_state)
         self._leaf_update = jax.jit(ops_sha.leaf_block_update)
-        self._inner_first = jax.jit(ops_sha.merkle_inner_first)
-        self._inner_tail = jax.jit(ops_sha.merkle_inner_tail)
+        self._inner = jax.jit(ops_sha.merkle_inner)
         self.stats: Dict[str, int] = {
             "device_roots": 0,
             "device_proof_sets": 0,
@@ -317,11 +316,8 @@ class MerkleHasher:
         counts = [len(items)]
         cnt = len(items)
         while int(levels[-1].shape[1]) > HOST_TAIL_WIDTH and cnt > 1:
-            lv = levels[-1]
-            mid = self._inner_first(lv)
-            lv = self._inner_tail(mid, lv, np.int32(cnt))
+            levels.append(self._inner(levels[-1], np.int32(cnt)))
             cnt = (cnt + 1) // 2
-            levels.append(lv)
             counts.append(cnt)
         return levels, counts
 

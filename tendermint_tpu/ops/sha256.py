@@ -1,38 +1,28 @@
-"""Batched SHA-256 kernels for the device merkle engine.
+"""Batched SHA-256 kernels for the device merkle and tx-key engines.
 
-Mirrors the ops/sha512.py message-schedule style (statically unrolled
-rounds over uint32 words; SHA-256 is natively 32-bit so no (hi, lo)
-pairing is needed), but the graph SHAPE is driven by an XLA:CPU fusion
-discipline the merkle workload forced into the open:
+One compression (``_compress``) serves every kernel here and every
+backend: the 64 rounds are a ROLLED ``lax.fori_loop`` over the state as
+one (8, N) uint32 array and a sliding 16-word schedule window as one
+(16, N) array. Rolled because XLA:CPU (jax 0.9.0) does not finish
+executing the statically unrolled 64-round graph — tier-1 runs on that
+backend, and a kernel no test can execute is not guarded at all.
 
-- ONE compression per compiled graph. Chaining two 64-round compress
-  instances in a single jit graph pushes XLA past its fusion budget and
-  both compile time (~40s -> minutes) and runtime (2ms -> 120ms+ at 10k
-  rows) collapse. The tree is therefore reduced DISPATCH-BY-DISPATCH
-  from Python (models/hasher.py), each dispatch one compress.
-- ONE logical output per graph, behind an optimization_barrier. XLA
-  re-materializes the whole 1800-op compress DAG once per fusion root:
-  a (N,) single-word output runs ~1.9ms at 10k rows where the same
-  graph serialized to (N, 32) digest bytes (32 roots) runs ~70ms. Hash
-  state therefore travels BETWEEN dispatches as one stacked (8, N)
-  uint32 array — big-endian words, exactly the digest — and bytes are
-  only materialized host-side (state_to_digests).
-- Inner-node messages are built in WORD space (merkle_inner_first):
-  an inner node hashes 0x01 || left || right (65 bytes, 2 blocks), and
-  both children arrive as (8, half) word columns, so w0..w15 of block
-  one are shifts/ors of child words — no byte round-trip. Block two is
-  all padding except its first byte (right child's last byte), so its
-  schedule constant-folds at trace time around that single varying
-  word (merkle_inner_tail).
+Hash state travels between dispatches as that stacked (8, N) array —
+big-endian words, exactly the digest — and bytes are only materialized
+host-side (state_to_digests). Inner-node messages are built in WORD
+space (merkle_inner): an inner node hashes 0x01 || left || right (65
+bytes, 2 blocks) and both children arrive as (8, half) word columns, so
+block one's words are shifts/ors of child words — no byte round-trip.
 
 Used by models/hasher.py for block data hashes, tx roots, part-set
 roots, validator-set hashes and evidence hashes above the
-merkle_device_threshold (crypto/merkle.py).
+merkle_device_threshold (crypto/merkle.py), and by ingest/hashing.py
+for mempool tx keys.
 """
 
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple, Union
+from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -68,61 +58,44 @@ def _ror(x, n: int):
     return (x >> n) | (x << (32 - n))
 
 
-def _round(st, wt, kt: int):
-    """One SHA-256 round; ch uses the 3-op form g ^ (e & (f ^ g))."""
-    a, b, c, d, e, f, g, h = st
-    s1 = _ror(e, 6) ^ _ror(e, 11) ^ _ror(e, 25)
-    ch = g ^ (e & (f ^ g))
-    t1 = h + s1 + ch + jnp.uint32(kt) + wt
-    s0 = _ror(a, 2) ^ _ror(a, 13) ^ _ror(a, 22)
-    maj = (a & b) ^ (a & c) ^ (b & c)
-    return (t1 + s0 + maj, a, b, c, d + t1, e, f, g)
+def _compress(state, w):
+    """One block: state (8, N) u32, w (16, N) u32 message words ->
+    (8, N). Round t reads w[0] and slides the window on by the schedule
+    word of round t + 16 (the last 16 are computed and never read)."""
+    k = jnp.asarray(_K, dtype=U32)
+
+    def round_(t, carry):
+        (a, b, c, d, e, f, g, h), w = carry
+        s1 = _ror(e, 6) ^ _ror(e, 11) ^ _ror(e, 25)
+        ch = g ^ (e & (f ^ g))
+        t1 = h + s1 + ch + k[t] + w[0]
+        s0 = _ror(a, 2) ^ _ror(a, 13) ^ _ror(a, 22)
+        maj = (a & b) ^ (a & c) ^ (b & c)
+        x1, x14 = w[1], w[14]
+        nxt = (
+            w[0]
+            + (_ror(x1, 7) ^ _ror(x1, 18) ^ (x1 >> 3))
+            + w[9]
+            + (_ror(x14, 17) ^ _ror(x14, 19) ^ (x14 >> 10))
+        )
+        return (
+            jnp.stack([t1 + s0 + maj, a, b, c, d + t1, e, f, g]),
+            jnp.concatenate([w[1:], nxt[None]], axis=0),
+        )
+
+    out, _ = jax.lax.fori_loop(0, 64, round_, (state, w))
+    return state + out
 
 
-def _round_const(st, kw: int):
-    """_round with the schedule word pre-folded into the constant."""
-    a, b, c, d, e, f, g, h = st
-    s1 = _ror(e, 6) ^ _ror(e, 11) ^ _ror(e, 25)
-    ch = g ^ (e & (f ^ g))
-    t1 = h + s1 + ch + jnp.uint32(kw)
-    s0 = _ror(a, 2) ^ _ror(a, 13) ^ _ror(a, 22)
-    maj = (a & b) ^ (a & c) ^ (b & c)
-    return (t1 + s0 + maj, a, b, c, d + t1, e, f, g)
-
-
-def _compress(st, w16):
-    """One block: st 8-tuple of (N,) u32; w16 list of 16 (N,) u32 words.
-    Rounds AND message schedule statically unrolled — on XLA:CPU a
-    lax.scan boundary costs ~6x runtime (the scan carry becomes a
-    multi-root fusion, see module docstring)."""
-    wl = list(w16)
-    s_in = st
-    for t in range(64):
-        if t < 16:
-            wt = wl[t]
-        else:
-            j = t % 16
-            x1 = wl[(j + 1) % 16]
-            x14 = wl[(j + 14) % 16]
-            s0 = _ror(x1, 7) ^ _ror(x1, 18) ^ (x1 >> 3)
-            s1 = _ror(x14, 17) ^ _ror(x14, 19) ^ (x14 >> 10)
-            wt = wl[j] + s0 + wl[(j + 9) % 16] + s1
-            wl[j] = wt
-        st = _round(st, wt, _K[t])
-    return tuple(o + n for o, n in zip(s_in, st))
+def _initial_state(n: int) -> jnp.ndarray:
+    return jnp.broadcast_to(jnp.asarray(_H0, dtype=U32)[:, None], (8, n))
 
 
 def _words_from_bytes(blk):
-    """(N, 64) u8 byte values -> 16 (N,) u32 big-endian words."""
+    """(N, 64) u8 byte values -> (16, N) u32 big-endian words."""
     b = blk.astype(U32).reshape(blk.shape[0], 16, 4)
     w = (b[:, :, 0] << 24) | (b[:, :, 1] << 16) | (b[:, :, 2] << 8) | b[:, :, 3]
-    return [w[:, i] for i in range(16)]
-
-
-def _stack_state(st) -> jnp.ndarray:
-    """8-tuple -> (8, N) behind a barrier: without it XLA re-derives the
-    full compress once per output row (the multi-root duplication)."""
-    return jnp.stack(jax.lax.optimization_barrier(tuple(st)), axis=0)
+    return w.T
 
 
 # -- leaf hashing -----------------------------------------------------------
@@ -133,117 +106,44 @@ def leaf_block_state(blk: jnp.ndarray) -> jnp.ndarray:
     block bytes -> (8, N) u32 state. Rows are independent leaves; the
     block must already carry the 0x00 leaf prefix and, for single-block
     leaves, the 0x80 terminator + bit length (models/hasher.py packs)."""
-    st = tuple(jnp.full((blk.shape[0],), h, dtype=U32) for h in _H0)
-    return _stack_state(_compress(st, _words_from_bytes(blk)))
+    return _compress(_initial_state(blk.shape[0]), _words_from_bytes(blk))
 
 
 def leaf_block_update(state: jnp.ndarray, blk: jnp.ndarray, active: jnp.ndarray) -> jnp.ndarray:
     """Fold one more block into multi-block leaves: state (8, N) u32,
     blk (N, 64) u8, active (N,) bool (False rows — leaves already fully
     consumed — keep their state)."""
-    st = tuple(state[i] for i in range(8))
-    new = _compress(st, _words_from_bytes(blk))
-    return _stack_state(
-        tuple(jnp.where(active, n, o) for o, n in zip(st, new))
-    )
+    return jnp.where(active, _compress(state, _words_from_bytes(blk)), state)
 
 
 # -- inner levels -----------------------------------------------------------
-#
-# Inner node = sha256(0x01 || left(32) || right(32)): 65 bytes, two
-# blocks. Block one is bytes 0..63 (prefix, left, right[0:31]); block
-# two is right[31] || 0x80 || zeros || len(520 bits) — constant except
-# its first byte.
 
 
-def merkle_inner_first(level: jnp.ndarray) -> jnp.ndarray:
-    """Block one of all sibling pairs of a level: level (8, C) u32 word
-    columns (C even or odd; an odd last column is a promoted node the
-    tail step re-appends) -> (8, C//2) u32 mid-state."""
+def merkle_inner(level: jnp.ndarray, m) -> jnp.ndarray:
+    """Hash all sibling pairs of a level and build the next one.
+
+    level (8, C) u32 word columns; m () int32 — the level's LOGICAL node
+    count (<= C; columns past it are padding junk). Output
+    (8, ceil(C/2)): column i is the pair hash when 2i+1 < m, the
+    PROMOTED left child when 2i == m-1 (odd count, reference
+    getSplitPoint recursion — the lone node rides up unchanged), junk
+    otherwise.
+
+    Inner node = sha256(0x01 || left(32) || right(32)): 65 bytes, two
+    blocks. Block one is bytes 0..63 (prefix, left, right[0:31]); block
+    two is right[31] || 0x80 || zeros || len(520 bits)."""
     half = level.shape[1] // 2
-    lw = [level[i, 0 : 2 * half : 2] for i in range(8)]   # left child words
-    rw = [level[i, 1 : 2 * half : 2] for i in range(8)]   # right child words
-    w = [jnp.uint32(0x01000000) | (lw[0] >> 8)]
-    for k in range(1, 8):
-        w.append((lw[k - 1] << 24) | (lw[k] >> 8))
-    w.append((lw[7] << 24) | (rw[0] >> 8))
-    for k in range(1, 8):
-        w.append((rw[k - 1] << 24) | (rw[k] >> 8))
-    st = tuple(jnp.full((half,), h, dtype=U32) for h in _H0)
-    return _stack_state(_compress(st, w))
-
-
-def _inner_tail_words(r_last) -> list:
-    """Block-two schedule with w0 = right[31] || 0x80 || 0 || 0 the only
-    varying word: entries stay python ints wherever both operands are
-    constant, so most of the 48-step expansion folds at trace time."""
-    w: List[Union[int, jnp.ndarray]] = [
-        (r_last << 24) | jnp.uint32(0x00800000)
-    ]
-    w += [0] * 14
-    w.append(65 * 8)  # bit length of the 65-byte message
-    for t in range(16, 64):
-
-        def sig0(x):
-            if isinstance(x, int):
-                return (
-                    (((x >> 7) | (x << 25)) ^ ((x >> 18) | (x << 14)) ^ (x >> 3))
-                    & 0xFFFFFFFF
-                )
-            return _ror(x, 7) ^ _ror(x, 18) ^ (x >> 3)
-
-        def sig1(x):
-            if isinstance(x, int):
-                return (
-                    (((x >> 17) | (x << 15)) ^ ((x >> 19) | (x << 13)) ^ (x >> 10))
-                    & 0xFFFFFFFF
-                )
-            return _ror(x, 17) ^ _ror(x, 19) ^ (x >> 10)
-
-        parts = [w[t - 16], sig0(w[t - 15]), w[t - 7], sig1(w[t - 2])]
-        if all(isinstance(p, int) for p in parts):
-            w.append(sum(parts) & 0xFFFFFFFF)
-        else:
-            acc = None
-            const = 0
-            for p in parts:
-                if isinstance(p, int):
-                    const = (const + p) & 0xFFFFFFFF
-                else:
-                    acc = p if acc is None else acc + p
-            w.append(acc + jnp.uint32(const) if const else acc)
-    return w
-
-
-def merkle_inner_tail(mid: jnp.ndarray, level: jnp.ndarray, m) -> jnp.ndarray:
-    """Finish the inner hashes and build the next level.
-
-    mid (8, half) u32 from merkle_inner_first; level (8, C) the current
-    level's word columns; m () int32 — the level's LOGICAL node count
-    (<= C; columns past it are padding junk). Output (8, ceil(C/2)):
-    column i is the pair hash when 2i+1 < m, the PROMOTED left child
-    when 2i == m-1 (odd count, reference getSplitPoint recursion — the
-    lone node rides up unchanged), junk otherwise."""
-    half = level.shape[1] // 2
-    r_last = level[7, 1 : 2 * half : 2] & jnp.uint32(0xFF)
-    st_in = tuple(mid[i] for i in range(8))
-    st = st_in
-    w = _inner_tail_words(r_last)
-    for t in range(64):
-        wt = w[t]
-        if isinstance(wt, int):
-            # fold the constant schedule word into the round constant
-            st = _round_const(st, (_K[t] + wt) & 0xFFFFFFFF)
-        else:
-            st = _round(st, wt, _K[t])
-    pair = tuple(o + n for o, n in zip(st_in, st))
-    idx = jnp.arange(half, dtype=jnp.int32)
-    has_right = (2 * idx + 1) < m
-    out = tuple(
-        jnp.where(has_right, p, level[i, 0 : 2 * half : 2])
-        for i, p in enumerate(pair)
-    )
-    out = _stack_state(out)
+    left = level[:, 0 : 2 * half : 2]
+    right = level[:, 1 : 2 * half : 2]
+    lr = jnp.concatenate([left, right], axis=0)  # (16, half): left || right
+    prev = jnp.concatenate([jnp.full((1, half), 0x01, dtype=U32), lr[:-1]], axis=0)
+    first = (prev << 24) | (lr >> 8)  # the same bytes behind the 0x01 prefix
+    tail = jnp.zeros((16, half), dtype=U32)
+    tail = tail.at[0].set((right[7] << 24) | jnp.uint32(0x00800000))
+    tail = tail.at[15].set(65 * 8)  # bit length of the 65-byte message
+    pair = _compress(_compress(_initial_state(half), first), tail)
+    has_right = (2 * jnp.arange(half, dtype=jnp.int32) + 1) < m
+    out = jnp.where(has_right, pair, left)
     if level.shape[1] % 2:
         # odd STATIC width: the last column can only pair with padding,
         # so it is carried; when the logical count is smaller and odd,
@@ -295,7 +195,7 @@ def pack_leaf_blocks(
     prefix_len=0 packs plain sha256 messages (the ingest tx-key engine,
     ingest/hashing.py). Pad rows (>= len(items)) get count 0 and
     all-zero blocks; their junk digests are never selected
-    (merkle_inner_tail masks on the logical count)."""
+    (merkle_inner masks on the logical count)."""
     n = len(items)
     p = int(prefix_len)
     lens = np.fromiter((len(x) for x in items), dtype=np.int64, count=n)
@@ -350,31 +250,21 @@ def leaf_blocks_needed(max_len: int) -> int:
 def sha256(msgs: jnp.ndarray) -> jnp.ndarray:
     """Batched SHA-256 of uniform-length messages: (N, L) u8/int32 byte
     values -> (N, 32) int32 digest bytes. L is static; padding is
-    computed at trace time (mirror of ops/sha512.sha256's contract).
-    Fine under vmap/jit for L <= 55 (one block); multi-block inputs
-    chain compress instances in one graph, which is correct everywhere
-    but slow on XLA:CPU — the merkle engine uses the staged kernels
-    above instead."""
+    computed at trace time (mirror of ops/sha512.sha512's contract)."""
     n, length = msgs.shape
-    m = msgs.astype(U32)
-    total = length + 1 + 8
-    blocks = (total + 63) // 64
-    padded = blocks * 64
-    pad = np.zeros(padded - length, dtype=np.uint32)
+    blocks = (length + 1 + 8 + 63) // 64
+    pad = np.zeros(blocks * 64 - length, dtype=np.uint32)
     pad[0] = 0x80
     bitlen = length * 8
     for i in range(8):
         pad[-1 - i] = (bitlen >> (8 * i)) & 0xFF
     m = jnp.concatenate(
-        [m, jnp.broadcast_to(jnp.asarray(pad), (n, pad.shape[0]))], axis=1
+        [msgs.astype(U32), jnp.broadcast_to(jnp.asarray(pad), (n, pad.shape[0]))],
+        axis=1,
     )
-    st = tuple(jnp.full((n,), h, dtype=U32) for h in _H0)
+    st = _initial_state(n)
     for b in range(blocks):
         st = _compress(st, _words_from_bytes(m[:, b * 64 : (b + 1) * 64]))
-    st = jax.lax.optimization_barrier(tuple(st))
-    outs = []
-    for word in st:
-        outs.extend(
-            [(word >> 24) & 0xFF, (word >> 16) & 0xFF, (word >> 8) & 0xFF, word & 0xFF]
-        )
-    return jnp.stack(outs, axis=-1).astype(jnp.int32)
+    shifts = jnp.asarray([24, 16, 8, 0], dtype=U32)
+    out = (st.T[:, :, None] >> shifts) & 0xFF  # (N, 8, 4) big-endian bytes
+    return out.reshape(n, 32).astype(jnp.int32)

@@ -48,6 +48,11 @@ if "TM_TABLES_CACHE_DIR" not in os.environ:
 # test_ops_ed25519.py).
 os.environ["TM_CRYPTO_PROVIDER"] = "cpu"
 
+import contextlib  # noqa: E402
+import faulthandler  # noqa: E402
+import hashlib  # noqa: E402
+import sys  # noqa: E402
+
 import pytest  # noqa: E402
 
 
@@ -83,6 +88,48 @@ def pytest_unconfigure(config):
     import atexit
 
     atexit.register(filter_cpu_aot_noise)
+    controller = config.pluginmanager.getplugin("dsession")
+    if controller is not None:  # xdist's, its workers gone: drop _hang_guard's marks
+        shutil.rmtree(_attempts_dir(controller.nodemanager.testrunuid), ignore_errors=True)
+
+
+# A test stuck inside native code (an XLA executable that never
+# returns) cannot be interrupted from Python: unguarded it pins its
+# process until the whole run is cut, and nothing says where. The guard
+# makes the process write every thread's stack and exit. Under xdist
+# (--dist loadfile) the controller then reports the crash and hands the
+# file, that test included, to a fresh worker: so the stacks go to a
+# file of the run, and there they fail the test at once and the rest of
+# the file runs. In one process there is no rerun to tell: the stacks
+# go to stderr (captured with the rest unless run with -s).
+_HANG_LIMIT_S = 600
+
+
+def _attempts_dir(run_uid):
+    return os.path.join(tempfile.gettempdir(), f"tm_test_attempts_{run_uid}")
+
+
+@pytest.fixture(autouse=True)
+def _hang_guard(request):
+    run = os.environ.get("PYTEST_XDIST_TESTRUNUID")
+    mark = None
+    if run is not None:
+        os.makedirs(_attempts_dir(run), exist_ok=True)
+        mark = os.path.join(
+            _attempts_dir(run), hashlib.sha1(request.node.nodeid.encode()).hexdigest()
+        )
+        if os.path.exists(mark):
+            with open(mark) as fh:
+                pytest.fail(
+                    "an earlier worker of this run did not come back from this test "
+                    f"(stuck for {_HANG_LIMIT_S} s, or crashed); its stacks:\n{fh.read()}"
+                )
+    with open(mark, "w") if mark else contextlib.nullcontext(sys.__stderr__) as fh:
+        faulthandler.dump_traceback_later(_HANG_LIMIT_S, exit=True, file=fh)
+        yield
+        faulthandler.cancel_dump_traceback_later()
+    if mark:
+        os.remove(mark)
 
 
 def load_check_metrics_lint():

@@ -249,7 +249,11 @@ def test_chaos_exec_batch_faults_node_still_commits(tmp_path):
         faults.arm("exec.batch", "raise", p=0.3, seed=CHAOS_SEED)
 
         privs, balances = igen.accounts(4)
-        txs = igen.make_transfers(privs, 24, amount=1, fee=1)
+        # the site is evaluated once per block that carries txs, and the
+        # seeded stream fires on the 4th evaluation: transfers go in one
+        # block's worth at a time until the site's own counter moves,
+        # because how many blocks a fixed lot lands in is up to timing
+        txs = igen.make_transfers(privs, 64, amount=1, fee=1)
         cache = SC()
         app = PaymentsApplication(dict(balances), sig_cache=cache)
         genesis, vals = make_genesis(1)
@@ -275,9 +279,25 @@ def test_chaos_exec_batch_faults_node_still_commits(tmp_path):
                         await asyncio.sleep(0.02)
                 return False
 
-            ok = await asyncio.gather(*(submit_with_retry(t) for t in txs))
-            assert all(ok), "admission starved a tx past 20 retries"
-            await node.cs.wait_for_height(5, timeout_s=90)
+            loop = asyncio.get_running_loop()
+            deadline = loop.time() + 90  # the node's height deadline bounds it all
+
+            async def next_height():
+                await node.cs.wait_for_height(
+                    node.cs.state.last_block_height + 1,
+                    timeout_s=max(0.0, deadline - loop.time()),
+                )
+
+            sent = 0
+            while not faults.stats()["sites"]["exec.batch"]["triggers"]:
+                assert sent < len(txs), "exec.batch chaos never fired in 8 blocks with txs"
+                wave, sent = txs[sent : sent + 8], sent + 8
+                ok = await asyncio.gather(*(submit_with_retry(t) for t in wave))
+                assert all(ok), "admission starved a tx past 20 retries"
+                while node.mempool.size():
+                    await next_height()
+            while node.cs.state.last_block_height < 5:
+                await next_height()
         finally:
             st = faults.stats()["sites"]
             exec_stats = node.cs._block_exec.exec_stats()

@@ -3,30 +3,34 @@ that demonstrably skips compilation.
 
 Two fresh interpreters compile the same verify bucket against the same
 JAX_COMPILATION_CACHE_DIR; the second must hit the cache (entries
-written by the first, and a much faster cold start)."""
+written by the first, JAX's own hit and miss counts in the second)."""
 
 import json
 import os
 import subprocess
 import sys
-import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _CHILD = r"""
-import json, os, sys, time
+import collections, json, os, sys
+import jax.monitoring
+events = collections.Counter()
+jax.monitoring.register_event_listener(lambda name, **kw: events.update([name]))
 from tendermint_tpu.models.verifier import VerifierModel
 import __graft_entry__ as g
 
 model = VerifierModel()
 pks, msgs, sigs = g._example_batch(16)
-t0 = time.perf_counter()
 ok = model.verify(pks, msgs, sigs)
-secs = time.perf_counter() - t0
 assert ok.all(), "valid signatures must verify"
 cache = os.environ["JAX_COMPILATION_CACHE_DIR"]
 entries = len(os.listdir(cache)) if os.path.isdir(cache) else 0
-print(json.dumps({"first_call_s": secs, "cache_entries": entries}))
+print(json.dumps({
+    "cache_entries": entries,
+    "hits": events["/jax/compilation_cache/cache_hits"],
+    "misses": events["/jax/compilation_cache/cache_misses"],
+}))
 """
 
 
@@ -59,5 +63,9 @@ def test_second_process_hits_persistent_cache(tmp_path):
     second = _run(cache)
     # deterministic signal: the second process compiled NOTHING new
     assert second["cache_entries"] == first["cache_entries"], (first, second)
-    # secondary (timing) signal: loading executables beats compiling them
-    assert second["first_call_s"] < first["first_call_s"] / 2, (first, second)
+    # ...because every program it asked for was in the cache (JAX's own
+    # counters; how much faster a load is than a compile is a timing on
+    # a shared host, and no test's to judge)
+    assert first["misses"] >= first["cache_entries"], (first, second)
+    assert second["hits"] >= first["cache_entries"], (first, second)
+    assert second["misses"] == 0, (first, second)

@@ -4,68 +4,25 @@ The sharded program (shard_map over the virtual 8-device CPU mesh the
 conftest forces) must accept EXACTLY the rows the single-device program
 accepts and tally identically — including rows corrupted in every
 shard, uneven (non-divisible) batch sizes, and non-uniform voting
-powers. The driver's dryrun_multichip re-checks this at 4k rows.
+powers. The driver's dryrun_multichip re-checks this at 4k rows. The
+cached-table programs' parity is in test_mesh_parity_tabled.py: a file
+is one worker's under the tier-1 run's --dist loadfile. Both take the
+signed batch and the two models from tests/mesh_helpers.py.
 """
 
 import numpy as np
-import pytest
 
-jax = pytest.importorskip("jax")
-
-from tendermint_tpu.models.verifier import VerifierModel
-from tendermint_tpu.parallel import make_mesh
-
-N_DEV = 8
-
-
-def _signed_batch(n, msg_len=96, seed=11):
-    try:
-        from cryptography.hazmat.primitives import serialization
-        from cryptography.hazmat.primitives.asymmetric.ed25519 import (
-            Ed25519PrivateKey,
-        )
-    except ImportError:  # no OpenSSL wheel: pure-Python fallback
-        from tendermint_tpu.crypto.fallback import Ed25519PrivateKey, serialization
-
-    rng = np.random.RandomState(seed)
-    keys = [
-        Ed25519PrivateKey.from_private_bytes(bytes(rng.bytes(32)))
-        for _ in range(min(n, 16))
-    ]
-    pubs = [
-        k.public_key().public_bytes(
-            serialization.Encoding.Raw, serialization.PublicFormat.Raw
-        )
-        for k in keys
-    ]
-    pks = np.zeros((n, 32), dtype=np.uint8)
-    msgs = np.zeros((n, msg_len), dtype=np.uint8)
-    sigs = np.zeros((n, 64), dtype=np.uint8)
-    for i in range(n):
-        msg = rng.bytes(msg_len)
-        pks[i] = np.frombuffer(pubs[i % len(keys)], dtype=np.uint8)
-        msgs[i] = np.frombuffer(msg, dtype=np.uint8)
-        sigs[i] = np.frombuffer(keys[i % len(keys)].sign(msg), dtype=np.uint8)
-    return pks, msgs, sigs
-
-
-@pytest.fixture(scope="module")
-def models():
-    devs = jax.devices()
-    if len(devs) < N_DEV:
-        pytest.skip(f"need {N_DEV} virtual devices, have {len(devs)}")
-    return (
-        VerifierModel(mesh=make_mesh(devs[:N_DEV]), block_on_compile=True),
-        VerifierModel(block_on_compile=True),
-    )
+from tests.mesh_helpers import N_DEV, models, signed_batch  # noqa: F401  (models: the fixture)
 
 
 def test_mesh_parity_mixed_rows_per_shard_negatives(models):
     mesh_m, single_m = models
-    n = 1024  # bucket-exact; 128 rows per shard
-    pk, mg, sg = _signed_batch(n)
+    # bucket-exact, 32 rows per shard; the same 256 bucket as the
+    # uneven batch below, so the two share their compiled programs
+    n = 256
+    pk, mg, sg = signed_batch(n)
     shard = n // N_DEV
-    bad = [s * shard + 7 * s for s in range(N_DEV)]  # one per shard
+    bad = [s * shard + 3 * s for s in range(N_DEV)]  # one per shard
     for r in bad:
         sg[r, 9] ^= 0x20
     powers = np.arange(1, n + 1, dtype=np.int64)
@@ -85,7 +42,7 @@ def test_mesh_parity_mixed_rows_per_shard_negatives(models):
 def test_mesh_parity_uneven_batch(models):
     mesh_m, single_m = models
     n = 137  # not divisible by 8: exercises pad/remainder handling
-    pk, mg, sg = _signed_batch(n, seed=12)
+    pk, mg, sg = signed_batch(n, seed=12)
     sg[0, 0] ^= 1
     sg[n - 1, 63] ^= 0x80
     powers = np.full(n, 5, dtype=np.int64)
@@ -97,57 +54,10 @@ def test_mesh_parity_uneven_batch(models):
     assert not ok_m[0] and not ok_m[n - 1] and ok_m[1 : n - 1].all()
 
 
-def test_mesh_parity_tabled_path(models):
-    """The per-valset cached-table path on a mesh (rows sharded, tables
-    replicated) must match the single-device tabled path bit-for-bit."""
-    mesh_m, single_m = models
-    n = 128
-    pk, mg, sg = _signed_batch(n, seed=14)
-    all_pk = pk[:16].copy()  # 16 distinct keys repeated: valset matrix
-    idx = (np.arange(n) % 16).astype(np.int32)
-    sg[9] = 0
-    sg[77, 3] ^= 1
-    ok_m = mesh_m.verify_rows_cached(b"mesh-valset", all_pk, idx, mg, sg)
-    ok_s = single_m.verify_rows_cached(b"mesh-valset", all_pk, idx, mg, sg)
-    assert ok_m is not None and ok_s is not None
-    np.testing.assert_array_equal(ok_m, ok_s)
-    assert not ok_m[9] and not ok_m[77] and ok_m.sum() == n - 2
-
-
-def test_mesh_parity_tabled_templated_path(models):
-    """The TEMPLATED tabled path (templates replicate, per-row columns
-    shard, rows materialize on device) must match the materialized
-    mesh run and the single-device templated run bit-for-bit."""
-    mesh_m, single_m = models
-    n = 128
-    pk, mg, sg = _signed_batch(n, seed=14)
-    all_pk = pk[:16].copy()
-    idx = (np.arange(n) % 16).astype(np.int32)
-    sg[9] = 0
-    sg[77, 3] ^= 1
-    # each row as its own template with the ts span spliced out:
-    # materialization must reproduce mg exactly
-    templates = mg.copy()
-    templates[:, 93:101] = 0
-    ts8 = mg[:, 93:101].copy()
-    tmpl_idx = np.arange(n, dtype=np.int32)
-    ok_mat = mesh_m.verify_rows_cached(b"mesh-valset-t", all_pk, idx, mg, sg)
-    ok_m = mesh_m.verify_rows_cached_templated(
-        b"mesh-valset-t", all_pk, idx, templates, tmpl_idx, ts8, sg
-    )
-    ok_s = single_m.verify_rows_cached_templated(
-        b"mesh-valset-t", all_pk, idx, templates, tmpl_idx, ts8, sg
-    )
-    assert ok_mat is not None and ok_m is not None and ok_s is not None
-    np.testing.assert_array_equal(ok_m, ok_mat)
-    np.testing.assert_array_equal(ok_m, ok_s)
-    assert not ok_m[9] and not ok_m[77] and ok_m.sum() == n - 2
-
-
 def test_mesh_parity_verify_only_path(models):
     mesh_m, single_m = models
     n = 64
-    pk, mg, sg = _signed_batch(n, seed=13)
+    pk, mg, sg = signed_batch(n, seed=13)
     sg[17] = 0
     ok_m = mesh_m.verify(pk, mg, sg)
     ok_s = single_m.verify(pk, mg, sg)

@@ -19,7 +19,7 @@ when a mesh is live) is pinned at the bottom.
 import numpy as np
 import pytest
 
-from test_mesh_parity import _signed_batch
+from tests.mesh_helpers import signed_batch
 
 from tendermint_tpu.crypto.batch import CPUBatchVerifier, MeshRoutedVerifier
 from tendermint_tpu.parallel import DeviceTopology, MeshRouter
@@ -88,7 +88,7 @@ def test_tripped_breaker_sheds_shard_to_survivors_verdicts_intact():
     r = _logical_router(n=4, min_rows=4)
     v = MeshRoutedVerifier(CPUBatchVerifier(), r)
     n = 64
-    pk, mg, sg = _signed_batch(n, seed=31)
+    pk, mg, sg = signed_batch(n, seed=31)
     sg[5, 0] ^= 1
     sg[33, 1] ^= 2
     powers = np.arange(1, n + 1, dtype=np.int64)
@@ -121,7 +121,7 @@ def test_all_shed_degrades_to_single_path():
     v = MeshRoutedVerifier(CPUBatchVerifier(), r)
     for b in r.topology.breakers:
         b.force_open()
-    pk, mg, sg = _signed_batch(16, seed=32)
+    pk, mg, sg = signed_batch(16, seed=32)
     ok = v.verify_batch(pk, mg, sg)
     np.testing.assert_array_equal(ok, CPUBatchVerifier().verify_batch(pk, mg, sg))
     st = r.stats()
@@ -134,7 +134,7 @@ def test_all_shed_degrades_to_single_path():
 def test_half_open_probe_readmits_recovered_device():
     r = _logical_router(n=4, min_rows=4)
     v = MeshRoutedVerifier(CPUBatchVerifier(), r)
-    pk, mg, sg = _signed_batch(32, seed=33)
+    pk, mg, sg = signed_batch(32, seed=33)
     want = CPUBatchVerifier().verify_batch(pk, mg, sg)
 
     sick = r.topology.breakers[1]
@@ -205,7 +205,7 @@ def one_dev_router():
 def test_one_device_mesh_verifier_bit_identical(one_dev_router):
     from tendermint_tpu.crypto.batch import TPUBatchVerifier
 
-    pk, mg, sg = _signed_batch(64, seed=21)
+    pk, mg, sg = signed_batch(64, seed=21)
     sg[7, 0] ^= 1
     meshed = TPUBatchVerifier(block_on_compile=True, router=one_dev_router)
     plain = TPUBatchVerifier(block_on_compile=True)

@@ -1,0 +1,198 @@
+"""Per-valset cached-table verify path (round 3): the commit-shaped
+path — 160-byte sign bytes, the valset-size bucket warmed at node
+start, templated rows materialized on the device, and
+ValidatorSet.verify_commit through the provider. The stage kernels are
+in test_tabled_kernels.py, the model's materialized path in
+test_tabled_verify.py, sharded tables and cross-height batches in
+test_tabled_batches.py.
+"""
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+
+from tendermint_tpu.ops import ref_ed25519 as ref
+from tests.tabled_helpers import arrs, sign_rows
+
+
+def test_register_valset_prewarms_tabled_path():
+    """Node-start warmup: register_valset builds tables + warms the
+    valset-size bucket so the FIRST live verify uses the cached path
+    (blocking mode: immediately; non-blocking: after the background
+    build completes)."""
+    import time as _time
+
+    from tendermint_tpu.models.verifier import VerifierModel
+
+    # msg_len 160 = the commit sign-bytes width register_valset warms
+    pks, msgs, sigs = sign_rows(12, msg_len=160, seed=19)
+    pk, mg, sg = arrs(pks, msgs, sigs)
+    idx = np.arange(12, dtype=np.int32)
+
+    m = VerifierModel(block_on_compile=True)
+    m.register_valset(b"boot-valset", pk)
+    assert len(m._valset_tables) == 1
+    ok = m.verify_rows_cached(b"boot-valset", pk, idx, mg, sg)
+    assert ok is not None and ok.all()
+    assert len(m._valset_tables) == 1  # no rebuild
+
+    # Non-blocking: the warmup ALONE (no live traffic) must build the
+    # tables and warm the valset-size bucket — polled WITHOUT calling
+    # verify_rows_cached, which would otherwise kick the lazy build
+    # itself and mask a broken warmup.
+    m2 = VerifierModel(block_on_compile=False)
+    m2.register_valset(b"boot-valset-2", pk)
+    deadline = _time.monotonic() + 120
+    warmed = False
+    while _time.monotonic() < deadline:
+        e = m2._valset_tables.get(b"boot-valset-2")
+        if e is not None and e.ready:
+            rows = int(e.tables.shape[0])
+            ent = m2._entries.get(("tabled", 16, 160, 0, rows, 1))
+            ent_t = m2._entries.get(("tabled-tpl", 16, 160, 2, rows, 1))
+            if ent is not None and ent.ready and ent_t is not None and ent_t.ready:
+                warmed = True
+                break
+        _time.sleep(0.25)
+    assert warmed, "warmup alone never built tables + warmed the bucket"
+    # and the first live call is served immediately (no None fallback)
+    ok2 = m2.verify_rows_cached(b"boot-valset-2", pk, idx, mg, sg)
+    assert ok2 is not None and ok2.all()
+
+
+def _templated_rows(n, n_templates=3, seed=11):
+    """Signed rows whose messages are template[tmpl_idx] with an 8-byte
+    splice at the sign-bytes timestamp offset (93:101) — the exact
+    shape materialize_sign_bytes reconstructs on device."""
+    rng = np.random.default_rng(seed)
+    templates = rng.integers(0, 256, size=(n_templates, 160)).astype(np.uint8)
+    tmpl_idx = rng.integers(0, n_templates, size=n).astype(np.int32)
+    ts8 = rng.integers(0, 256, size=(n, 8)).astype(np.uint8)
+    msgs = templates[tmpl_idx].copy()
+    msgs[:, 93:101] = ts8
+    seeds = [rng.bytes(32) for _ in range(n)]
+    pks = np.frombuffer(
+        b"".join(ref.pubkey_from_seed(s) for s in seeds), dtype=np.uint8
+    ).reshape(n, 32)
+    sigs = np.frombuffer(
+        b"".join(ref.sign(s, m.tobytes()) for s, m in zip(seeds, msgs)),
+        dtype=np.uint8,
+    ).reshape(n, 64)
+    return pks, templates, tmpl_idx, ts8, msgs, sigs
+
+
+def test_templated_rows_cached_matches_materialized():
+    """verify_rows_cached_templated must accept/reject bit-identically
+    to verify_rows_cached on the materialized messages — dense shape,
+    gathered subset (with duplicates), and corrupted rows."""
+    from tendermint_tpu.models.verifier import VerifierModel
+
+    n = 16  # the 16-row bucket the other tests of this file compile
+    pks, templates, tmpl_idx, ts8, msgs, sigs = _templated_rows(n)
+    sigs = sigs.copy()
+    sigs[5, 3] ^= 1
+    ts8_bad = ts8.copy()
+    ts8_bad[9] ^= 0xFF  # wrong timestamp => wrong sign bytes => reject
+
+    m = VerifierModel(block_on_compile=True)
+    key = b"tpl-parity"
+    idx = np.arange(n, dtype=np.int32)
+    ok_mat = m.verify_rows_cached(key, pks, idx, msgs, sigs)
+    ok_tpl = m.verify_rows_cached_templated(
+        key, pks, idx, templates, tmpl_idx, ts8, sigs
+    )
+    assert ok_mat is not None and ok_tpl is not None
+    np.testing.assert_array_equal(ok_mat, ok_tpl)
+    assert not ok_tpl[5] and ok_tpl.sum() == n - 1
+
+    ok_bad_ts = m.verify_rows_cached_templated(
+        key, pks, idx, templates, tmpl_idx, ts8_bad, sigs
+    )
+    assert not ok_bad_ts[9] and ok_bad_ts.sum() == n - 2
+
+    # gathered shape with duplicate validator indices
+    sub = np.array([3, 3, 11, 0, 7, 15], dtype=np.int32)
+    ok_sub = m.verify_rows_cached_templated(
+        key, pks, sub, templates, tmpl_idx[sub], ts8[sub], sigs[sub]
+    )
+    assert ok_sub is not None
+    np.testing.assert_array_equal(ok_sub, np.ones(len(sub), dtype=bool))
+
+
+def test_templated_windowed_boundary_controls(monkeypatch):
+    """The templated source through the >MAX_DEVICE_ROWS streaming path:
+    invalid rows planted across every window boundary, same controls as
+    the materialized windowed test."""
+    from tendermint_tpu.models import verifier as vmod
+
+    monkeypatch.setattr(vmod, "MAX_DEVICE_ROWS", 16)
+    pks, templates, tmpl_idx, ts8, msgs, sigs = _templated_rows(16, seed=29)
+    n = 42  # 2 full windows of 16 + tail of 10
+    rng = np.random.default_rng(5)
+    idx = rng.integers(0, 16, size=n).astype(np.int32)
+    ti = tmpl_idx[idx].copy()
+    t8 = ts8[idx].copy()
+    sg = sigs[idx].copy()
+    bad = [0, 15, 16, 31, 32, 41]
+    for b in bad:
+        sg[b, 7] ^= 0x08
+    m = vmod.VerifierModel(block_on_compile=True)
+    ok = m.verify_rows_cached_templated(b"tpl-win", pks, idx, templates, ti, t8, sg)
+    assert ok is not None and ok.shape == (n,)
+    want = np.ones(n, dtype=bool)
+    want[bad] = False
+    np.testing.assert_array_equal(ok, want)
+
+    # non-blocking with cold buckets: nothing dispatches, caller falls back
+    m2 = vmod.VerifierModel(block_on_compile=False)
+    assert (
+        m2.verify_rows_cached_templated(b"tpl-win-2", pks, idx, templates, ti, t8, sg)
+        is None
+    )
+
+
+def test_validator_set_verify_commit_uses_cached_tables():
+    """End-to-end: ValidatorSet.verify_commit through a TPU provider must
+    accept/reject identically to the CPU provider, and hit the cached
+    path (table cache populated)."""
+    from tendermint_tpu.crypto.batch import CPUBatchVerifier, TPUBatchVerifier
+    from tendermint_tpu.state.state import state_from_genesis_doc
+    from tests.cs_harness import make_genesis
+
+    genesis, privs = make_genesis(6)
+    st = state_from_genesis_doc(genesis)
+    vals = st.validators
+    from tendermint_tpu.codec.signbytes import PRECOMMIT_TYPE
+    from tendermint_tpu.types.block import BlockID, PartSetHeader
+    from tendermint_tpu.types.vote import Vote
+    from tendermint_tpu.types.vote_set import VoteSet
+
+    bid = BlockID(hash=b"\x21" * 32, parts=PartSetHeader(total=2, hash=b"\x22" * 32))
+    by_addr = {pv.address(): pv for pv in privs}
+    ordered = [by_addr[v.address] for v in vals.validators]
+    vs = VoteSet(genesis.chain_id, 3, 0, PRECOMMIT_TYPE, vals)
+    for i, pv in enumerate(ordered):
+        v = Vote(
+            vote_type=PRECOMMIT_TYPE, height=3, round=0, block_id=bid,
+            timestamp_ns=9000 + i, validator_address=pv.address(),
+            validator_index=i,
+        )
+        v.signature = pv.priv_key.sign(v.sign_bytes(genesis.chain_id))
+        assert vs.add_vote(v)
+    commit = vs.make_commit()
+
+    tpu = TPUBatchVerifier(block_on_compile=True, min_device_batch=2)
+    vals.verify_commit(genesis.chain_id, bid, 3, commit, provider=tpu)  # no raise
+    assert len(tpu.model._valset_tables) == 1  # cached path exercised
+    cpu = CPUBatchVerifier()
+    vals.verify_commit(genesis.chain_id, bid, 3, commit, provider=cpu)
+
+    # corrupt one signature: both providers must reject identically
+    bad = commit.signatures[2]
+    bad.signature = bad.signature[:10] + bytes([bad.signature[10] ^ 1]) + bad.signature[11:]
+    from tendermint_tpu.types.validator_set import ErrInvalidCommitSignature
+
+    for prov in (tpu, cpu):
+        with pytest.raises(ErrInvalidCommitSignature):
+            vals.verify_commit(genesis.chain_id, bid, 3, commit, provider=prov)

@@ -1,11 +1,9 @@
-"""Per-valset cached-table verify path (round 3).
-
-The tabled pipeline (ops/ed25519.verify_stage_*_tabled +
-curve.build_split_tables) must accept EXACTLY the signatures the generic
-kernel and the host reference accept — it is an optimization of the
-same Go x/crypto acceptance (crypto/ed25519/ed25519.go:151), keyed on
-the fact that validator pubkeys are stable across heights
-(types/validator_set.go:641 re-verifies the same keys every block).
+"""Per-valset cached-table verify path (round 3): VerifierModel's
+cached path on materialized rows — build, bucket warm-up, fallbacks,
+the windowed stream, the on-disk table cache. The stage kernels are in
+test_tabled_kernels.py, the commit-shaped (templated, provider-level)
+path in test_tabled_templated.py, sharded tables and cross-height
+batches in test_tabled_batches.py.
 """
 
 import os
@@ -14,136 +12,17 @@ import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
-import jax.numpy as jnp  # noqa: E402
 
-from tendermint_tpu.ops import curve, ed25519 as E, field as F, ref_ed25519 as ref
-
-
-def _sign_rows(n, msg_len=100, seed=7):
-    rng = np.random.default_rng(seed)
-    seeds = [rng.bytes(32) for _ in range(n)]
-    pks = [ref.pubkey_from_seed(s) for s in seeds]
-    msgs = [rng.bytes(msg_len) for _ in range(n)]
-    sigs = [ref.sign(s, m) for s, m in zip(seeds, msgs)]
-    return pks, msgs, sigs
-
-
-def _arrs(pks, msgs, sigs):
-    n = len(pks)
-    return (
-        np.frombuffer(b"".join(pks), dtype=np.uint8).reshape(n, 32),
-        np.frombuffer(b"".join(msgs), dtype=np.uint8).reshape(n, len(msgs[0])),
-        np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(n, 64),
-    )
-
-
-# Module-level jitted wrappers: a fresh jax.jit() per call would retrace
-# every time; one wrapper per stage keeps the whole file to one compile
-# per distinct shape.
-_BUILD = jax.jit(E.build_valset_tables)
-_S1 = jax.jit(E.verify_stage_prepare_tabled)
-_S2 = jax.jit(E.verify_stage_scan_tabled)
-_S3 = jax.jit(E.verify_stage_finish_blocked)
-
-
-def _tabled_ok(pk, mg, sg, idx=None, tables=None, a_ok=None):
-    pk, mg, sg = jnp.asarray(pk), jnp.asarray(mg), jnp.asarray(sg)
-    if tables is None:
-        tables, a_ok = _BUILD(pk)
-    if idx is None:
-        idx = jnp.arange(pk.shape[0], dtype=jnp.int32)
-    sd, kd, s_ok = _S1(pk, mg, sg)
-    px, py, pz, pt, aok = _S2(sd, kd, tables, a_ok, jnp.asarray(idx))
-    return np.asarray(_S3(px, py, pz, pt, sg, aok, s_ok))
-
-
-def test_invert_blocked_matches_fermat():
-    rng = np.random.default_rng(3)
-    vals = [int(rng.integers(1, 2**62)) ** 2 % F.P for _ in range(48)]
-    vals[5] = 0
-    vals[17] = F.P - 1
-    z = jnp.asarray(np.stack([F.to_limbs(v) for v in vals]))
-    inv = np.asarray(jax.jit(F.invert_blocked)(z))
-    for i, v in enumerate(vals):
-        assert F.from_limbs(inv[i]) == (pow(v, F.P - 2, F.P) if v else 0)
-
-
-def test_split_tables_are_reference_multiples():
-    q_ref = ref.pt_mul(11, ref.pt_from_affine(*ref.BASE))
-    qx, qy = ref.pt_to_affine(q_ref)
-    pt = curve.Point(
-        jnp.asarray(F.to_limbs(qx))[None],
-        jnp.asarray(F.to_limbs(qy))[None],
-        jnp.asarray(F.to_limbs(1))[None],
-        jnp.asarray(F.to_limbs(qx * qy % ref.P))[None],
-    )
-    tbl = np.asarray(jax.jit(curve.build_split_tables)(pt))
-    for m in (0, 3, curve.SPLITS - 1):
-        for i in (0, 7):
-            want = ref.pt_to_affine(
-                ref.pt_mul((i + 1) * 16 ** (curve.SPLIT_W * m), q_ref)
-            )
-            got = tbl[0, m, i].reshape(3, F.LIMBS)
-            assert F.from_limbs(got[0]) == (want[1] + want[0]) % ref.P
-            assert F.from_limbs(got[1]) == (want[1] - want[0]) % ref.P
-            assert F.from_limbs(got[2]) == 2 * ref.D * want[0] * want[1] % ref.P
-
-
-def test_tabled_matches_generic_and_reference():
-    pks, msgs, sigs = _sign_rows(16)
-    # corruptions across every rejection class
-    sigs[1] = sigs[1][:5] + bytes([sigs[1][5] ^ 0x40]) + sigs[1][6:]  # bad R
-    sigs[2] = sigs[2][:33] + bytes([sigs[2][33] ^ 1]) + sigs[2][34:]  # bad s
-    sigs[4] = sigs[4][:32] + (
-        int.from_bytes(sigs[4][32:], "little") + ref.L
-    ).to_bytes(32, "little")  # non-canonical s
-    msgs[6] = msgs[6][:-1] + bytes([msgs[6][-1] ^ 1])  # wrong msg
-    pk, mg, sg = _arrs(pks, msgs, sigs)
-    want = np.array([ref.verify(p, m, s) for p, m, s in zip(pks, msgs, sigs)])
-    assert not want.all() and want.any()
-    generic = np.asarray(
-        jax.jit(E.verify_core)(jnp.asarray(pk), jnp.asarray(mg), jnp.asarray(sg))
-    )
-    tabled = _tabled_ok(pk, mg, sg)
-    np.testing.assert_array_equal(generic, want)
-    np.testing.assert_array_equal(tabled, want)
-
-
-def test_tabled_gather_subset_and_duplicates():
-    pks, msgs, sigs = _sign_rows(16, seed=9)
-    pk, mg, sg = _arrs(pks, msgs, sigs)
-    tables, a_ok = _BUILD(jnp.asarray(pk))
-    # subset with a duplicate validator index (trusting-path shape);
-    # length 16 keeps the stage shapes shared with the other tests
-    idx = np.array([3, 3, 8, 15, 0, 12, 1, 2, 4, 5, 6, 7, 9, 10, 11, 14], dtype=np.int32)
-    ok = _tabled_ok(pk[idx], mg[idx], sg[idx], idx=idx, tables=tables, a_ok=a_ok)
-    assert ok.all()
-    # same subset, one row signed by the WRONG validator's key
-    sg2 = sg[idx].copy()
-    sg2[2] = sg[1]
-    want = np.ones(16, dtype=bool)
-    want[2] = False
-    ok2 = _tabled_ok(pk[idx], mg[idx], sg2, idx=idx, tables=tables, a_ok=a_ok)
-    np.testing.assert_array_equal(ok2, want)
-
-
-def test_tabled_rejects_non_decompressible_key():
-    pks, msgs, sigs = _sign_rows(16, seed=11)
-    bad_y = next(c for c in range(2, 100) if ref._recover_x(c, 0) is None)
-    pks[0] = bad_y.to_bytes(32, "little")
-    pk, mg, sg = _arrs(pks, msgs, sigs)
-    ok = _tabled_ok(pk, mg, sg)
-    want = np.array([ref.verify(p, m, s) for p, m, s in zip(pks, msgs, sigs)])
-    assert not want[0]
-    np.testing.assert_array_equal(ok, want)
+from tendermint_tpu.ops import ref_ed25519 as ref
+from tests.tabled_helpers import arrs, sign_rows
 
 
 def test_verifier_model_rows_cached_and_fallback():
     from tendermint_tpu.models.verifier import VerifierModel
 
-    pks, msgs, sigs = _sign_rows(12, seed=13)
+    pks, msgs, sigs = sign_rows(12, seed=13)
     sigs[5] = bytes(64)
-    pk, mg, sg = _arrs(pks, msgs, sigs)
+    pk, mg, sg = arrs(pks, msgs, sigs)
     want = np.array([ref.verify(p, m, s) for p, m, s in zip(pks, msgs, sigs)])
 
     m = VerifierModel(block_on_compile=True)
@@ -161,8 +40,8 @@ def test_verifier_model_rows_cached_and_fallback():
 def test_verifier_model_nonblocking_cold_returns_none():
     from tendermint_tpu.models.verifier import VerifierModel
 
-    pks, msgs, sigs = _sign_rows(4, seed=17)
-    pk, mg, sg = _arrs(pks, msgs, sigs)
+    pks, msgs, sigs = sign_rows(4, seed=17)
+    pk, mg, sg = arrs(pks, msgs, sigs)
     m = VerifierModel(block_on_compile=False)
     out = m.verify_rows_cached(b"k2", pk, np.arange(4, dtype=np.int32), mg, sg)
     assert out is None  # cold: background build kicked off, caller falls back
@@ -184,8 +63,8 @@ def test_failed_table_build_latches_to_generic_fallback(monkeypatch):
     verification — and must NOT be retried on every verify."""
     from tendermint_tpu.models.verifier import VerifierModel
 
-    pks, msgs, sigs = _sign_rows(8, seed=29)
-    pk, mg, sg = _arrs(pks, msgs, sigs)
+    pks, msgs, sigs = sign_rows(8, seed=29)
+    pk, mg, sg = arrs(pks, msgs, sigs)
     idx = np.arange(8, dtype=np.int32)
 
     m = VerifierModel(block_on_compile=True)
@@ -201,237 +80,6 @@ def test_failed_table_build_latches_to_generic_fallback(monkeypatch):
     assert len(calls) == 1, "doomed build retried"
 
 
-def test_register_valset_prewarms_tabled_path():
-    """Node-start warmup: register_valset builds tables + warms the
-    valset-size bucket so the FIRST live verify uses the cached path
-    (blocking mode: immediately; non-blocking: after the background
-    build completes)."""
-    import time as _time
-
-    from tendermint_tpu.models.verifier import VerifierModel
-
-    # msg_len 160 = the commit sign-bytes width register_valset warms
-    pks, msgs, sigs = _sign_rows(12, msg_len=160, seed=19)
-    pk, mg, sg = _arrs(pks, msgs, sigs)
-    idx = np.arange(12, dtype=np.int32)
-
-    m = VerifierModel(block_on_compile=True)
-    m.register_valset(b"boot-valset", pk)
-    assert len(m._valset_tables) == 1
-    ok = m.verify_rows_cached(b"boot-valset", pk, idx, mg, sg)
-    assert ok is not None and ok.all()
-    assert len(m._valset_tables) == 1  # no rebuild
-
-    # Non-blocking: the warmup ALONE (no live traffic) must build the
-    # tables and warm the valset-size bucket — polled WITHOUT calling
-    # verify_rows_cached, which would otherwise kick the lazy build
-    # itself and mask a broken warmup.
-    m2 = VerifierModel(block_on_compile=False)
-    m2.register_valset(b"boot-valset-2", pk)
-    deadline = _time.monotonic() + 120
-    warmed = False
-    while _time.monotonic() < deadline:
-        e = m2._valset_tables.get(b"boot-valset-2")
-        if e is not None and e.ready:
-            rows = int(e.tables.shape[0])
-            ent = m2._entries.get(("tabled", 16, 160, 0, rows, 1))
-            ent_t = m2._entries.get(("tabled-tpl", 16, 160, 2, rows, 1))
-            if ent is not None and ent.ready and ent_t is not None and ent_t.ready:
-                warmed = True
-                break
-        _time.sleep(0.25)
-    assert warmed, "warmup alone never built tables + warmed the bucket"
-    # and the first live call is served immediately (no None fallback)
-    ok2 = m2.verify_rows_cached(b"boot-valset-2", pk, idx, mg, sg)
-    assert ok2 is not None and ok2.all()
-
-
-def _templated_rows(n, n_templates=3, seed=11):
-    """Signed rows whose messages are template[tmpl_idx] with an 8-byte
-    splice at the sign-bytes timestamp offset (93:101) — the exact
-    shape materialize_sign_bytes reconstructs on device."""
-    rng = np.random.default_rng(seed)
-    templates = rng.integers(0, 256, size=(n_templates, 160)).astype(np.uint8)
-    tmpl_idx = rng.integers(0, n_templates, size=n).astype(np.int32)
-    ts8 = rng.integers(0, 256, size=(n, 8)).astype(np.uint8)
-    msgs = templates[tmpl_idx].copy()
-    msgs[:, 93:101] = ts8
-    seeds = [rng.bytes(32) for _ in range(n)]
-    pks = np.frombuffer(
-        b"".join(ref.pubkey_from_seed(s) for s in seeds), dtype=np.uint8
-    ).reshape(n, 32)
-    sigs = np.frombuffer(
-        b"".join(ref.sign(s, m.tobytes()) for s, m in zip(seeds, msgs)),
-        dtype=np.uint8,
-    ).reshape(n, 64)
-    return pks, templates, tmpl_idx, ts8, msgs, sigs
-
-
-def test_templated_rows_cached_matches_materialized():
-    """verify_rows_cached_templated must accept/reject bit-identically
-    to verify_rows_cached on the materialized messages — dense shape,
-    gathered subset (with duplicates), and corrupted rows."""
-    from tendermint_tpu.models.verifier import VerifierModel
-
-    n = 24
-    pks, templates, tmpl_idx, ts8, msgs, sigs = _templated_rows(n)
-    sigs = sigs.copy()
-    sigs[5, 3] ^= 1
-    ts8_bad = ts8.copy()
-    ts8_bad[9] ^= 0xFF  # wrong timestamp => wrong sign bytes => reject
-
-    m = VerifierModel(block_on_compile=True)
-    key = b"tpl-parity"
-    idx = np.arange(n, dtype=np.int32)
-    ok_mat = m.verify_rows_cached(key, pks, idx, msgs, sigs)
-    ok_tpl = m.verify_rows_cached_templated(
-        key, pks, idx, templates, tmpl_idx, ts8, sigs
-    )
-    assert ok_mat is not None and ok_tpl is not None
-    np.testing.assert_array_equal(ok_mat, ok_tpl)
-    assert not ok_tpl[5] and ok_tpl.sum() == n - 1
-
-    ok_bad_ts = m.verify_rows_cached_templated(
-        key, pks, idx, templates, tmpl_idx, ts8_bad, sigs
-    )
-    assert not ok_bad_ts[9] and ok_bad_ts.sum() == n - 2
-
-    # gathered shape with duplicate validator indices
-    sub = np.array([3, 3, 11, 0, 17, 23], dtype=np.int32)
-    ok_sub = m.verify_rows_cached_templated(
-        key, pks, sub, templates, tmpl_idx[sub], ts8[sub], sigs[sub]
-    )
-    assert ok_sub is not None
-    np.testing.assert_array_equal(ok_sub, np.ones(len(sub), dtype=bool))
-
-
-def test_templated_windowed_boundary_controls(monkeypatch):
-    """The templated source through the >MAX_DEVICE_ROWS streaming path:
-    invalid rows planted across every window boundary, same controls as
-    the materialized windowed test."""
-    from tendermint_tpu.models import verifier as vmod
-
-    monkeypatch.setattr(vmod, "MAX_DEVICE_ROWS", 16)
-    pks, templates, tmpl_idx, ts8, msgs, sigs = _templated_rows(16, seed=29)
-    n = 42  # 2 full windows of 16 + tail of 10
-    rng = np.random.default_rng(5)
-    idx = rng.integers(0, 16, size=n).astype(np.int32)
-    ti = tmpl_idx[idx].copy()
-    t8 = ts8[idx].copy()
-    sg = sigs[idx].copy()
-    bad = [0, 15, 16, 31, 32, 41]
-    for b in bad:
-        sg[b, 7] ^= 0x08
-    m = vmod.VerifierModel(block_on_compile=True)
-    ok = m.verify_rows_cached_templated(b"tpl-win", pks, idx, templates, ti, t8, sg)
-    assert ok is not None and ok.shape == (n,)
-    want = np.ones(n, dtype=bool)
-    want[bad] = False
-    np.testing.assert_array_equal(ok, want)
-
-    # non-blocking with cold buckets: nothing dispatches, caller falls back
-    m2 = vmod.VerifierModel(block_on_compile=False)
-    assert (
-        m2.verify_rows_cached_templated(b"tpl-win-2", pks, idx, templates, ti, t8, sg)
-        is None
-    )
-
-
-def test_sharded_tables_large_valset(monkeypatch, tmp_path):
-    """Valsets past MAX_TABLED_VALSET ride SHARDED tables (equal-size
-    shards, per-shard bounded gathers in one program) instead of
-    falling to the generic pipeline. Shrunk constants drive the real
-    code path on CPU: 20 validators, 8-row shards. Verdicts must match
-    the materialized/templated single-table semantics bit for bit, and
-    the shards must round-trip the disk cache (re-split on load)."""
-    from tendermint_tpu.models import aot_cache, verifier as vmod
-
-    monkeypatch.setenv("TM_TABLES_CACHE_DIR", str(tmp_path))
-    monkeypatch.setattr(vmod, "MAX_TABLED_VALSET", 8)
-    monkeypatch.setattr(vmod, "_TABLE_BUILD_CHUNK", 8)
-    monkeypatch.setattr(vmod, "MAX_SHARDED_VALSET", 64)
-
-    v = 20
-    pks, msgs, sigs = _sign_rows(v, msg_len=160, seed=31)
-    pk, mg16, sg16 = _arrs(pks, msgs, sigs)
-    rng = np.random.default_rng(9)
-    n = 33  # rows spanning all shards, with duplicates
-    idx = rng.integers(0, v, size=n).astype(np.int32)
-    mg = mg16[idx].copy()
-    sg = sg16[idx].copy()
-    bad = [0, 7, 8, 20, 32]
-    for b in bad:
-        sg[b, 5] ^= 0x10
-    m = vmod.VerifierModel(block_on_compile=True)
-    ok = m.verify_rows_cached(b"sharded-valset", pk, idx, mg, sg)
-    assert ok is not None, "sharded path unavailable"
-    e = m._valset_tables[b"sharded-valset"]
-    assert e.shards is not None and len(e.shards) == 8  # v_pad 64 / 8
-    want = np.ones(n, dtype=bool)
-    want[bad] = False
-    np.testing.assert_array_equal(ok, want)
-
-    # templated source over the same sharded entry
-    templates = mg.copy()
-    templates[:, 93:101] = 0
-    ts8 = mg[:, 93:101].copy()
-    ok_t = m.verify_rows_cached_templated(
-        b"sharded-valset", pk, idx, templates,
-        np.arange(n, dtype=np.int32), ts8, sg,
-    )
-    assert ok_t is not None
-    np.testing.assert_array_equal(ok_t, want)
-
-    # disk round-trip: a fresh model loads and RE-SPLITS the shards
-    m2 = vmod.VerifierModel(block_on_compile=True)
-    ok2 = m2.verify_rows_cached(b"sharded-valset", pk, idx, mg, sg)
-    assert ok2 is not None
-    e2 = m2._valset_tables[b"sharded-valset"]
-    assert e2.source == "disk" and e2.shards is not None and len(e2.shards) == 8
-    np.testing.assert_array_equal(ok2, want)
-
-    # past MAX_SHARDED_VALSET: tabled path declines (generic fallback)
-    monkeypatch.setattr(vmod, "MAX_SHARDED_VALSET", 16)
-    m3 = vmod.VerifierModel(block_on_compile=True)
-    assert m3.verify_rows_cached(b"sharded-valset-2", pk, idx, mg, sg) is None
-
-
-def test_cross_height_batch_rides_cached_tables():
-    """verify_commits_batched over heights sharing one valset (the
-    fast-sync / light-client sequential shape) must route through the
-    per-valset cached tables and accept/reject exactly like the CPU
-    provider per height."""
-    from tendermint_tpu.crypto.batch import CPUBatchVerifier, TPUBatchVerifier
-    from tendermint_tpu.types.validator_set import (
-        CommitVerifySpec,
-        verify_commits_batched,
-    )
-    from tests.light_helpers import CHAIN_ID, gen_chain, keys, valset
-
-    headers, valsets = gen_chain(10)
-    # corrupt height 4's commit
-    cs = headers[4].commit.signatures[1]
-    cs.signature = cs.signature[:12] + bytes([cs.signature[12] ^ 2]) + cs.signature[13:]
-
-    def specs():
-        return [
-            CommitVerifySpec(
-                valsets[h], CHAIN_ID, headers[h].commit.block_id,
-                h, headers[h].commit,
-            )
-            for h in range(1, 10)
-        ]
-
-    tpu = TPUBatchVerifier(block_on_compile=True, min_device_batch=2)
-    res_tpu = verify_commits_batched(specs(), provider=tpu)
-    res_cpu = verify_commits_batched(specs(), provider=CPUBatchVerifier())
-    assert len(tpu.model._valset_tables) == 1, "cached tables not used"
-    for h, (a, b) in enumerate(zip(res_tpu, res_cpu), start=1):
-        assert (a is None) == (b is None), (h, a, b)
-    assert res_tpu[3] is not None  # height 4 rejected
-    assert sum(1 for r in res_tpu if r is None) == 8
-
-
 def test_windowed_cached_path_boundary_controls(monkeypatch):
     """The >MAX_DEVICE_ROWS streaming path: shrink the window so CI
     drives full windows + tail with invalid rows planted on both sides
@@ -439,8 +87,8 @@ def test_windowed_cached_path_boundary_controls(monkeypatch):
     from tendermint_tpu.models import verifier as vmod
 
     monkeypatch.setattr(vmod, "MAX_DEVICE_ROWS", 16)
-    pks, msgs, sigs = _sign_rows(16, seed=23)
-    pk16, mg16, sg16 = _arrs(pks, msgs, sigs)
+    pks, msgs, sigs = sign_rows(16, seed=23)
+    pk16, mg16, sg16 = arrs(pks, msgs, sigs)
     n = 42  # 2 full windows of 16 + tail of 10
     rng = np.random.default_rng(5)
     idx = rng.integers(0, 16, size=n).astype(np.int32)
@@ -462,86 +110,6 @@ def test_windowed_cached_path_boundary_controls(monkeypatch):
     assert m2.verify_rows_cached(b"win-test-2", pk16, idx, mg, sg) is None
 
 
-def test_cross_height_batch_mixed_valsets_fall_back_correctly():
-    """Specs spanning DIFFERENT validator sets cannot share one table
-    cache — the batch must take the generic route and still
-    accept/reject per spec exactly like the CPU provider."""
-    from tendermint_tpu.crypto.batch import CPUBatchVerifier, TPUBatchVerifier
-    from tendermint_tpu.types.validator_set import (
-        CommitVerifySpec,
-        verify_commits_batched,
-    )
-    from tests.light_helpers import CHAIN_ID, gen_chain, keys
-
-    gen2 = keys(4, tag="mixed-gen2")
-    headers, valsets = gen_chain(8, key_changes={5: gen2})
-    cs = headers[6].commit.signatures[2]
-    cs.signature = cs.signature[:5] + bytes([cs.signature[5] ^ 1]) + cs.signature[6:]
-
-    def specs():
-        return [
-            CommitVerifySpec(
-                valsets[h], CHAIN_ID, headers[h].commit.block_id,
-                h, headers[h].commit,
-            )
-            for h in range(1, 8)
-        ]
-
-    tpu = TPUBatchVerifier(block_on_compile=True, min_device_batch=2)
-    res_tpu = verify_commits_batched(specs(), provider=tpu)
-    res_cpu = verify_commits_batched(specs(), provider=CPUBatchVerifier())
-    for h, (a, b) in enumerate(zip(res_tpu, res_cpu), start=1):
-        assert (a is None) == (b is None), (h, a, b)
-    assert res_tpu[5] is not None  # corrupted height 6 rejected
-    assert sum(1 for r in res_tpu if r is None) == 6
-
-
-def test_validator_set_verify_commit_uses_cached_tables():
-    """End-to-end: ValidatorSet.verify_commit through a TPU provider must
-    accept/reject identically to the CPU provider, and hit the cached
-    path (table cache populated)."""
-    from tendermint_tpu.crypto.batch import CPUBatchVerifier, TPUBatchVerifier
-    from tendermint_tpu.state.state import state_from_genesis_doc
-    from tests.cs_harness import make_genesis
-
-    genesis, privs = make_genesis(6)
-    st = state_from_genesis_doc(genesis)
-    vals = st.validators
-    from tendermint_tpu.codec.signbytes import PRECOMMIT_TYPE
-    from tendermint_tpu.types.block import BlockID, PartSetHeader
-    from tendermint_tpu.types.vote import Vote
-    from tendermint_tpu.types.vote_set import VoteSet
-
-    bid = BlockID(hash=b"\x21" * 32, parts=PartSetHeader(total=2, hash=b"\x22" * 32))
-    by_addr = {pv.address(): pv for pv in privs}
-    ordered = [by_addr[v.address] for v in vals.validators]
-    vs = VoteSet(genesis.chain_id, 3, 0, PRECOMMIT_TYPE, vals)
-    for i, pv in enumerate(ordered):
-        v = Vote(
-            vote_type=PRECOMMIT_TYPE, height=3, round=0, block_id=bid,
-            timestamp_ns=9000 + i, validator_address=pv.address(),
-            validator_index=i,
-        )
-        v.signature = pv.priv_key.sign(v.sign_bytes(genesis.chain_id))
-        assert vs.add_vote(v)
-    commit = vs.make_commit()
-
-    tpu = TPUBatchVerifier(block_on_compile=True, min_device_batch=2)
-    vals.verify_commit(genesis.chain_id, bid, 3, commit, provider=tpu)  # no raise
-    assert len(tpu.model._valset_tables) == 1  # cached path exercised
-    cpu = CPUBatchVerifier()
-    vals.verify_commit(genesis.chain_id, bid, 3, commit, provider=cpu)
-
-    # corrupt one signature: both providers must reject identically
-    bad = commit.signatures[2]
-    bad.signature = bad.signature[:10] + bytes([bad.signature[10] ^ 1]) + bad.signature[11:]
-    from tendermint_tpu.types.validator_set import ErrInvalidCommitSignature
-
-    for prov in (tpu, cpu):
-        with pytest.raises(ErrInvalidCommitSignature):
-            vals.verify_commit(genesis.chain_id, bid, 3, commit, provider=prov)
-
-
 def test_tables_persist_to_disk_and_reload(tmp_path, monkeypatch):
     """Restart path: the built split tables are pure deterministic data,
     so a fresh model (fresh process analog) must LOAD them from disk —
@@ -551,9 +119,9 @@ def test_tables_persist_to_disk_and_reload(tmp_path, monkeypatch):
     from tendermint_tpu.models.verifier import VerifierModel
 
     monkeypatch.setenv("TM_TABLES_CACHE_DIR", str(tmp_path))
-    pks, msgs, sigs = _sign_rows(12, seed=31)
+    pks, msgs, sigs = sign_rows(12, seed=31)
     sigs[3] = bytes(64)
-    pk, mg, sg = _arrs(pks, msgs, sigs)
+    pk, mg, sg = arrs(pks, msgs, sigs)
     want = np.array([ref.verify(p, m, s) for p, m, s in zip(pks, msgs, sigs)])
     idx = np.arange(12, dtype=np.int32)
     key = b"persist-valset"
@@ -574,8 +142,8 @@ def test_tables_disk_corruption_falls_back_to_build(tmp_path, monkeypatch):
     from tendermint_tpu.models.verifier import VerifierModel
 
     monkeypatch.setenv("TM_TABLES_CACHE_DIR", str(tmp_path))
-    pks, msgs, sigs = _sign_rows(8, seed=37)
-    pk, mg, sg = _arrs(pks, msgs, sigs)
+    pks, msgs, sigs = sign_rows(8, seed=37)
+    pk, mg, sg = arrs(pks, msgs, sigs)
     idx = np.arange(8, dtype=np.int32)
     key = b"corrupt-valset"
 
@@ -599,8 +167,8 @@ def test_tables_disk_pubkey_mismatch_rebuilds(tmp_path, monkeypatch):
 
     monkeypatch.setenv("TM_TABLES_CACHE_DIR", str(tmp_path))
     key = b"reused-valset-key"
-    pks1, msgs1, sigs1 = _sign_rows(8, seed=41)
-    pk1, mg1, sg1 = _arrs(pks1, msgs1, sigs1)
+    pks1, msgs1, sigs1 = sign_rows(8, seed=41)
+    pk1, mg1, sg1 = arrs(pks1, msgs1, sigs1)
     idx = np.arange(8, dtype=np.int32)
 
     m1 = VerifierModel(block_on_compile=True)
@@ -608,8 +176,8 @@ def test_tables_disk_pubkey_mismatch_rebuilds(tmp_path, monkeypatch):
     assert m1._valset_tables[key].source == "build"
 
     # same key, DIFFERENT pubkeys: the persisted blob must be rejected
-    pks2, msgs2, sigs2 = _sign_rows(8, seed=43)
-    pk2, mg2, sg2 = _arrs(pks2, msgs2, sigs2)
+    pks2, msgs2, sigs2 = sign_rows(8, seed=43)
+    pk2, mg2, sg2 = arrs(pks2, msgs2, sigs2)
     m2 = VerifierModel(block_on_compile=True)
     ok = m2.verify_rows_cached(key, pk2, idx, mg2, sg2)
     assert m2._valset_tables[key].source == "build"  # rebuilt, not loaded
@@ -620,14 +188,14 @@ def test_oversized_valset_skips_tabled_path(monkeypatch):
     """Sets beyond MAX_SHARDED_VALSET must ride the generic pipeline:
     the 50k-ingest eval measured the huge-table path ~50x slower end
     to end (HBM-resident 2GB tables + huge-shape compiles). Sets
-    between the two caps go SHARDED (test_sharded_tables_large_valset)
+    between the two caps go SHARDED (test_tabled_batches.py)
     — only past the sharded cap does the tabled path decline."""
     from tendermint_tpu.models import verifier as vmod
 
     monkeypatch.setattr(vmod, "MAX_TABLED_VALSET", 8)
     monkeypatch.setattr(vmod, "MAX_SHARDED_VALSET", 8)
-    pks, msgs, sigs = _sign_rows(12, seed=51)
-    pk, mg, sg = _arrs(pks, msgs, sigs)
+    pks, msgs, sigs = sign_rows(12, seed=51)
+    pk, mg, sg = arrs(pks, msgs, sigs)
     m = vmod.VerifierModel(block_on_compile=True)
     out = m.verify_rows_cached(b"big-valset", pk, np.arange(12, dtype=np.int32), mg, sg)
     assert out is None  # caller falls back to the generic path
@@ -641,8 +209,8 @@ def test_small_gathered_batch_against_huge_table_falls_back(monkeypatch):
     small drains (the pathology was only measured on ~2GB tables)."""
     from tendermint_tpu.models import verifier as vmod
 
-    pks, msgs, sigs = _sign_rows(80, seed=53)
-    pk, mg, sg = _arrs(pks, msgs, sigs)
+    pks, msgs, sigs = sign_rows(80, seed=53)
+    pk, mg, sg = arrs(pks, msgs, sigs)
     m = vmod.VerifierModel(block_on_compile=True)
     # full-set call (dense) builds the 80-row (pad 256) tables
     ok = m.verify_rows_cached(b"gather-valset", pk, np.arange(80, dtype=np.int32), mg, sg)

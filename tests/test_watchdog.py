@@ -378,7 +378,7 @@ def test_reactor_deadline_zero_disables_window_deadline():
 # -- breaker recovery through the device engines ----------------------------
 
 
-def test_merkle_device_breaker_trip_and_halfopen_recovery():
+def test_merkle_device_breaker_trip_and_halfopen_recovery(monkeypatch):
     """ISSUE-4 circuit-breaker acceptance (merkle side): injected device
     failures latch hashing to host; once injection stops, a half-open
     probe re-enables the device path; health counters show the trip and
@@ -387,6 +387,10 @@ def test_merkle_device_breaker_trip_and_halfopen_recovery():
     from tendermint_tpu.crypto import merkle
     from tendermint_tpu.utils.metrics import HealthMetrics, Registry
 
+    # the seam builds its engine anew: the breaker registry is keyed by
+    # name, and an engine that another test of this worker built later
+    # would have taken "merkle.compile" over from the seam's
+    monkeypatch.setattr(merkle, "_HASHER", None)
     wd_mod.set_breaker_defaults(failure_threshold=2, cooldown_s=0.1)
     items = [bytes([i % 251]) * 20 for i in range(64)]
     try:
