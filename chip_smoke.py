@@ -131,11 +131,12 @@ def signed_commit(by_addr, vals, chain_id: str, height: int, block_id, t_ns: int
         CommitSig(BLOCK_ID_FLAG_COMMIT, v.address, t_ns + i, b"")
         for i, v in enumerate(vals.validators)
     ]
-    commit = Commit(height, 0, block_id, sigs)
-    rows = commit.sign_bytes_matrix(chain_id)
+    rows = Commit(height, 0, block_id, sigs).sign_bytes_matrix(chain_id)
     for cs, row in zip(sigs, rows):
         cs.signature = by_addr[cs.validator_address].sign(row.tobytes())
-    return commit
+    # a Commit is immutable once read (its memos hold its slots as
+    # columns, signatures too): the signed slots get a Commit of their own
+    return Commit(height, 0, block_id, sigs)
 
 
 def make_block_id(seed: int, tag: str):

@@ -52,6 +52,46 @@ class RowCounts:
             return self.device, self.host
 
 
+class SeamCounts:
+    """Thread-safe counts of the verify seam's column form
+    (types/block.CommitColumns, ValidatorSet._commit_batch_arrays),
+    counted where the work is done: ``column_rows`` signature slots
+    read into columns (once per Commit object — a count that stands
+    still while commits are verified means a memo outlived its
+    commit), ``packed_rows`` rows packed from columns for a provider,
+    ``fixup_rows`` those of them off the common shape — a non-64-byte
+    signature or a non-ed25519 key (verified row by row outside the
+    batch) or an unknown address (dropped). An all-ed25519 commit
+    reads 0 fix-up rows; a BLS or mixed set shows the other side."""
+
+    __slots__ = ("_lock", "column_rows", "packed_rows", "fixup_rows")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.column_rows = 0
+        self.packed_rows = 0
+        self.fixup_rows = 0
+
+    def add(self, column_rows: int = 0, packed_rows: int = 0, fixup_rows: int = 0) -> None:
+        with self._lock:
+            self.column_rows += column_rows
+            self.packed_rows += packed_rows
+            self.fixup_rows += fixup_rows
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {
+                "seam_column_rows": self.column_rows,
+                "seam_packed_rows": self.packed_rows,
+                "seam_fixup_rows": self.fixup_rows,
+            }
+
+
+# The seam packs before a provider is chosen (types/ knows none), so
+# its counts are the process's, as crypto/merkle.device_stats() are.
+SEAM_COUNTS = SeamCounts()
+
+
 class BatchVerifier:
     """Batch signature verification over rectangular u8 arrays."""
 

@@ -53,7 +53,7 @@ import time
 
 import numpy as np
 
-from tendermint_tpu.crypto.batch import BatchVerifier, CPUBatchVerifier
+from tendermint_tpu.crypto.batch import SEAM_COUNTS, BatchVerifier, CPUBatchVerifier
 from tendermint_tpu.utils import faultinject as faults
 from tendermint_tpu.utils import trace
 
@@ -623,6 +623,7 @@ class PipelinedVerifier(BatchVerifier):
             }
         for k, v in self.cache.stats().items():
             s[f"cache_{k}"] = v
+        s.update(SEAM_COUNTS.snapshot())
         return s
 
     def engine_stats(self) -> Dict[str, object]:
@@ -655,6 +656,9 @@ class PipelinedVerifier(BatchVerifier):
         cache = self.cache.stats()
         counters["cache_hits"] = cache["hits"]
         counters["cache_misses"] = cache["misses"]
+        # the verify seam packs before it reaches any provider, so its
+        # counts are the process's (crypto/batch.SeamCounts)
+        counters.update(SEAM_COUNTS.snapshot())
         buckets: Dict[str, dict] = {}
         breakers: Dict[str, dict] = {}
         model = self.model  # the wrapped VerifierModel (None for CPU inner)

@@ -8,12 +8,16 @@ Commit.VoteSignBytes :637, BlockID :957 region.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from operator import attrgetter
 from typing import List, Optional
+
+import numpy as np
 
 from tendermint_tpu.codec import signbytes
 from tendermint_tpu.codec.binary import Reader, Writer
 from tendermint_tpu.codec.signbytes import PRECOMMIT_TYPE
 from tendermint_tpu.crypto import merkle
+from tendermint_tpu.crypto.batch import SEAM_COUNTS
 from tendermint_tpu.types.tx import Txs
 from tendermint_tpu.version import BLOCK_PROTOCOL
 
@@ -173,6 +177,102 @@ class CommitSig:
         return cls(r.read_u8(), r.read_bytes(), r.read_i64(), r.read_bytes())
 
 
+_FLAG_OF = attrgetter("block_id_flag")
+_TIMESTAMP_OF = attrgetter("timestamp_ns")
+_SIGNATURE_OF = attrgetter("signature")
+_ADDRESS_OF = attrgetter("validator_address")
+
+
+def _int_column(values, n: int) -> np.ndarray:
+    """(n,) column of the integers ``values()`` yields, read in one C
+    pass: u8 when every value fits a byte (all a decoded commit can
+    hold), i64 for what only code can construct (a flag of 300, a
+    400-byte signature) — the checks that follow read either alike."""
+    try:
+        return np.frombuffer(bytes(values()), dtype=np.uint8)
+    except ValueError:
+        return np.fromiter(values(), dtype=np.int64, count=n)
+
+
+# What CommitSig.validate_basic admits, by flag value (take(...,
+# mode="clip") reads a flag that is no byte as 0 or 255, unknown
+# both): the address length, the least and the most signature bytes.
+# A flag outside 1-3 admits no signature length at all.
+_SLOT_LIMITS = np.empty((3, 256), dtype=np.int64)
+_SLOT_LIMITS[:] = [[0], [1], [0]]
+_SLOT_LIMITS[:, BLOCK_ID_FLAG_ABSENT] = 0
+_SLOT_LIMITS[:, [BLOCK_ID_FLAG_COMMIT, BLOCK_ID_FLAG_NIL]] = [[20], [1], [MAX_SIGNATURE_SIZE]]
+
+
+def first_true(mask: np.ndarray) -> int:
+    """Index of the first True of a 1-D mask, -1 when it has none."""
+    if not mask.size:
+        return -1
+    i = int(mask.argmax())
+    return i if mask[i] else -1
+
+
+class CommitColumns:
+    """A commit's signature slots read ONCE into columns; the verify
+    seam's structural check, sign-bytes parts, pack and replay
+    (types/validator_set.py) work on these arrays, so a 10,000-slot
+    commit is never walked object by object.
+
+    ``flags`` (N,) block-id flags, ``timestamp_ns`` (N,) i64,
+    ``sig_lens`` / ``addr_lens`` (N,) byte lengths, ``present`` (P,)
+    i64 indices of the non-absent slots in order with their
+    ``present_sig_lens`` (P,), ``sig_buf`` every slot's signature bytes
+    back to back (absent slots hold none in a valid commit, so the
+    buffer is the present rows'). All read-only: they are shared by
+    every verification of the commit."""
+
+    __slots__ = (
+        "flags", "timestamp_ns", "sig_lens", "addr_lens",
+        "present", "present_sig_lens", "sig_buf",
+    )
+
+    def __init__(self, signatures: List["CommitSig"]):
+        n = len(signatures)
+        sigs = list(map(_SIGNATURE_OF, signatures))
+        self.flags = _int_column(lambda: map(_FLAG_OF, signatures), n)
+        self.timestamp_ns = np.fromiter(
+            map(_TIMESTAMP_OF, signatures), dtype=np.int64, count=n
+        )
+        self.sig_lens = _int_column(lambda: map(len, sigs), n)
+        self.addr_lens = _int_column(
+            lambda: map(len, map(_ADDRESS_OF, signatures)), n
+        )
+        self.present = (self.flags != BLOCK_ID_FLAG_ABSENT).nonzero()[0]
+        self.present.flags.writeable = False
+        self.present_sig_lens = self.sig_lens[self.present]
+        self.sig_buf = np.frombuffer(b"".join(sigs), dtype=np.uint8)
+        SEAM_COUNTS.add(column_rows=n)
+
+    def first_invalid(self) -> int:
+        """Index of the first slot CommitSig.validate_basic rejects,
+        -1 when it accepts every one (the same checks as masks)."""
+        want_addr, min_sig, max_sig = _SLOT_LIMITS.take(self.flags, axis=1, mode="clip")
+        return first_true(
+            (self.addr_lens != want_addr)
+            | (self.sig_lens < min_sig)
+            | (self.sig_lens > max_sig)
+        )
+
+    def sig_rows(self, width: int) -> np.ndarray:
+        """(P, width) u8: the present rows' signatures clamped or
+        zero-padded to ``width`` — the buffer itself when every one of
+        them is ``width`` bytes, a gather by offsets when not."""
+        lens = self.present_sig_lens
+        if self.sig_buf.size == width * lens.size and not np.count_nonzero(lens != width):
+            return self.sig_buf.reshape(lens.size, width)
+        starts = (self.sig_lens.cumsum(dtype=np.int64) - self.sig_lens)[self.present]
+        col = np.arange(width)
+        padded = np.concatenate([self.sig_buf, np.zeros(width, dtype=np.uint8)])
+        return np.where(
+            col < lens[:, None], padded[starts[:, None] + col], 0
+        ).astype(np.uint8)
+
+
 @dataclass
 class Commit:
     """+2/3 precommits for a block (types/block.go:572)."""
@@ -186,7 +286,8 @@ class Commit:
 
     def __deepcopy__(self, memo):
         """Deep copies get a MEMO-FREE commit: the hash / encode /
-        validate / row-key caches assume immutability, and the one
+        validate / column / row-key caches assume immutability (the
+        columns hold the signature bytes themselves), and the one
         legitimate reason to deep-copy a commit is to build a variant
         (tests tamper with signatures; evidence construction mutates) —
         a carried row-key cache on a then-mutated copy could otherwise
@@ -199,6 +300,15 @@ class Commit:
             block_id=_copy.deepcopy(self.block_id, memo),
             signatures=_copy.deepcopy(self.signatures, memo),
         )
+
+    def columns(self) -> CommitColumns:
+        """The signature slots as columns, read once per Commit object
+        (memoized under hash()'s immutability contract, like
+        ``_parts_cache``; a deep copy starts without it)."""
+        cols = getattr(self, "_cols_cache", None)
+        if cols is None:
+            cols = self._cols_cache = CommitColumns(self.signatures)
+        return cols
 
     def vote_sign_bytes(self, chain_id: str, idx: int) -> bytes:
         """Canonical sign-bytes for signature `idx` (reference
@@ -240,9 +350,6 @@ class Commit:
         cached = getattr(self, "_parts_cache", None)
         if cached is not None and cached[0] == chain_id:
             return cached[1]
-        import numpy as np
-
-        n = len(self.signatures)
         template = signbytes.canonical_sign_bytes(
             msg_type=PRECOMMIT_TYPE,
             height=self.height,
@@ -253,21 +360,11 @@ class Commit:
             timestamp_ns=0,
             chain_id=chain_id,
         )
-        templates = np.stack(
-            [
-                np.frombuffer(template, dtype=np.uint8),
-                np.frombuffer(template, dtype=np.uint8).copy(),
-            ]
-        )
+        templates = np.frombuffer(template * 2, dtype=np.uint8).reshape(2, -1).copy()
         templates[1, signbytes.BLOCK_ID_OFFSET : signbytes.BLOCK_ID_END] = 0
-        ts = np.asarray(
-            [cs.timestamp_ns for cs in self.signatures], dtype=np.int64
-        )
-        ts8 = ts.astype(">i8").view(np.uint8).reshape(n, 8)
-        flags = np.asarray(
-            [cs.block_id_flag for cs in self.signatures], dtype=np.uint8
-        )
-        tmpl_idx = (flags != BLOCK_ID_FLAG_COMMIT).astype(np.int32)
+        cols = self.columns()
+        ts8 = cols.timestamp_ns.astype(">i8").view(np.uint8).reshape(-1, 8)
+        tmpl_idx = (cols.flags != BLOCK_ID_FLAG_COMMIT).astype(np.int32)
         out = (templates, tmpl_idx, ts8)
         self._parts_cache = (chain_id, out)
         return out
@@ -277,15 +374,10 @@ class Commit:
         (N, 160) uint8 (absent rows are zeros — callers filter by index).
         Host-side materialization of sign_bytes_parts — ~50x cheaper
         than N Python struct.pack calls on a 10k-validator commit."""
-        import numpy as np
-
         templates, tmpl_idx, ts8 = self.sign_bytes_parts(chain_id)
         mat = templates[tmpl_idx]
         mat[:, signbytes.TIMESTAMP_OFFSET : signbytes.TIMESTAMP_OFFSET + 8] = ts8
-        flags = np.asarray(
-            [cs.block_id_flag for cs in self.signatures], dtype=np.uint8
-        )
-        absent = flags == BLOCK_ID_FLAG_ABSENT
+        absent = self.columns().flags == BLOCK_ID_FLAG_ABSENT
         if absent.any():
             mat[absent] = 0
         return mat
@@ -349,10 +441,9 @@ class Commit:
                 return "commit cannot be for nil block"
             if not self.signatures:
                 return "no signatures in commit"
-            for i, cs in enumerate(self.signatures):
-                err = cs.validate_basic()
-                if err:
-                    return f"wrong CommitSig #{i}: {err}"
+            i = self.columns().first_invalid()
+            if i >= 0:
+                return f"wrong CommitSig #{i}: {self.signatures[i].validate_basic()}"
         return None
 
     def encode(self) -> bytes:

@@ -17,16 +17,18 @@ identical to the serial loop.
 from __future__ import annotations
 
 from fractions import Fraction
+from itertools import repeat
+from operator import attrgetter
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from tendermint_tpu.codec.binary import Reader, Writer
+from tendermint_tpu.codec.signbytes import splice_timestamps
 from tendermint_tpu.crypto import merkle
-from tendermint_tpu.crypto.batch import BatchVerifier, get_default_provider
+from tendermint_tpu.crypto.batch import SEAM_COUNTS, BatchVerifier, get_default_provider
+from tendermint_tpu.types.block import BLOCK_ID_FLAG_COMMIT, MAX_SIGNATURE_SIZE, first_true
 from tendermint_tpu.types.validator import Validator
-
-from tendermint_tpu.types.block import MAX_SIGNATURE_SIZE
 
 MAX_TOTAL_VOTING_POWER = (1 << 63) // 8
 PRIORITY_WINDOW_SIZE_FACTOR = 2
@@ -351,94 +353,83 @@ class ValidatorSet:
         looks each signer up by address, skipping unknowns
         (verify_commit_trusting: commit from another set).
 
-        Vectorized: sign-bytes come from Commit.sign_bytes_matrix (one
-        numpy template + per-row columns), pubkeys/powers from the per-set
-        cache, signatures from one concatenated frombuffer — the 10k-row
-        hot path does no per-row Python struct packing.
+        Every array is a fancy index into the commit's columns
+        (Commit.columns, read once per commit) or the per-set cache
+        (_device_arrays); no CommitSig is visited here. Rows off the
+        common shape are told apart by what the columns show: a
+        signature that is not 64 bytes or a non-ed25519 key leaves the
+        ``ed`` mask (those rows verify one by one, _serial_fill_non_ed),
+        an unknown address under ``by_address`` is dropped.
 
-        Returns (idxs, vals_idx, pubkeys(N,32), msgs(N,160), sigs(N,64),
-        powers(N,), counted(N,), ed(N,), tpl) where idxs maps rows back
-        to signature indices and vals_idx to validator indices (for
-        duplicate-signer detection during the sequential replay -- NOT
-        here, so that a duplicate after quorum doesn't reject like the
-        reference doesn't). tpl is the commit's templated sign-bytes
-        (templates(2,160), tmpl_idx(N,), ts8(N,8)) row-gathered like
-        msgs — device providers materialize rows on device so per-row
-        H2D carries 12 message bytes instead of 160.
+        Returns (idxs(N,) i64, vals_idx(N,) i64, pubkeys(N,32),
+        msgs(N,160), sigs(N,64), powers(N,), counted(N,), ed(N,), tpl)
+        where idxs maps rows back to signature indices and vals_idx to
+        validator indices (for duplicate-signer detection during the
+        sequential replay -- NOT here, so that a duplicate after quorum
+        doesn't reject like the reference doesn't). tpl is the commit's
+        templated sign-bytes (templates(2,160), tmpl_idx(N,), ts8(N,8))
+        row-gathered like msgs — device providers materialize rows on
+        device so per-row H2D carries 12 message bytes instead of 160.
         """
-        idxs: List[int] = []
-        vals_idx: List[int] = []
-        sig_parts: List[bytes] = []
-        counted: List[bool] = []
-        for i, cs in enumerate(commit.signatures):
-            if cs.absent_():
-                continue
-            if len(cs.signature) > MAX_SIGNATURE_SIZE:
-                # reference MaxSignatureSize (widened to 96 for BLS G2
-                # rows); must never be truncated into a valid prefix
-                # (commit-hash malleability).
-                raise ErrInvalidCommit(f"signature #{i} too big ({len(cs.signature)})")
-            if by_address:
-                vi, val = self.get_by_address(cs.validator_address)
-                if val is None:
-                    continue
-            else:
-                vi = i
-            idxs.append(i)
-            vals_idx.append(vi)
-            # the (n, 64) matrix feeds the ed25519 kernel only; BLS /
-            # other-type rows re-read the full signature bytes from the
-            # commit (_serial_fill_non_ed), so clamping here cannot
-            # change any verdict
-            sig_parts.append(cs.signature[:64].ljust(64, b"\x00"))
-            counted.append(cs.for_block())
-        n = len(idxs)
+        cols = commit.columns()
+        idxs, sig_lens = cols.present, cols.present_sig_lens
+        r = first_true(sig_lens > MAX_SIGNATURE_SIZE)
+        if r >= 0:
+            # reference MaxSignatureSize (widened to 96 for BLS G2
+            # rows); must never be truncated into a valid prefix
+            # (commit-hash malleability).
+            raise ErrInvalidCommit(f"signature #{idxs[r]} too big ({sig_lens[r]})")
+        # the (n, 64) matrix feeds the ed25519 kernel only; BLS /
+        # other-type rows re-read the full signature bytes from the
+        # commit (_serial_fill_non_ed), so clamping here cannot
+        # change any verdict
+        sg = cols.sig_rows(64)
+        unknown = 0
+        if by_address:
+            vals_idx = np.fromiter(
+                map(
+                    self._addr_index.get,
+                    map(attrgetter("validator_address"), commit.signatures),
+                    repeat(-1),
+                ),
+                dtype=np.int64,
+                count=len(commit.signatures),
+            )[idxs]
+            known = vals_idx >= 0
+            unknown = idxs.size - int(np.count_nonzero(known))
+            if unknown:
+                idxs, vals_idx, sig_lens, sg = (
+                    idxs[known], vals_idx[known], sig_lens[known], sg[known]
+                )
+        else:
+            vals_idx = idxs
         all_pk, all_powers, all_ed = self._device_arrays()
-        vals_idx_arr = np.asarray(vals_idx, dtype=np.int64)
-        pk = all_pk[vals_idx_arr] if n else np.zeros((0, 32), dtype=np.uint8)
-        powers = all_powers[vals_idx_arr] if n else np.zeros(0, dtype=np.int64)
-        ed = all_ed[vals_idx_arr] if n else np.zeros(0, dtype=bool)
-        if n:
-            # an ed25519 row with an oversized (>64B, <=MAX) signature
-            # must NOT ride the clamped batch matrix — the serial path
-            # rejects any non-64-byte ed25519 signature, and truncating
-            # could reconstitute a valid prefix (verdict divergence)
-            sig_lens = np.asarray(
-                [len(commit.signatures[i].signature) for i in idxs]
-            )
-            ed = ed & (sig_lens <= 64)
-        idxs_arr = np.asarray(idxs, dtype=np.int64)
+        # an ed25519 row whose signature is not 64 bytes must NOT ride
+        # the clamped / padded batch matrix — the serial path rejects
+        # any non-64-byte ed25519 signature, and truncating or padding
+        # could reconstitute a valid one (verdict divergence)
+        ed = all_ed[vals_idx] & (sig_lens == 64)
+        SEAM_COUNTS.add(
+            packed_rows=idxs.size,
+            fixup_rows=unknown + idxs.size - int(np.count_nonzero(ed)),
+        )
         # ONE sign_bytes_parts call feeds both forms: the templated
         # parts (what device providers consume) and the host-side
         # materialization mg (fallback paths + non-ed rows). Absent
-        # rows were filtered above, so the absent-row zeroing that
+        # rows are not among idxs, so the absent-row zeroing that
         # sign_bytes_matrix does is not needed here.
         templates, tmpl_idx_all, ts8_all = commit.sign_bytes_parts(chain_id)
-        if n:
-            from tendermint_tpu.codec.signbytes import splice_timestamps
-
-            tpl = (templates, tmpl_idx_all[idxs_arr], ts8_all[idxs_arr])
-            # fancy indexing already allocates a fresh array
-            mg = splice_timestamps(templates[tpl[1]], tpl[2])
-        else:
-            tpl = (
-                templates,
-                np.zeros(0, dtype=np.int32),
-                np.zeros((0, 8), dtype=np.uint8),
-            )
-            mg = np.zeros((0, 160), dtype=np.uint8)
-        sg = (
-            np.frombuffer(b"".join(sig_parts), dtype=np.uint8).reshape(n, 64)
-            if n else np.zeros((0, 64), dtype=np.uint8)
-        )
+        tpl = (templates, tmpl_idx_all[idxs], ts8_all[idxs])
+        # fancy indexing already allocates a fresh array
+        mg = splice_timestamps(templates[tpl[1]], tpl[2])
         return (
             idxs,
             vals_idx,
-            pk,
+            all_pk[vals_idx],
             mg,
             sg,
-            powers,
-            np.asarray(counted, dtype=bool),
+            all_powers[vals_idx],
+            cols.flags[idxs] == BLOCK_ID_FLAG_COMMIT,
             ed,
             tpl,
         )
@@ -652,7 +643,7 @@ class ValidatorSet:
         if sig_cache is not None and sig_cache.capacity > 0:
             all_keys = self._commit_row_keys(chain_id, commit)
             if all_keys is not None:
-                row_keys = [all_keys[i] for i in idxs]
+                row_keys = [all_keys[i] for i in idxs.tolist()]
         ok = self._verify_rows(
             commit, idxs, vals_idx, pk, mg, sg, ed, v, tpl,
             sig_cache=sig_cache, row_keys=row_keys,
@@ -709,19 +700,15 @@ class ValidatorSet:
         keys = self._commit_row_keys(chain_id, commit)
         if keys is None:
             return False
-        idxs: List[int] = []
-        counted: List[bool] = []
-        for i, cs in enumerate(commit.signatures):
-            if cs.absent_():
-                continue
-            if not sig_cache.seen(keys[i]):
-                return False
-            idxs.append(i)
-            counted.append(cs.for_block())
+        cols = commit.columns()
+        idxs = cols.present
+        if not all(map(sig_cache.seen, map(keys.__getitem__, idxs.tolist()))):
+            return False
         _pk, all_powers, _ed = self._device_arrays()
-        powers = all_powers[np.asarray(idxs, dtype=np.int64)] if idxs else []
-        ok = np.ones(len(idxs), dtype=bool)
-        self._replay_commit_full(commit, ok, idxs, powers, counted)
+        ok = np.ones(idxs.size, dtype=bool)
+        self._replay_commit_full(
+            commit, ok, idxs, all_powers[idxs], cols.flags[idxs] == BLOCK_ID_FLAG_COMMIT
+        )
         return True
 
     def _check_commit_size(self, commit) -> None:
@@ -813,20 +800,33 @@ class ValidatorSet:
         ):
             raise ValueError(f"trust level must be within [1/3, 1], got {trust_level}")
 
+    @staticmethod
+    def _replay_visited(powers, counted, needed: int) -> Tuple[int, int]:
+        """How far the reference's loop gets (types/validator_set.go
+        :641-668: it returns at the first row it reaches with the tally
+        already past ``needed``): (number of rows it visits, the
+        for-block power tallied over them). int64 holds every partial
+        sum up to that row — a set's total is capped at 2^60."""
+        after = (powers * counted).cumsum()
+        r = first_true(after > needed)
+        if r < 0:
+            return after.size, int(after[-1]) if after.size else 0
+        # the row that carries the tally past `needed` is still visited
+        return r + 1, int(after[r])
+
     def _replay_commit_full(self, commit, ok, idxs, powers, counted) -> None:
         """Sequential-early-return acceptance over batched results
-        (reference loop types/validator_set.go:641-668)."""
+        (reference loop types/validator_set.go:641-668), as reductions:
+        the first visited row with a bad signature rejects; else the
+        tally over the visited rows decides."""
         voting_power_needed = self.total_voting_power() * 2 // 3
-        talled = 0
-        for r, i in enumerate(idxs):
-            if talled > voting_power_needed:
-                return  # quorum reached before this signature was needed
-            if not ok[r]:
-                raise ErrInvalidCommitSignature(
-                    f"wrong signature #{i} ({commit.signatures[i].validator_address.hex()})"
-                )
-            if counted[r]:
-                talled += int(powers[r])
+        visited, talled = self._replay_visited(powers, counted, voting_power_needed)
+        r = first_true(~np.asarray(ok[:visited], dtype=bool))
+        if r >= 0:
+            i = int(idxs[r])
+            raise ErrInvalidCommitSignature(
+                f"wrong signature #{i} ({commit.signatures[i].validator_address.hex()})"
+            )
         if talled > voting_power_needed:
             return
         raise ErrNotEnoughVotingPower(f"have {talled}, need > {voting_power_needed}")
@@ -867,19 +867,19 @@ class ValidatorSet:
         types/validator_set.go:754 region), incl. duplicate-signer check."""
         total = self.total_voting_power()
         needed = total * trust_level.numerator // trust_level.denominator
-        talled = 0
-        seen_vals: Dict[int, int] = {}
-        for r, i in enumerate(idxs):
-            if talled > needed:
-                return
-            vi = vals_idx[r]
-            if vi in seen_vals:
-                raise ErrInvalidCommit(f"double vote from validator index {vi}")
-            seen_vals[vi] = i
-            if not ok[r]:
-                raise ErrInvalidCommitSignature(f"wrong signature #{i}")
-            if counted_arr[r]:
-                talled += int(powers_arr[r])
+        visited, talled = self._replay_visited(powers_arr, counted_arr, needed)
+        vi = np.asarray(vals_idx[:visited], dtype=np.int64)
+        # a row is a double vote when its validator index has a lower
+        # visited row; it is checked before the signature on that row
+        dup = np.zeros(visited, dtype=bool)
+        if visited and np.bincount(vi).max() > 1:
+            dup[:] = True
+            dup[np.unique(vi, return_index=True)[1]] = False
+        r = first_true(dup | ~np.asarray(ok[:visited], dtype=bool))
+        if r >= 0:
+            if dup[r]:
+                raise ErrInvalidCommit(f"double vote from validator index {vi[r]}")
+            raise ErrInvalidCommitSignature(f"wrong signature #{idxs[r]}")
         if talled > needed:
             return
         raise ErrNotEnoughVotingPower(f"have {talled}, need > {needed}")

@@ -331,6 +331,9 @@ class CryptoMetrics:
         ("pipeline_device_rows", "device_rows"),
         ("dedupe_cache_hits", "cache_hits"),
         ("dedupe_cache_misses", "cache_misses"),
+        ("seam_column_rows", "seam_column_rows"),
+        ("seam_packed_rows", "seam_packed_rows"),
+        ("seam_fixup_rows", "seam_fixup_rows"),
     )
 
     def __init__(self, registry: Optional[Registry] = None, namespace="tendermint"):
@@ -346,6 +349,9 @@ class CryptoMetrics:
         self.dedupe_cache_hits = reg(Counter("dedupe_cache_hits_total", "Dedupe-cache hits (device round trips saved).", namespace, sub))
         self.dedupe_cache_misses = reg(Counter("dedupe_cache_misses_total", "Dedupe-cache misses.", namespace, sub))
         self.dedupe_cache_size = reg(Gauge("dedupe_cache_size", "Verified triples currently cached.", namespace, sub))
+        self.seam_column_rows = reg(Counter("seam_column_rows_total", "Commit signature slots read into columns (once per Commit object).", namespace, sub))
+        self.seam_packed_rows = reg(Counter("seam_packed_rows_total", "Commit rows packed from columns for a provider.", namespace, sub))
+        self.seam_fixup_rows = reg(Counter("seam_fixup_rows_total", "Packed rows off the common shape: non-64-byte signature, non-ed25519 key, unknown address.", namespace, sub))
         self._deltas = _SnapshotCounters()
 
     def update(self, stats: dict) -> None:

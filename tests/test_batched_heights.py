@@ -301,9 +301,16 @@ def test_fast_sync_processor_window_one_call():
 def test_fast_sync_processor_window_rejects_bad_block():
     genesis_state, blocks = _make_block_chain(6)
     # corrupt the commit for block 4 (carried in block 5's last_commit)
-    sig = bytearray(blocks[5].last_commit.signatures[0].signature)
+    # (building the chain verified that commit, and a verified commit is
+    # immutable — its memos vouch for its bytes — so the forged one is a
+    # deep copy, which starts without them)
+    import copy
+
+    forged = copy.deepcopy(blocks[5].last_commit)
+    sig = bytearray(forged.signatures[0].signature)
     sig[3] ^= 0x80
-    blocks[5].last_commit.signatures[0].signature = bytes(sig)
+    forged.signatures[0].signature = bytes(sig)
+    blocks[5].last_commit = forged
 
     from tests.test_state import make_executor
 

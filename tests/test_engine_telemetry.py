@@ -145,6 +145,63 @@ def test_pipeline_engine_stats():
     assert st["queue_wait_ms"]["sum_ms"] >= 0
 
 
+def _signed_commit(privs, chain_id="seam-chain", absent=()):
+    """A +2/3 commit of ``privs`` (any key types), one vote each."""
+    from tendermint_tpu.codec.signbytes import PRECOMMIT_TYPE
+    from tendermint_tpu.types.block import BlockID, CommitSig, PartSetHeader
+    from tendermint_tpu.types.validator import Validator
+    from tendermint_tpu.types.validator_set import ValidatorSet
+    from tendermint_tpu.types.vote import Vote
+    from tendermint_tpu.types.vote_set import VoteSet
+
+    vals = ValidatorSet([Validator(p.pub_key(), 10) for p in privs])
+    by_addr = {p.pub_key().address(): p for p in privs}
+    bid = BlockID(b"\x42" * 32, PartSetHeader(1, b"\x43" * 32))
+    vs = VoteSet(chain_id, 5, 0, PRECOMMIT_TYPE, vals)
+    for idx, val in enumerate(vals.validators):
+        v = Vote(
+            vote_type=PRECOMMIT_TYPE, height=5, round=0, block_id=bid,
+            timestamp_ns=1234 + idx, validator_address=val.address, validator_index=idx,
+        )
+        v.signature = by_addr[val.address].sign(v.sign_bytes(chain_id))
+        assert vs.add_vote(v)
+    commit = vs.make_commit()
+    for i in absent:
+        commit.signatures[i] = CommitSig.absent()
+    return vals, bid, commit
+
+
+def test_pipeline_engine_stats_seam_counters():
+    """The verify seam's counts (crypto/batch.SeamCounts) under
+    engine_stats()["counters"]: an all-ed25519 commit is packed from
+    columns with 0 fix-up rows; a set with a secp256k1 key shows that row
+    on the other side."""
+    from tendermint_tpu.crypto.batch import CPUBatchVerifier
+    from tendermint_tpu.crypto.keys import Ed25519PrivKey
+    from tendermint_tpu.crypto.pipeline import PipelinedVerifier, SigCache
+    from tendermint_tpu.crypto.secp256k1 import Secp256k1PrivKey
+
+    eds = [Ed25519PrivKey.from_secret(f"seam-{i}".encode()) for i in range(7)]
+    with PipelinedVerifier(CPUBatchVerifier(), cache=SigCache()) as pv:
+        def delta(since):
+            now = pv.engine_stats()["counters"]
+            return tuple(
+                now[k] - since[k]
+                for k in ("seam_column_rows", "seam_packed_rows", "seam_fixup_rows")
+            )
+
+        start = pv.engine_stats()["counters"]
+        vals, bid, commit = _signed_commit(eds, absent=(2,))
+        vals.verify_commit("seam-chain", bid, 5, commit, provider=pv)
+        assert delta(start) == (7, 6, 0)
+
+        start = pv.engine_stats()["counters"]
+        vals, bid, commit = _signed_commit(eds[:3] + [Secp256k1PrivKey.from_secret(b"seam-secp")])
+        vals.verify_commit("seam-chain", bid, 5, commit, provider=pv)
+        assert delta(start) == (4, 4, 1)
+        assert pv.stats()["seam_fixup_rows"] == pv.engine_stats()["counters"]["seam_fixup_rows"]
+
+
 def _row_case_warm_blocking(v, batch):
     pk, mg, sg = batch
     assert v.verify_batch(pk, mg, sg).all()

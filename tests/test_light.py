@@ -251,3 +251,147 @@ def test_client_prune():
         assert c.store.latest_height() == 12
 
     run(go())
+
+
+# -- the chain's commits as columns, against a per-row oracle --------------
+#
+# verify_chain packs every link's commit from Commit.columns() and replays
+# each on arrays (types/validator_set.py). The oracle below is the serial
+# reference written out: one host verification per signature in slot
+# order, returning at the quorum row.
+
+
+def _mixed_chain(n_heights, faults):
+    """A chain of 7 validators (power 10: more than 46 of 70 needed)
+    whose every commit has one absent slot and one nil vote, dealt by
+    height — so exactly 5 for-block rows, the quorum falling on the
+    last of them. ``faults[h]`` edits height h's slots before signing:
+    ("forge", i) flips a bit of slot i's signature, ("nil", i) makes
+    slot i a nil vote too."""
+    from tendermint_tpu.codec.signbytes import PRECOMMIT_TYPE
+    from tendermint_tpu.light.types import SignedHeader
+    from tendermint_tpu.types.block import (
+        BLOCK_ID_FLAG_COMMIT,
+        BLOCK_ID_FLAG_NIL,
+        BlockID,
+        Commit,
+        CommitSig,
+    )
+    from tendermint_tpu.types.vote import Vote
+
+    privs = keys(7, tag="mixed")
+    by_addr = {p.pub_key().address(): p for p in privs}
+    headers, vals = gen_chain(n_heights, base_keys=privs)
+    for h, sh in headers.items():
+        vs = vals[h]
+        kinds = {(h * 3) % 7: "absent", (h * 3 + 2) % 7: "nil"}
+        for kind, i in faults.get(h, []):
+            if kind == "nil":
+                kinds[i] = "nil"
+        slots = []
+        for i, val in enumerate(vs.validators):
+            if kinds.get(i) == "absent":
+                slots.append(CommitSig.absent())
+                continue
+            nil = kinds.get(i) == "nil"
+            vote = Vote(
+                vote_type=PRECOMMIT_TYPE, height=h, round=0,
+                block_id=BlockID() if nil else sh.commit.block_id,
+                timestamp_ns=sh.time_ns + i, validator_address=val.address, validator_index=i,
+            )
+            sig = by_addr[val.address].sign(vote.sign_bytes(CHAIN_ID))
+            if ("forge", i) in faults.get(h, []):
+                sig = bytes([sig[0] ^ 0x10]) + sig[1:]
+            slots.append(CommitSig(
+                BLOCK_ID_FLAG_NIL if nil else BLOCK_ID_FLAG_COMMIT, val.address, sh.time_ns + i, sig,
+            ))
+        headers[h] = SignedHeader(sh.header, Commit(h, 0, sh.commit.block_id, slots))
+    return headers, vals
+
+
+def _oracle_verify_commit(vs, commit):
+    """(exception type name, text) or None, as the reference's loop over
+    the slots gives it (types/validator_set.go:641-668)."""
+    needed = vs.total_voting_power() * 2 // 3
+    talled = 0
+    for i, cs in enumerate(commit.signatures):
+        if cs.absent_():
+            continue
+        if talled > needed:
+            return None
+        val = vs.validators[i]
+        if not val.pub_key.verify(commit.vote_sign_bytes(CHAIN_ID, i), cs.signature):
+            return "ErrInvalidCommitSignature", f"wrong signature #{i} ({cs.validator_address.hex()})"
+        if cs.for_block():
+            talled += val.voting_power
+    if talled > needed:
+        return None
+    return "ErrNotEnoughVotingPower", f"have {talled}, need > {needed}"
+
+
+_CHAIN_CASES = {
+    "sound": {},
+    # height 4: absent slot 5, nil slot 0 — for-block rows 1 2 3 4 6
+    "forged-before-quorum": {4: [("forge", 2)]},
+    "forged-on-the-quorum-row": {4: [("forge", 6)]},
+    "forged-nil-row": {4: [("forge", 0)]},
+    "one-signer-short": {5: [("nil", 4)]},
+    "two-links-fail": {3: [("forge", 1)], 6: [("nil", 3)]},
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CHAIN_CASES))
+def test_verify_chain_commits_match_per_row_oracle(case):
+    from tendermint_tpu.light.verifier import verify_chain
+    from tendermint_tpu.lightserve import core
+    from tendermint_tpu.types.validator_set import verify_commits_batched
+
+    headers, vals = _mixed_chain(7, _CHAIN_CASES[case])
+    heights = range(2, 8)
+    want = [_oracle_verify_commit(vals[h], headers[h].commit) for h in heights]
+    # quorum sits on the last for-block row, so every planted fault is visited
+    assert [w is not None for w in want] == [h in _CHAIN_CASES[case] for h in heights]
+
+    specs = [core.full_spec(vals[h], CHAIN_ID, headers[h]) for h in heights]
+    got = verify_commits_batched(specs)
+    assert [None if e is None else (type(e).__name__, str(e)) for e in got] == want
+
+    # fresh Commit objects for the chain call: the first failing link raises
+    chain = [(headers[h], vals[h]) for h in heights]
+    first = next((w for w in want if w is not None), None)
+    try:
+        verify_chain(CHAIN_ID, headers[1], vals[1], chain, PERIOD, now_ns=NOW)
+        outcome = None
+    except Exception as e:
+        outcome = (type(e).__name__, str(e))
+    assert outcome == first
+
+
+def test_verify_chain_forged_row_after_quorum_is_accepted():
+    """With every validator signing for the block the quorum row is the
+    5th of 7: a forged 6th or 7th slot is never visited (the reference's
+    early return), a forged 5th is."""
+    from tendermint_tpu.light.types import SignedHeader
+    from tendermint_tpu.light.verifier import verify_chain
+    from tendermint_tpu.types.block import Commit
+    from tendermint_tpu.types.validator_set import ErrInvalidCommitSignature
+
+    headers, vals = gen_chain(4, base_keys=keys(7, tag="mixed"))
+
+    def forged(h, slot):
+        c = headers[h].commit
+        slots = list(c.signatures)
+        cs = slots[slot]
+        slots[slot] = type(cs)(
+            cs.block_id_flag, cs.validator_address, cs.timestamp_ns,
+            bytes([cs.signature[0] ^ 0x10]) + cs.signature[1:],
+        )
+        return SignedHeader(headers[h].header, Commit(c.height, c.round, c.block_id, slots))
+
+    for slot in (5, 6):
+        chain = [(forged(h, slot) if h == 3 else headers[h], vals[h]) for h in (2, 3, 4)]
+        assert _oracle_verify_commit(vals[3], chain[1][0].commit) is None
+        verify_chain(CHAIN_ID, headers[1], vals[1], chain, PERIOD, now_ns=NOW)
+    chain = [(forged(h, 4) if h == 3 else headers[h], vals[h]) for h in (2, 3, 4)]
+    with pytest.raises(ErrInvalidCommitSignature, match="wrong signature #4"):
+        verify_chain(CHAIN_ID, headers[1], vals[1], chain, PERIOD, now_ns=NOW)

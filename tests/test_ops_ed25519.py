@@ -135,7 +135,12 @@ class TestTPUProviderIntegration:
         tpu = make_provider("tpu")
         vs.verify_commit("test-chain", bid, 5, commit, provider=tpu)
 
-        # corrupt a needed signature: both providers must reject
+        # corrupt a needed signature: both providers must reject (on a
+        # deep copy — a verified commit is immutable, its memos vouch
+        # for its bytes; the copy starts without them)
+        import copy
+
+        commit = copy.deepcopy(commit)
         commit.signatures[0].signature = bytes(64)
         import pytest as _pytest
 

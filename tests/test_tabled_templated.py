@@ -189,6 +189,11 @@ def test_validator_set_verify_commit_uses_cached_tables():
     vals.verify_commit(genesis.chain_id, bid, 3, commit, provider=cpu)
 
     # corrupt one signature: both providers must reject identically
+    # (on a deep copy — a verified commit is immutable, its memos vouch
+    # for its bytes; the copy starts without them)
+    import copy
+
+    commit = copy.deepcopy(commit)
     bad = commit.signatures[2]
     bad.signature = bad.signature[:10] + bytes([bad.signature[10] ^ 1]) + bad.signature[11:]
     from tendermint_tpu.types.validator_set import ErrInvalidCommitSignature

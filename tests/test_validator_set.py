@@ -305,7 +305,7 @@ def test_commit_batch_arrays_vectorized_equivalence():
         CHAIN_ID, commit, by_address=False
     )
     assert ed.all()  # all-ed25519 set
-    assert idxs == list(range(4))
+    assert idxs.tolist() == list(range(4))
     templates, tmpl_idx, ts8 = tpl
     for r, i in enumerate(idxs):
         cs = commit.signatures[i]
@@ -379,6 +379,9 @@ def test_mixed_key_type_commit_verification():
         i for i, val in enumerate(vals.validators)
         if len(val.pub_key.bytes()) != 32
     )
+    import copy
+
+    commit = copy.deepcopy(commit)  # a verified commit is immutable: tamper with a copy
     sig = bytearray(commit.signatures[secp_idx].signature)
     sig[-1] ^= 1
     commit.signatures[secp_idx].signature = bytes(sig)
@@ -459,3 +462,473 @@ def test_random_update_sequences_maintain_invariants():
         vals.increment_proposer_priority(1)
         seen.add(vals.get_proposer().address)
     assert seen == {v.address for v in vals.validators}
+
+
+# -- the column form against a per-row oracle --------------------------------
+#
+# Commit.columns() reads a commit's slots once into arrays, and the
+# structural check, the pack and both replays work on those. Each is held
+# here to the plain per-row loop it replaced, written out below.
+
+import copy
+import random
+
+import numpy as np
+
+from tendermint_tpu.crypto.batch import SEAM_COUNTS
+from tendermint_tpu.crypto.keys import is_batch_ed25519
+from tendermint_tpu.types.block import MAX_SIGNATURE_SIZE
+from tendermint_tpu.types.validator_set import ErrInvalidCommit
+
+COL_CHAIN = "col-chain"
+COL_BID = BlockID(hash=b"\x42" * 32, parts=PartSetHeader(total=1, hash=b"\x43" * 32))
+
+
+def _raw_sigs(vs, rng, absent=0.2, nil=0.15):
+    """Slots of mixed kinds with random bytes for signatures: what the
+    structural check and the pack read never depends on their validity."""
+    sigs = []
+    for v in vs.validators:
+        u = rng.random()
+        if u < absent:
+            sigs.append(CommitSig.absent())
+        else:
+            flag = BLOCK_ID_FLAG_NIL if u < absent + nil else BLOCK_ID_FLAG_COMMIT
+            sigs.append(CommitSig(flag, v.address, 10**18 + rng.randrange(10**12), rng.randbytes(64)))
+    return sigs
+
+
+def _oracle_validate_basic(commit):
+    for i, cs in enumerate(commit.signatures):
+        err = cs.validate_basic()
+        if err:
+            return f"wrong CommitSig #{i}: {err}"
+    return None
+
+
+def _oracle_pack(vs, commit, by_address):
+    idxs, vals_idx, sg, counted, ed = [], [], [], [], []
+    for i, cs in enumerate(commit.signatures):
+        if cs.absent_():
+            continue
+        if len(cs.signature) > MAX_SIGNATURE_SIZE:
+            raise ErrInvalidCommit(f"signature #{i} too big ({len(cs.signature)})")
+        if by_address:
+            vi, val = vs.get_by_address(cs.validator_address)
+            if val is None:
+                continue
+        else:
+            vi = i
+        idxs.append(i)
+        vals_idx.append(vi)
+        sg.append(cs.signature[:64].ljust(64, b"\x00"))
+        counted.append(cs.for_block())
+        ed.append(is_batch_ed25519(vs.validators[vi].pub_key) and len(cs.signature) == 64)
+    return idxs, vals_idx, sg, counted, ed
+
+
+def _oracle_replay_full(vs, commit, ok, idxs, powers, counted):
+    needed = vs.total_voting_power() * 2 // 3
+    talled = 0
+    for r, i in enumerate(idxs):
+        if talled > needed:
+            return
+        if not ok[r]:
+            raise ErrInvalidCommitSignature(
+                f"wrong signature #{i} ({commit.signatures[i].validator_address.hex()})"
+            )
+        if counted[r]:
+            talled += int(powers[r])
+    if talled > needed:
+        return
+    raise ErrNotEnoughVotingPower(f"have {talled}, need > {needed}")
+
+
+def _oracle_replay_trusting(vs, ok, idxs, vals_idx, powers, counted, trust_level):
+    needed = vs.total_voting_power() * trust_level.numerator // trust_level.denominator
+    talled = 0
+    seen = set()
+    for r, i in enumerate(idxs):
+        if talled > needed:
+            return
+        vi = vals_idx[r]
+        if vi in seen:
+            raise ErrInvalidCommit(f"double vote from validator index {vi}")
+        seen.add(vi)
+        if not ok[r]:
+            raise ErrInvalidCommitSignature(f"wrong signature #{i}")
+        if counted[r]:
+            talled += int(powers[r])
+    if talled > needed:
+        return
+    raise ErrNotEnoughVotingPower(f"have {talled}, need > {needed}")
+
+
+def _outcome(f, *args):
+    try:
+        f(*args)
+    except Exception as e:  # the verdict IS the exception's type and text
+        return type(e).__name__, str(e)
+    return None
+
+
+def _set(i, **fields):
+    def edit(sigs):
+        for k, v in fields.items():
+            setattr(sigs[i], k, v)
+    return edit
+
+
+def _both(*edits):
+    def edit(sigs):
+        for e in edits:
+            e(sigs)
+    return edit
+
+
+_VB_CASES = {
+    "valid": (lambda sigs: None, None),
+    "flag-0": (_set(3, block_id_flag=0), "wrong CommitSig #3: unknown BlockIDFlag: 0"),
+    "flag-4": (_set(3, block_id_flag=4), "wrong CommitSig #3: unknown BlockIDFlag: 4"),
+    "flag-300": (_set(3, block_id_flag=300), "wrong CommitSig #3: unknown BlockIDFlag: 300"),
+    "flag-negative": (_set(3, block_id_flag=-1), "wrong CommitSig #3: unknown BlockIDFlag: -1"),
+    "absent-with-address": (
+        _set(1, block_id_flag=BLOCK_ID_FLAG_ABSENT, signature=b""),
+        "wrong CommitSig #1: validator address is present for absent CommitSig",
+    ),
+    "absent-with-signature": (
+        _set(1, block_id_flag=BLOCK_ID_FLAG_ABSENT, validator_address=b""),
+        "wrong CommitSig #1: signature is present for absent CommitSig",
+    ),
+    "absent-with-both": (
+        _set(1, block_id_flag=BLOCK_ID_FLAG_ABSENT),
+        "wrong CommitSig #1: validator address is present for absent CommitSig",
+    ),
+    "address-19": (
+        _set(5, validator_address=b"\x07" * 19),
+        "wrong CommitSig #5: expected ValidatorAddress size 20",
+    ),
+    "address-empty": (
+        _set(5, validator_address=b""), "wrong CommitSig #5: expected ValidatorAddress size 20",
+    ),
+    "address-300": (
+        _set(5, validator_address=b"\x07" * 300),
+        "wrong CommitSig #5: expected ValidatorAddress size 20",
+    ),
+    "signature-missing": (_set(0, signature=b""), "wrong CommitSig #0: signature is missing"),
+    "signature-97": (
+        _set(7, signature=b"\x01" * (MAX_SIGNATURE_SIZE + 1)),
+        "wrong CommitSig #7: signature too big",
+    ),
+    "signature-300": (_set(7, signature=b"\x01" * 300), "wrong CommitSig #7: signature too big"),
+    "signature-96-is-fine": (_set(7, signature=b"\x01" * MAX_SIGNATURE_SIZE), None),
+    "two-faults-address-first": (
+        _set(2, validator_address=b"\x07" * 21, signature=b""),
+        "wrong CommitSig #2: expected ValidatorAddress size 20",
+    ),
+    "two-faults-flag-first": (
+        _set(2, block_id_flag=9, validator_address=b"", signature=b"\x01" * 200),
+        "wrong CommitSig #2: unknown BlockIDFlag: 9",
+    ),
+    "two-rows-lower-wins": (
+        _both(_set(6, signature=b""), _set(2, signature=b"\x01" * 120)),
+        "wrong CommitSig #2: signature too big",
+    ),
+    "last-row": (_set(7, validator_address=b"\x07"), "wrong CommitSig #7: expected ValidatorAddress size 20"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_VB_CASES))
+def test_columns_validate_basic_matches_per_row_loop(case):
+    """The same string for the same first failing index and the same
+    first failing check as CommitSig.validate_basic row by row."""
+    edit, want = _VB_CASES[case]
+    vs, _ = make_vals([1] * 8)
+    sigs = _raw_sigs(vs, random.Random(1), absent=0.0)
+    edit(sigs)
+    commit = Commit(5, 0, COL_BID, sigs)
+    assert commit.validate_basic() == want
+    assert _oracle_validate_basic(commit) == want
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_columns_validate_basic_random_faults(seed):
+    rng = random.Random(seed)
+    vs, _ = make_vals([1] * 40)
+    faults = [
+        dict(block_id_flag=0), dict(block_id_flag=77), dict(block_id_flag=1000),
+        dict(validator_address=b""), dict(validator_address=b"\x01" * 32),
+        dict(signature=b""), dict(signature=b"\x02" * 97), dict(signature=b"\x02" * 1000),
+        dict(block_id_flag=BLOCK_ID_FLAG_ABSENT), dict(block_id_flag=BLOCK_ID_FLAG_NIL),
+    ]
+    for _ in range(40):
+        sigs = _raw_sigs(vs, rng)
+        for _ in range(rng.randrange(0, 4)):
+            cs = sigs[rng.randrange(len(sigs))]
+            for k, v in rng.choice(faults).items():
+                setattr(cs, k, v)
+        commit = Commit(5, 0, COL_BID, sigs)
+        assert commit.validate_basic() == _oracle_validate_basic(commit)
+
+
+def _assert_pack_equals_oracle(vs, commit, by_address):
+    want = _outcome(_oracle_pack, vs, commit, by_address)
+    if want is not None:
+        assert _outcome(vs._commit_batch_arrays, COL_CHAIN, commit, by_address) == want
+        return None
+    idxs, vals_idx, sg, counted, ed = _oracle_pack(vs, commit, by_address)
+    got = vs._commit_batch_arrays(COL_CHAIN, commit, by_address)
+    g_idxs, g_vals_idx, g_pk, g_mg, g_sg, g_powers, g_counted, g_ed, (tmpl, tmpl_idx, ts8) = got
+    n = len(idxs)
+    assert (g_idxs.dtype, g_idxs.tolist()) == (np.int64, idxs)
+    assert (g_vals_idx.dtype, g_vals_idx.tolist()) == (np.int64, vals_idx)
+    assert (g_sg.dtype, g_sg.shape) == (np.uint8, (n, 64))
+    assert [bytes(row) for row in g_sg] == sg
+    assert (g_counted.dtype, g_counted.tolist()) == (np.bool_, counted)
+    assert (g_ed.dtype, g_ed.tolist()) == (np.bool_, ed)
+    assert (g_pk.dtype, g_pk.shape) == (np.uint8, (n, 32))
+    assert (g_powers.dtype, g_powers.tolist()) == (
+        np.int64, [vs.validators[vi].voting_power for vi in vals_idx]
+    )
+    assert (g_mg.dtype, g_mg.shape) == (np.uint8, (n, 160))
+    assert (tmpl.dtype, tmpl.shape) == (np.uint8, (2, 160))
+    assert (tmpl_idx.dtype, tmpl_idx.shape) == (np.int32, (n,))
+    assert (ts8.dtype, ts8.shape) == (np.uint8, (n, 8))
+    for r, (i, vi) in enumerate(zip(idxs, vals_idx)):
+        assert bytes(g_mg[r]) == commit.vote_sign_bytes(COL_CHAIN, i)
+        row = tmpl[tmpl_idx[r]].copy()
+        row[93:101] = ts8[r]
+        assert bytes(row) == commit.vote_sign_bytes(COL_CHAIN, i)
+        if ed[r]:
+            assert bytes(g_pk[r]) == vs.validators[vi].pub_key.bytes()
+    return got
+
+
+@pytest.mark.parametrize("by_address", [False, True], ids=["by-index", "by-address"])
+@pytest.mark.parametrize("seed", range(4))
+def test_columns_pack_matches_per_row_loop(seed, by_address):
+    """idxs, vals_idx, sg, counted, ed and tpl — values, shapes and
+    dtypes — on commits that mix absent, nil and for-block slots."""
+    rng = random.Random(100 + seed)
+    vs, _ = make_vals([rng.randrange(1, 50) for _ in range(33)])
+    for absent in (0.0, 0.2, 1.0):
+        commit = Commit(5, 0, COL_BID, _raw_sigs(vs, rng, absent=absent))
+        _assert_pack_equals_oracle(vs, commit, by_address)
+
+
+@pytest.mark.parametrize("length", [0, 1, 63, 65, 80, 96])
+def test_columns_pack_off_width_signature_leaves_the_batch(length):
+    """An ed25519 row whose signature is not 64 bytes is clamped or
+    padded in sg and leaves the ed mask (it is verified by its own key
+    type, which refuses the length); its neighbours stay where they were."""
+    vs, _ = make_vals([1] * 6)
+    sigs = _raw_sigs(vs, random.Random(7), absent=0.0)
+    sigs[1] = CommitSig.absent()
+    sigs[3].signature = bytes(range(1, length + 1))
+    commit = Commit(5, 0, COL_BID, sigs)
+    got = _assert_pack_equals_oracle(vs, commit, False)
+    assert got[7].tolist() == [True, True, False, True, True]
+    assert bytes(got[4][2]) == bytes(range(1, length + 1))[:64].ljust(64, b"\x00")
+    assert bytes(got[4][3]) == sigs[4].signature
+
+
+def test_columns_pack_refuses_first_oversized_signature():
+    vs, _ = make_vals([1] * 6)
+    sigs = _raw_sigs(vs, random.Random(8), absent=0.0)
+    sigs[0] = CommitSig.absent()
+    sigs[4].signature = b"\x01" * 300
+    sigs[2].signature = b"\x01" * (MAX_SIGNATURE_SIZE + 1)
+    for by_address in (False, True):
+        commit = Commit(5, 0, COL_BID, sigs)
+        with pytest.raises(ErrInvalidCommit, match=r"^signature #2 too big \(97\)$"):
+            vs._commit_batch_arrays(COL_CHAIN, commit, by_address)
+        _assert_pack_equals_oracle(vs, commit, by_address)
+
+
+def test_columns_pack_by_address_skips_unknown_signers():
+    """The trusting mode looks signers up by address in THIS set: the
+    commit is another set's, in its order, and strangers are dropped."""
+    vs, _ = make_vals([3, 5, 7, 9])
+    stranger = Ed25519PrivKey.from_secret(b"stranger").pub_key().address()
+    sigs = _raw_sigs(vs, random.Random(9), absent=0.0)
+    sigs = [sigs[2], CommitSig(BLOCK_ID_FLAG_COMMIT, stranger, 5, b"\x05" * 64), sigs[0],
+            CommitSig.absent(), sigs[3], sigs[2]]
+    commit = Commit(5, 0, COL_BID, sigs)
+    before = SEAM_COUNTS.snapshot()
+    got = _assert_pack_equals_oracle(vs, commit, True)
+    after = SEAM_COUNTS.snapshot()
+    assert got[0].tolist() == [0, 2, 4, 5]
+    assert got[1].tolist() == [2, 0, 3, 2]
+    assert got[5].tolist() == [vs.validators[vi].voting_power for vi in (2, 0, 3, 2)]
+    assert after["seam_packed_rows"] - before["seam_packed_rows"] == 4
+    assert after["seam_fixup_rows"] - before["seam_fixup_rows"] == 1  # the stranger
+
+
+_FULL_REPLAY_CASES = {
+    # nine present rows of power 10: 90 in all, more than 60 needed,
+    # so the 7th for-block row carries the tally past the quorum
+    "all-sound": ({}, None),
+    "forged-before-quorum": ({"bad": [2]}, ("ErrInvalidCommitSignature", "wrong signature #3 (")),
+    "forged-on-the-quorum-row": ({"bad": [6]}, ("ErrInvalidCommitSignature", "wrong signature #7 (")),
+    "forged-after-quorum": ({"bad": [7, 8]}, None),
+    "quorum-on-the-last-row": ({"nil": [3, 5]}, None),
+    "quorum-on-the-last-row-forged": (
+        {"nil": [3, 5], "bad": [8]}, ("ErrInvalidCommitSignature", "wrong signature #9 (")
+    ),
+    "forged-nil-row-before-quorum": (
+        {"nil": [3, 5], "bad": [5]}, ("ErrInvalidCommitSignature", "wrong signature #6 (")
+    ),
+    "one-signer-short": ({"nil": [0, 4, 8]}, ("ErrNotEnoughVotingPower", "have 60, need > 60")),
+    "no-for-block-row": ({"nil": list(range(9))}, ("ErrNotEnoughVotingPower", "have 0, need > 60")),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FULL_REPLAY_CASES))
+def test_replay_full_matches_the_early_return_loop(case):
+    spec, want = _FULL_REPLAY_CASES[case]
+    vs, _ = make_vals([10] * 10)
+    sigs = _raw_sigs(vs, random.Random(3), absent=0.0, nil=0.0)
+    sigs[0] = CommitSig.absent()  # rows are slots 1..9: row r is signature r + 1
+    commit = Commit(5, 0, COL_BID, sigs)
+    idxs = np.arange(1, 10)
+    vs9, _ = make_vals([10] * 9)
+    powers = np.full(9, 10, dtype=np.int64)
+    counted = np.ones(9, dtype=bool)
+    counted[spec.get("nil", [])] = False
+    ok = np.ones(9, dtype=bool)
+    ok[spec.get("bad", [])] = False
+    got = _outcome(vs9._replay_commit_full, commit, ok, idxs, powers, counted)
+    assert got == _outcome(_oracle_replay_full, vs9, commit, ok, idxs.tolist(), powers, counted)
+    if want is None:
+        assert got is None
+    else:
+        assert got[0] == want[0] and got[1].startswith(want[1])
+        if want[0] == "ErrInvalidCommitSignature":
+            i = int(got[1].split("#")[1].split()[0])
+            assert got[1] == f"wrong signature #{i} ({sigs[i].validator_address.hex()})"
+
+
+def test_replay_full_of_no_rows():
+    vs, _ = make_vals([10] * 3)
+    commit = Commit(5, 0, COL_BID, [CommitSig.absent()] * 3)
+    empty = np.zeros(0, dtype=np.int64)
+    got = _outcome(vs._replay_commit_full, commit, empty.astype(bool), empty, empty, empty.astype(bool))
+    assert got == ("ErrNotEnoughVotingPower", "have 0, need > 20")
+
+
+_TRUSTING_REPLAY_CASES = {
+    # nine rows of power 10 against a set of 90 at trust level 1/3:
+    # more than 30 needed, so the 4th for-block row is the last visited
+    "all-sound": ({}, None),
+    "duplicate-before-quorum": ({"dup": {2: 0}}, ("ErrInvalidCommit", "double vote from validator index 0")),
+    "duplicate-on-the-quorum-row": ({"dup": {3: 1}}, ("ErrInvalidCommit", "double vote from validator index 1")),
+    "duplicate-after-quorum": ({"dup": {4: 0, 8: 2}}, None),
+    "forged-before-quorum": ({"bad": [1]}, ("ErrInvalidCommitSignature", "wrong signature #11")),
+    "forged-after-quorum": ({"bad": [4, 7]}, None),
+    "duplicate-and-forged-same-row": (
+        {"dup": {2: 1}, "bad": [2]}, ("ErrInvalidCommit", "double vote from validator index 1")
+    ),
+    "forged-then-duplicate": ({"bad": [1], "dup": {2: 0}}, ("ErrInvalidCommitSignature", "wrong signature #11")),
+    "duplicate-then-forged": (
+        {"dup": {1: 0}, "bad": [2]}, ("ErrInvalidCommit", "double vote from validator index 0")
+    ),
+    "third-vote-of-one-validator": (
+        {"dup": {1: 0, 2: 0}}, ("ErrInvalidCommit", "double vote from validator index 0")
+    ),
+    "nil-rows-move-the-quorum-row": (
+        {"nil": [0, 1], "dup": {5: 4}}, ("ErrInvalidCommit", "double vote from validator index 4")
+    ),
+    "one-signer-short": (
+        {"nil": [0, 1, 2, 3, 4, 5]}, ("ErrNotEnoughVotingPower", "have 30, need > 30")
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TRUSTING_REPLAY_CASES))
+def test_replay_trusting_matches_the_early_return_loop(case):
+    spec, want = _TRUSTING_REPLAY_CASES[case]
+    vs, _ = make_vals([10] * 9)
+    idxs = np.arange(10, 19)  # the commit is another set's: its own slot numbers
+    vals_idx = np.arange(9)
+    for r, vi in spec.get("dup", {}).items():
+        vals_idx[r] = vi
+    powers = np.full(9, 10, dtype=np.int64)
+    counted = np.ones(9, dtype=bool)
+    counted[spec.get("nil", [])] = False
+    ok = np.ones(9, dtype=bool)
+    ok[spec.get("bad", [])] = False
+    level = Fraction(1, 3)
+    got = _outcome(vs._replay_commit_trusting, ok, idxs, vals_idx, powers, counted, level)
+    assert got == want
+    assert got == _outcome(
+        _oracle_replay_trusting, vs, ok, idxs.tolist(), vals_idx.tolist(), powers, counted, level
+    )
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_replays_match_the_loops_on_random_rows(seed):
+    """Random powers, nil rows, forged rows and repeated signers: both
+    replays give the loops' verdict, text included, wherever the faults
+    fall about the quorum row."""
+    rng = random.Random(900 + seed)
+    for _ in range(150):
+        n_vals = rng.randrange(1, 24)
+        vs, _ = make_vals([rng.randrange(1, 100) for _ in range(n_vals)])
+        sigs = _raw_sigs(vs, rng, absent=0.0, nil=0.0)
+        commit = Commit(5, 0, COL_BID, sigs)
+        keep = sorted(rng.sample(range(n_vals), rng.randrange(0, n_vals + 1)))
+        idxs = np.asarray(keep, dtype=np.int64)
+        n = len(keep)
+        counted = np.asarray([rng.random() < 0.8 for _ in keep], dtype=bool)
+        ok = np.asarray([rng.random() < 0.93 for _ in keep], dtype=bool)
+        all_powers = np.asarray([v.voting_power for v in vs.validators], dtype=np.int64)
+        powers = all_powers[idxs]
+        assert _outcome(vs._replay_commit_full, commit, ok, idxs, powers, counted) == _outcome(
+            _oracle_replay_full, vs, commit, ok, keep, powers, counted
+        )
+        vals_idx = idxs.copy()
+        for _ in range(rng.randrange(0, 3)):
+            if n >= 2:
+                a, b = rng.sample(range(n), 2)
+                vals_idx[a] = vals_idx[b]
+        powers = all_powers[vals_idx] if n else powers
+        level = rng.choice([Fraction(1, 3), Fraction(1, 2), Fraction(2, 3), Fraction(1, 1)])
+        assert _outcome(
+            vs._replay_commit_trusting, ok, idxs, vals_idx, powers, counted, level
+        ) == _outcome(
+            _oracle_replay_trusting, vs, ok, keep, vals_idx.tolist(), powers, counted, level
+        )
+
+
+def test_columns_die_with_the_commit():
+    """No memo outlives a Commit: every fresh Commit over one shared
+    CommitSig list reads its own columns (the counter says so), a Commit
+    reads them once, and a deep copy starts without them — so a copy with
+    a tampered signature is packed, and refused, from the tampered bytes."""
+    vs, by_addr = make_vals([1] * 6)
+    commit, bid = make_commit(vs, by_addr, absent_idx={1})
+    shared = commit.signatures
+    before = SEAM_COUNTS.snapshot()
+    for _ in range(2):
+        fresh = Commit(5, 0, bid, shared)
+        vs.verify_commit("test-chain", bid, 5, fresh)
+    twice = SEAM_COUNTS.snapshot()
+    assert twice["seam_column_rows"] - before["seam_column_rows"] == 2 * 6
+    assert twice["seam_packed_rows"] - before["seam_packed_rows"] == 2 * 5
+    assert twice["seam_fixup_rows"] == before["seam_fixup_rows"]
+    vs.verify_commit("test-chain", bid, 5, fresh)
+    again = SEAM_COUNTS.snapshot()
+    assert again["seam_column_rows"] == twice["seam_column_rows"]
+    assert again["seam_packed_rows"] - twice["seam_packed_rows"] == 5
+
+    forged = copy.deepcopy(fresh)
+    assert not hasattr(forged, "_cols_cache") and hasattr(fresh, "_cols_cache")
+    sig = bytearray(forged.signatures[0].signature)
+    sig[5] ^= 0x40
+    forged.signatures[0].signature = bytes(sig)
+    packed = vs._commit_batch_arrays("test-chain", forged, by_address=False)
+    assert bytes(packed[4][0]) == bytes(sig)
+    with pytest.raises(ErrInvalidCommitSignature, match="wrong signature #0"):
+        vs.verify_commit("test-chain", bid, 5, forged)
+    assert SEAM_COUNTS.snapshot()["seam_column_rows"] - again["seam_column_rows"] == 6
+    vs.verify_commit("test-chain", bid, 5, fresh)  # the original stands
