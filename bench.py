@@ -509,7 +509,7 @@ def run_bench(platform: str):
             import jax.numpy as jnp
 
             s3 = model._table_stage_fns()[2]
-            s1d, s2d = model._dense_stage_fns()[:2]
+            s1d, s2d = model._slot_stage_fns()
             # the table's own padded row count, NOT a hardcoded 10240:
             # TM_BENCH_N smoke runs build smaller tables
             n_pad = int(e.tables.shape[0])
@@ -519,7 +519,7 @@ def run_bench(platform: str):
             tb_d, ao_d = e.tables[:n_pad], e.a_ok[:n_pad]
 
             def chain():
-                # dense full-commit shape: no index gathers anywhere
+                # slot order, one commit a launch: no index gathers anywhere
                 sd, kd, s_ok = s1d(pk_d, mg_d, sg_d)
                 px, py, pz, pt, a_ok = s2d(sd, kd, tb_d, ao_d)
                 return s3(px, py, pz, pt, sg_d, a_ok, s_ok)

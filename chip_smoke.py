@@ -324,7 +324,7 @@ def phase_commit(seed: int, prov, errors: ErrorLog, n_vals: int = COMMIT_VALS, k
             f"tally={int(tally_ref)} | verify_commit {t_commit:.3f}s "
             f"templated {t_tpl:.3f}s generic {t_gen:.3f}s (first case includes compile and table build)"
         )
-    check_device_did_the_work(prov, errors, rows0, submitted, "tabled-tpl")
+    check_device_did_the_work(prov, errors, rows0, submitted, "slots-tpl")
 
 
 # -- light-1k -----------------------------------------------------------------
@@ -414,11 +414,11 @@ def phase_light(
     say(f"verify_commits_batched: {len(specs)} specs, rejected spec {failed} ({res[failed[0]]!r})")
     if rows > MAX_DEVICE_ROWS:
         check(
-            any(k[0] == "tabled-tpl" and k[1] == MAX_DEVICE_ROWS and e.ready
+            any(k[0] == "slots-tpl" and k[1] == MAX_DEVICE_ROWS and e.ready
                 for k, e in prov.model._entries.items()),
-            "the windowed path never ran a full MAX_DEVICE_ROWS window",
+            "the chain never ran a full MAX_DEVICE_ROWS launch in slot order",
         )
-    check_device_did_the_work(prov, errors, rows0, submitted, "tabled-tpl")
+    check_device_did_the_work(prov, errors, rows0, submitted, "slots-tpl")
 
 
 # -- node-128 -----------------------------------------------------------------
@@ -525,13 +525,13 @@ def pipeline_view(engines: dict) -> dict:
 
 def node_is_warm(pipe: dict) -> bool:
     """The boot-time warm-up (node.py on_start) is done: this chain's
-    tables are built, its templated bucket is ready and nothing is
-    still compiling."""
+    tables are built, a commit's templated slot-order shape is ready
+    and nothing is still compiling."""
     buckets = pipe.get("buckets") or {}
     states = [b["state"] for b in buckets.values()]
     return (
         any(k.startswith("tables:") and b["state"] == "ready" for k, b in buckets.items())
-        and any(k.startswith("fn:tabled-tpl/") and b["state"] == "ready" for k, b in buckets.items())
+        and any(k.startswith("fn:slots-tpl/") and b["state"] == "ready" for k, b in buckets.items())
         and not any(s in ("compiling", "cold") for s in states)
     )
 

@@ -52,44 +52,48 @@ class RowCounts:
             return self.device, self.host
 
 
-class SeamCounts:
-    """Thread-safe counts of the verify seam's column form
-    (types/block.CommitColumns, ValidatorSet._commit_batch_arrays),
-    counted where the work is done: ``column_rows`` signature slots
-    read into columns (once per Commit object — a count that stands
-    still while commits are verified means a memo outlived its
-    commit), ``packed_rows`` rows packed from columns for a provider,
-    ``fixup_rows`` those of them off the common shape — a non-64-byte
-    signature or a non-ed25519 key (verified row by row outside the
-    batch) or an unknown address (dropped). An all-ed25519 commit
-    reads 0 fix-up rows; a BLS or mixed set shows the other side."""
+class NamedCounts:
+    """Thread-safe monotonic counts under one prefix, counted where the
+    work is done: ``add(name=n, ...)``; ``snapshot()`` gives
+    ``{prefix_name: count}`` (engine_stats()["counters"],
+    ``tendermint_crypto_*``)."""
 
-    __slots__ = ("_lock", "column_rows", "packed_rows", "fixup_rows")
+    __slots__ = ("_lock", "_prefix", "_n")
 
-    def __init__(self):
+    def __init__(self, prefix: str, names):
         self._lock = threading.Lock()
-        self.column_rows = 0
-        self.packed_rows = 0
-        self.fixup_rows = 0
+        self._prefix = prefix
+        self._n = dict.fromkeys(names, 0)
 
-    def add(self, column_rows: int = 0, packed_rows: int = 0, fixup_rows: int = 0) -> None:
+    def add(self, **counts: int) -> None:
         with self._lock:
-            self.column_rows += column_rows
-            self.packed_rows += packed_rows
-            self.fixup_rows += fixup_rows
+            for name, n in counts.items():
+                self._n[name] += n
 
     def snapshot(self) -> dict:
         with self._lock:
-            return {
-                "seam_column_rows": self.column_rows,
-                "seam_packed_rows": self.packed_rows,
-                "seam_fixup_rows": self.fixup_rows,
-            }
+            return {f"{self._prefix}_{name}": n for name, n in self._n.items()}
 
 
-# The seam packs before a provider is chosen (types/ knows none), so
-# its counts are the process's, as crypto/merkle.device_stats() are.
-SEAM_COUNTS = SeamCounts()
+# The verify seam's column form (types/block.CommitColumns,
+# ValidatorSet._commit_batch_arrays): ``column_rows`` signature slots
+# read into columns (once per Commit object — a count that stands still
+# while commits are verified means a memo outlived its commit),
+# ``packed_rows`` rows packed from columns for a provider,
+# ``fixup_rows`` those of them off the common shape — a non-64-byte
+# signature or a non-ed25519 key (verified row by row outside the
+# batch) or an unknown address (dropped). An all-ed25519 commit reads 0
+# fix-up rows; a BLS or mixed set shows the other side. The seam packs
+# before a provider is chosen (types/ knows none), so its counts are
+# the process's, as crypto/merkle.device_stats() are.
+SEAM_COUNTS = NamedCounts("seam", ("column_rows", "packed_rows", "fixup_rows"))
+
+# Which table operand the cached-table path took (models/verifier.py
+# plan_slots): ``slot_rows`` real rows verified in slot order (tables
+# read in place), ``slot_pad`` the empty slots launched with them,
+# ``gathered_rows`` rows whose ~30 KB key tables were gathered (sparse
+# or unordered batches, a mesh, sharded tables). Process-wide too.
+TABLED_COUNTS = NamedCounts("tabled", ("slot_rows", "slot_pad", "gathered_rows"))
 
 
 class BatchVerifier:

@@ -53,7 +53,9 @@ import time
 
 import numpy as np
 
-from tendermint_tpu.crypto.batch import SEAM_COUNTS, BatchVerifier, CPUBatchVerifier
+from tendermint_tpu.crypto.batch import (
+    SEAM_COUNTS, TABLED_COUNTS, BatchVerifier, CPUBatchVerifier,
+)
 from tendermint_tpu.utils import faultinject as faults
 from tendermint_tpu.utils import trace
 
@@ -624,6 +626,7 @@ class PipelinedVerifier(BatchVerifier):
         for k, v in self.cache.stats().items():
             s[f"cache_{k}"] = v
         s.update(SEAM_COUNTS.snapshot())
+        s.update(TABLED_COUNTS.snapshot())
         return s
 
     def engine_stats(self) -> Dict[str, object]:
@@ -657,8 +660,10 @@ class PipelinedVerifier(BatchVerifier):
         counters["cache_hits"] = cache["hits"]
         counters["cache_misses"] = cache["misses"]
         # the verify seam packs before it reaches any provider, so its
-        # counts are the process's (crypto/batch.SeamCounts)
+        # counts are the process's (crypto/batch.SEAM_COUNTS), as are the
+        # cached-table path's slot-order / gathered row counts
         counters.update(SEAM_COUNTS.snapshot())
+        counters.update(TABLED_COUNTS.snapshot())
         buckets: Dict[str, dict] = {}
         breakers: Dict[str, dict] = {}
         model = self.model  # the wrapped VerifierModel (None for CPU inner)

@@ -16,8 +16,12 @@ Two verify pipelines share the buckets:
   tables of each key (built once per valset digest, LRU of
   MAX_CACHED_VALSETS, device-resident) remove decompression, the
   per-row table build, and 7/8 of the scan doublings from the
-  per-commit program. Streams past MAX_DEVICE_ROWS as in-flight
-  windows; ``register_valset`` pre-builds at node start.
+  per-commit program. On one device, whole commits of a set they
+  mostly fill run in SLOT ORDER (``plan_slots``): each row goes to its
+  validator's slot and stage 2 reads the tables where they lie; sparse
+  or unordered batches, a mesh and sharded tables GATHER each row's
+  table by validator index. Streams past MAX_DEVICE_ROWS as in-flight
+  launches; ``register_valset`` pre-builds at node start.
 
 Two compile disciplines:
 
@@ -41,7 +45,7 @@ from __future__ import annotations
 import os
 import threading
 import time
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -165,6 +169,15 @@ _TABLE_BUILD_CHUNK = 16384
 # (~2GB) table; small and mid tables gather fine (round-3 ingest data).
 _GATHER_POLICY_MIN_TABLE = 16384
 
+# Slot order against gathered order (plan_slots): a batch goes to its
+# validators' slots when the slots it would launch are at most this
+# many times the padded rows the gathered pair would launch for it.
+# From the two costs read on a v5e (PERF.md section 6, PR 30): a whole
+# warm call of 10,240 gathered rows 43.5 ms against 28.8 ms in slot
+# order, 16,384 rows 75.4 against 47.4 ms — a gathered row costs
+# 1.51-1.59 slots; the lower edge, rounded down.
+_SLOT_GATHER_RATIO = 1.5
+
 # Largest valset served by ONE device table. The reference caps
 # commits at 10k votes (types/vote_set.go:18 MaxVotesCount); beyond
 # ~16k rows a single table's gathers go pathological (the 50k-ingest
@@ -182,6 +195,65 @@ MAX_TABLED_VALSET = int(os.environ.get("TM_MAX_TABLED_VALSET", "16384"))
 # the live cap from the mesh size (N=1 reproduces this constant
 # exactly). Beyond the cap the generic pipeline takes over.
 MAX_SHARDED_VALSET = int(os.environ.get("TM_MAX_SHARDED_VALSET", str(1 << 16)))
+
+
+class SlotPlan(NamedTuple):
+    """Where a batch's rows go in slot order (plan_slots)."""
+
+    slots: np.ndarray  # (n,) slot of row r, the launches laid end to end
+    launches: Tuple[Tuple[int, int, int], ...]  # (first row, end row, commits C) a launch
+
+
+def _gathered_rows(n: int) -> int:
+    """Padded rows the gathered pair launches for n rows on one device:
+    one bucket, or the full windows and the tail's bucket."""
+    tail = n % MAX_DEVICE_ROWS if n > MAX_DEVICE_ROWS else n
+    return n - tail + (_bucket(tail, 1) if tail else 0)
+
+
+def plan_slots(row_idx, v: int) -> Optional[SlotPlan]:
+    """Slot order for a batch against a V-row table, or None where the
+    gathered pair is cheaper. Pure numpy, from row_idx and V alone.
+
+    row_idx is cut into maximal strictly increasing runs — a commit's
+    rows are one run; a duplicate or a step back starts the next, so
+    unordered indices (the trusting path's lookups by address, a vote
+    drain) become many short runs. Run k is "commit" k: its rows go to
+    slots k*V + row_idx. Runs are dealt to launches of MAX_DEVICE_ROWS
+    // V commits, the last launch's count rounded up to a power of two
+    (a set meets at most log2 of that many + 1 program shapes). Slots
+    nobody signed for carry zeros and their verdicts are never read.
+
+    Slot order is taken when its slots are at most _SLOT_GATHER_RATIO
+    times the padded rows of the gathered pair (_gathered_rows): whole
+    commits of a set they mostly fill. A sparse or unordered batch has
+    slots far beyond its rows and stays gathered."""
+    idx = np.asarray(row_idx, dtype=np.int64)
+    n = idx.shape[0]
+    if n == 0 or not 0 < v <= MAX_DEVICE_ROWS or idx.min() < 0 or idx.max() >= v:
+        return None
+    run_start = np.flatnonzero(idx[1:] <= idx[:-1]) + 1  # rows that open a run
+    runs = run_start.shape[0] + 1
+    per = max(1, MAX_DEVICE_ROWS // v)  # whole commits a launch holds
+    full, rest = divmod(runs, per)
+    last = min(per, 1 << (rest - 1).bit_length()) if rest else 0
+    if (full * per + last) * v > _SLOT_GATHER_RATIO * _gathered_rows(n):
+        return None
+    run_of = np.zeros(n, dtype=np.int64)
+    run_of[run_start] = 1
+    bounds = np.concatenate([[0], run_start, [n]])  # each run's first row, then n
+    launches = tuple(
+        (int(bounds[k]), int(bounds[min(k + per, runs)]), per if k + per <= runs else last)
+        for k in range(0, runs, per)
+    )
+    return SlotPlan(np.cumsum(run_of) * v + idx, launches)
+
+
+def _to_slots(rows: np.ndarray, at: np.ndarray, n_slots: int) -> np.ndarray:
+    """rows scattered to their slots of a zeroed (n_slots, ...) array."""
+    out = np.zeros((n_slots,) + rows.shape[1:], dtype=rows.dtype)
+    out[at] = rows
+    return out
 
 
 class _TablesEntry:
@@ -211,7 +283,7 @@ class VerifierModel:
         self, mesh=None, block_on_compile: bool = True, logger=None,
         row_counts=None,
     ):
-        from tendermint_tpu.crypto.batch import CPUBatchVerifier, RowCounts
+        from tendermint_tpu.crypto.batch import TABLED_COUNTS, CPUBatchVerifier, RowCounts
         from tendermint_tpu.utils.watchdog import CircuitBreaker
 
         self.mesh = mesh
@@ -223,6 +295,7 @@ class VerifierModel:
         # serves every cold-bucket and ragged-batch fallback below
         self.row_counts = row_counts if row_counts is not None else RowCounts()
         self._cpu = CPUBatchVerifier(row_counts=self.row_counts)
+        self._tabled_counts = TABLED_COUNTS
         self._lock = threading.Lock()
         self._entries: Dict[Tuple[str, int, int], _Entry] = {}
         self._valset_tables: Dict[bytes, _TablesEntry] = {}  # insertion-ordered LRU
@@ -585,8 +658,9 @@ class VerifierModel:
     # per-commit program: decompression, the per-row table build and 240
     # of 256 shared doublings (256 - 4*SPLIT_W). verify_rows_cached is
     # the resulting fast path: challenge hash + 16-doubling (4*SPLIT_W)
-    # split scan + blocked-inversion encode, with each row's table
-    # gathered by validator index on device.
+    # split scan + blocked-inversion encode, with each row's table read
+    # in place (slot order, plan_slots) or gathered by validator index
+    # on device.
 
     def _table_stage_fns(self):
         cached = getattr(self, "_table_stages", None)
@@ -670,22 +744,23 @@ class VerifierModel:
                 )
         return self._materialize
 
-    def _dense_stage_fns(self):
-        """Single-device DENSE tabled stages for the full-commit shape
-        (row i == validator i): stage 1 consumes the device-resident
-        pubkey matrix directly and stage 2 skips the per-row table
-        gather — TPU gathers serialize, and the ~30KB/row table gather
-        was ~30% of stage-2 time at 10k rows."""
-        cached = getattr(self, "_dense_stages", None)
+    def _slot_stage_fns(self):
+        """Single-device tabled stages 1 and 2 in SLOT ORDER (plan_slots):
+        C whole commits of V slots a launch, the set's pubkey matrix and
+        key tables consumed as they lie — no index, no per-row gather
+        of ~30 KB of table (the gathered scan's copy is 315 MB a
+        10,240-row launch, 16.7 of its 39.4 ms on a v5e). C = 1 is a full
+        commit's shape."""
+        cached = getattr(self, "_slot_stages", None)
         if cached is not None:
             return cached
         from tendermint_tpu.models.aot_cache import AotJit
 
-        self._dense_stages = (
-            AotJit(ops_ed.verify_stage_prepare_tabled, "t-prepare-d"),
-            AotJit(ops_ed.verify_stage_scan_tabled_dense, "t-scan-d"),
+        self._slot_stages = (
+            AotJit(ops_ed.verify_stage_prepare_tabled_slots, "t-prepare-s"),
+            AotJit(ops_ed.verify_stage_scan_tabled_slots, "t-scan-s"),
         )
-        return self._dense_stages
+        return self._slot_stages
 
     def _build_tables(self, e: _TablesEntry, key: bytes, pubkeys: np.ndarray) -> None:
         from tendermint_tpu.models import aot_cache
@@ -913,9 +988,12 @@ class VerifierModel:
 
         row_idx MUST index into all_pubkeys; rows are independent, so
         duplicate indices are fine (the trusting path may produce them).
-        _window_tail is internal: the windowed path's tail slice must
-        not hit the small-batch gather policy (the windows already ran;
-        nullifying the tail would discard all their device work).
+        Its shape alone picks the table operand: whole commits in
+        validator order go to their validators' slots (plan_slots),
+        anything else gathers. _window_tail is internal: the windowed
+        path's tail slice must not hit the small-batch gather policy
+        (the windows already ran; nullifying the tail would discard all
+        their device work).
         """
         src = ("mat", np.asarray(msgs, dtype=np.uint8))
         return self._rows_cached_core(
@@ -954,6 +1032,14 @@ class VerifierModel:
         if e.shards is not None:
             return sum(int(s.shape[0]) for s in e.shards)
         return int(e.tables.shape[0])
+
+    def _slot_table_rows(self, e: _TablesEntry) -> int:
+        """V, the rows of the one table slot order reads in place; 0
+        under a mesh or with sharded tables, which gather (plan_slots
+        declines)."""
+        if self.mesh is None and e.shards is None:
+            return int(e.tables.shape[0])
+        return 0
 
     def _scan_rows(self, e: _TablesEntry, sd, kd, idx_dev):
         """Dispatch the right stage-2 flavor: single table (gathered)
@@ -997,26 +1083,47 @@ class VerifierModel:
             return ("mat", src[1][sl])
         return ("tpl", src[1], src[2][sl], src[3][sl])
 
-    def _src_stage1(self, e: _TablesEntry, src, dense: bool, n_pad: int, idx_dev, sg_dev):
-        """Dispatch stage 1 for (source, dense) and return
-        (sd, kd, s_ok). Inputs are padded to n_pad here. Both sources
-        converge on the SAME prepare executables: the templated source
-        materializes its (n_pad, W) u8 messages on device first (one
-        tiny extra dispatch; the H2D saving is the point)."""
+    @staticmethod
+    def _src_to_slots(src, rows: slice, at: np.ndarray, n_slots: int):
+        """The source's rows `rows` scattered to slots `at` of a zeroed
+        n_slots-row source (templates are shared)."""
         if src[0] == "mat":
-            mg = jnp.asarray(self._pad(src[1], n_pad))
-        else:
-            _, templates, tmpl_idx, ts8 = src
-            mg = self._materialize_fn()(
-                jnp.asarray(self._pad(templates, self._src_tpl_pad(src))),
-                jnp.asarray(self._pad(tmpl_idx, n_pad)),
-                jnp.asarray(self._pad(ts8, n_pad)),
-            )
-        if dense:
-            s1d = self._dense_stage_fns()[0]
-            return s1d(e.pk_dev[:n_pad], mg, sg_dev)
-        s1 = self._table_stage_fns()[0]
-        return s1(e.pk_dev, idx_dev, mg, sg_dev)
+            return ("mat", _to_slots(src[1][rows], at, n_slots))
+        return (
+            "tpl", src[1],
+            _to_slots(src[2][rows], at, n_slots), _to_slots(src[3][rows], at, n_slots),
+        )
+
+    def _src_messages(self, src, n_pad: int):
+        """The source's (n_pad, W) u8 messages on the device, rows
+        padded to n_pad here. Both sources converge on the SAME prepare
+        executables: the templated source materializes its messages on
+        device first (one tiny extra dispatch; the H2D saving is the
+        point)."""
+        if src[0] == "mat":
+            return jnp.asarray(self._pad(src[1], n_pad))
+        _, templates, tmpl_idx, ts8 = src
+        return self._materialize_fn()(
+            jnp.asarray(self._pad(templates, self._src_tpl_pad(src))),
+            jnp.asarray(self._pad(tmpl_idx, n_pad)),
+            jnp.asarray(self._pad(ts8, n_pad)),
+        )
+
+    def _gathered_launch(self, e: _TablesEntry, src, n_pad: int, idx_dev, sg_dev):
+        """Stages 1-3 of the gathered pair over one padded launch;
+        returns the device verdicts."""
+        s1, _, s3 = self._table_stage_fns()[:3]
+        sd, kd, s_ok = s1(e.pk_dev, idx_dev, self._src_messages(src, n_pad), sg_dev)
+        px, py, pz, pt, a_ok = self._scan_rows(e, sd, kd, idx_dev)
+        return s3(px, py, pz, pt, sg_dev, a_ok, s_ok)
+
+    def _slot_launch(self, e: _TablesEntry, src, n_slots: int, sg_dev):
+        """Stages 1-3 in slot order over one launch of n_slots = C*V
+        slots (src and sg_dev already scattered to them)."""
+        s1, s2 = self._slot_stage_fns()
+        sd, kd, s_ok = s1(e.pk_dev, self._src_messages(src, n_slots), sg_dev)
+        px, py, pz, pt, a_ok = s2(sd, kd, e.tables, e.a_ok)
+        return self._table_stage_fns()[2](px, py, pz, pt, sg_dev, a_ok, s_ok)
 
     def _rows_cached_core(
         self, valset_key: bytes, all_pubkeys, row_idx, src, sigs,
@@ -1028,21 +1135,24 @@ class VerifierModel:
         e = self._tables_entry(valset_key, np.asarray(all_pubkeys, dtype=np.uint8))
         if e is None:
             return None
+        idx_np = np.asarray(row_idx, dtype=np.int32)
+        plan = None if _window_tail else plan_slots(idx_np, self._slot_table_rows(e))
+        if plan is not None:
+            # whole commits of a set they mostly fill: rows go to their
+            # validators' slots and stage 2 reads the tables in place
+            return self._rows_cached_slots(e, plan, src, sigs)
         if n > MAX_DEVICE_ROWS:
             # cross-height streaming (eval 3): full windows through the
             # tabled stages, all in flight, one sync — the per-window
             # decompress and table build the generic path pays are
             # already hoisted into the cached tables
             return self._rows_cached_windowed(
-                valset_key, e, all_pubkeys, row_idx, src, sigs
+                valset_key, e, all_pubkeys, idx_np, src, sigs
             )
         faults.maybe("device.verify")
         n_pad = _bucket(n, self._pad_multiple())
-        idx_np = np.asarray(row_idx, dtype=np.int32)
-        dense = self._dense_applies(e, idx_np, n, n_pad)
         if (
-            not dense
-            and not _window_tail
+            not _window_tail
             and e.shards is None
             and self._table_rows(e) > _GATHER_POLICY_MIN_TABLE
             and self._table_rows(e) > 4 * n_pad
@@ -1062,24 +1172,13 @@ class VerifierModel:
         if not ent.ready and not self.block_on_compile:
             self._compile_tabled_async(ent, e, n_pad, src)
             return None
-        s3 = self._table_stage_fns()[2]
         sg = jnp.asarray(self._pad(np.asarray(sigs, dtype=np.uint8), n_pad))
         t0 = time.perf_counter()
         try:
-            if dense:
-                # full-commit shape (row i == validator i): no gathers
-                sd, kd, s_ok = self._src_stage1(e, src, True, n_pad, None, sg)
-                s2d = self._dense_stage_fns()[1]
-                px, py, pz, pt, a_ok = s2d(
-                    sd, kd, e.tables[:n_pad], e.a_ok[:n_pad]
-                )
-            else:
-                idx = jnp.asarray(self._pad(idx_np, n_pad))
-                sd, kd, s_ok = self._src_stage1(e, src, False, n_pad, idx, sg)
-                px, py, pz, pt, a_ok = self._scan_rows(e, sd, kd, idx)
-            ok = s3(px, py, pz, pt, sg, a_ok, s_ok)
-            out = np.asarray(ok)[:n]
+            idx = jnp.asarray(self._pad(idx_np, n_pad))
+            out = np.asarray(self._gathered_launch(e, src, n_pad, idx, sg))[:n]
             self.row_counts.add(device=n)
+            self._tabled_counts.add(gathered_rows=n)
         except Exception as ex:
             # None-means-fallback, never an exception into commit
             # verification: a transient device or compile failure must
@@ -1095,23 +1194,57 @@ class VerifierModel:
             ent.ready = True
         return out
 
-    def _dense_applies(
-        self, e: _TablesEntry, idx_np: np.ndarray, n: int, n_pad: int
-    ) -> bool:
-        """True when the batch is the full-commit shape: single device,
-        row i verifies validator i, and the padded batch fits the
-        table's leading axis (so static prefix slices replace gathers).
-        The host arange compare is ~µs at 10k rows."""
-        return (
-            self.mesh is None
-            and e.shards is None
-            and n_pad <= int(e.tables.shape[0])
-            and idx_np.shape[0] == n
-            and bool((idx_np == np.arange(n, dtype=np.int32)).all())
-        )
+    def _rows_cached_slots(
+        self, e: _TablesEntry, plan: SlotPlan, src, sigs
+    ) -> Optional[np.ndarray]:
+        """Verify a planned batch in slot order: every launch in flight,
+        one sync, the verdicts read back from the rows' slots. Same
+        None-means-fallback contract as the gathered paths; only the
+        real rows count as device rows."""
+        n = int(plan.slots.shape[0])
+        v = int(e.tables.shape[0])
+        ents = {
+            c: self._tabled_bucket_entry(e, c * v, src, slots=True)
+            for c in {c for _, _, c in plan.launches}
+        }
+        cold = [(ent, c) for c, ent in ents.items() if not ent.ready]
+        if cold and not self.block_on_compile:
+            # every shape must be warm before anything is dispatched
+            for ent, c in cold:
+                self._compile_tabled_async(ent, e, c * v, src, slots=True)
+            return None
+        faults.maybe("device.verify")
+        sg = np.asarray(sigs, dtype=np.uint8)
+        t0 = time.perf_counter()
+        try:
+            outs, base = [], 0
+            for lo, hi, c in plan.launches:
+                rows, at = slice(lo, hi), plan.slots[lo:hi] - base
+                outs.append(
+                    self._slot_launch(
+                        e, self._src_to_slots(src, rows, at, c * v), c * v,
+                        jnp.asarray(_to_slots(sg[rows], at, c * v)),
+                    )
+                )
+                base += c * v
+            out = np.concatenate([np.asarray(o) for o in outs])[plan.slots]
+            self.row_counts.add(device=n)
+            self._tabled_counts.add(slot_rows=n, slot_pad=base - n)
+        except Exception as ex:
+            self.logger.error(
+                "tabled slot-order verify failed (falling back)",
+                rows=n, err=repr(ex)[:200],
+            )
+            return None
+        for ent, _ in cold:
+            ent.compile_s = time.perf_counter() - t0
+            ent.ready = True
+        return out
 
-    def _tabled_bucket_entry(self, e: _TablesEntry, n_pad: int, src) -> _Entry:
-        kind = "tabled" if src[0] == "mat" else "tabled-tpl"
+    def _tabled_bucket_entry(
+        self, e: _TablesEntry, n_pad: int, src, slots: bool = False
+    ) -> _Entry:
+        kind = ("slots" if slots else "tabled") + ("" if src[0] == "mat" else "-tpl")
         n_shards = len(e.shards) if e.shards is not None else 1
         key = (
             kind, n_pad, self._src_msg_len(src), self._src_tpl_pad(src),
@@ -1149,7 +1282,6 @@ class VerifierModel:
                 for ent, pad in cold:
                     self._compile_tabled_async(ent, e, pad, src)
                 return None
-        s3 = self._table_stage_fns()[2]
         sg = np.asarray(sigs, dtype=np.uint8)
         idx = np.asarray(row_idx, dtype=np.int32)
         try:
@@ -1158,14 +1290,13 @@ class VerifierModel:
                 sl = slice(off, off + window)
                 idx_d = jnp.asarray(idx[sl])
                 sg_d = jnp.asarray(sg[sl])
-                sd, kd, s_ok = self._src_stage1(
-                    e, self._src_slice(src, sl), False, window, idx_d, sg_d
+                outs.append(
+                    self._gathered_launch(e, self._src_slice(src, sl), window, idx_d, sg_d)
                 )
-                px, py, pz, pt, a_ok = self._scan_rows(e, sd, kd, idx_d)
-                outs.append(s3(px, py, pz, pt, sg_d, a_ok, s_ok))
             win_ent.ready = True  # compile timing lives in the AOT layer
             parts = [np.asarray(o) for o in outs]
             self.row_counts.add(device=full_end)
+            self._tabled_counts.add(gathered_rows=full_end)
         except Exception as ex:
             # same None-means-fallback contract as the bucketed branch:
             # a transient device/compile failure mid-window degrades the
@@ -1192,7 +1323,8 @@ class VerifierModel:
 
     def register_valset(self, valset_key: bytes, all_pubkeys, msg_len: int = 160) -> None:
         """Pre-build the cached tables for a valset and warm its tabled
-        buckets — BOTH message flavors: the live commit path sends
+        shapes — a full commit in slot order and the gathered pair at
+        the set's bucket, in BOTH message flavors: the live commit path sends
         templated messages, while vote ingest and fallbacks still send
         materialized ones (node-start path: a restarting validator's
         FIRST commit should already ride the tabled pipeline, not wait
@@ -1222,10 +1354,16 @@ class VerifierModel:
         )
 
         def warm_bucket():
+            # what a full commit of the set takes (slot order, one
+            # commit a launch), and the gathered pair at the set's
+            # bucket for vote drains and lookups out of order
+            v = self._slot_table_rows(e)
+            shapes = ([(v, True)] if 0 < v <= MAX_DEVICE_ROWS else []) + [(n_pad, False)]
             for src in warm_srcs:
-                ent = self._tabled_bucket_entry(e, n_pad, src)
-                if not ent.ready:
-                    self._compile_tabled_async(ent, e, n_pad, src)
+                for rows, slots in shapes:
+                    ent = self._tabled_bucket_entry(e, rows, src, slots=slots)
+                    if not ent.ready:
+                        self._compile_tabled_async(ent, e, rows, src, slots=slots)
 
         if e.ready:
             warm_bucket()
@@ -1259,37 +1397,29 @@ class VerifierModel:
         )
 
     def _compile_tabled_async(
-        self, ent: _Entry, e: _TablesEntry, n_pad: int, src
+        self, ent: _Entry, e: _TablesEntry, n_pad: int, src, slots: bool = False
     ) -> None:
+        """Warm one tabled shape in the background, on zeros, to the
+        host: the gathered pair at n_pad rows, or (slots) the
+        slot-order pair at n_pad = C*V slots."""
         if not self._claim_compile(ent):
             return
         zsrc = self._src_zero(src, n_pad)
 
         def one_pass():
             t0 = time.perf_counter()
-            s3 = self._table_stage_fns()[2]
             sg = jnp.asarray(np.zeros((n_pad, 64), dtype=np.uint8))
-            idx = jnp.asarray(np.zeros(n_pad, dtype=np.int32))
-            sd, kd, s_ok = self._src_stage1(e, zsrc, False, n_pad, idx, sg)
-            px, py, pz, pt, a_ok = self._scan_rows(e, sd, kd, idx)
-            np.asarray(s3(px, py, pz, pt, sg, a_ok, s_ok))
-            if (
-                self.mesh is None
-                and e.shards is None
-                and n_pad <= int(e.tables.shape[0])
-            ):
-                # the dense (full-commit) variant must be warm too:
-                # the live path picks it per-call by index shape
-                sd, kd, s_ok = self._src_stage1(e, zsrc, True, n_pad, None, sg)
-                s2d = self._dense_stage_fns()[1]
-                px, py, pz, pt, a_ok = s2d(
-                    sd, kd, e.tables[:n_pad], e.a_ok[:n_pad]
-                )
-                np.asarray(s3(px, py, pz, pt, sg, a_ok, s_ok))
+            if slots:
+                ok = self._slot_launch(e, zsrc, n_pad, sg)
+            else:
+                idx = jnp.asarray(np.zeros(n_pad, dtype=np.int32))
+                ok = self._gathered_launch(e, zsrc, n_pad, idx, sg)
+            np.asarray(ok)
             ent.compile_s = time.perf_counter() - t0
             ent.ready = True
             self.logger.info(
                 "tabled bucket compiled", rows=n_pad, kind=src[0],
+                order="slots" if slots else "gathered",
                 msg_len=self._src_msg_len(src),
                 seconds=round(ent.compile_s, 2),
             )

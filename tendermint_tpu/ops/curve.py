@@ -427,14 +427,20 @@ def _tree_select(table: jnp.ndarray, mag: jnp.ndarray) -> jnp.ndarray:
     bits of mag-1: 7 lane-width `where`s over progressively halved
     tables — about half the VPU work of the one-hot masked sum it
     replaced (~420 vs ~960 ops/row at 60-limb entries). mag 0 selects
-    entry 0; callers mask the digit-0 identity afterward."""
+    entry 0; callers mask the digit-0 identity afterward.
+
+    table is (N, 8, W), one table a row, or (V, 8, W) with N = C*V rows
+    in slot order (row c*V + i reads table i): the first `where` then
+    broadcasts the V tables over the C axis, so the tables are read
+    where they lie and never repeated in memory."""
     assert _TBL & (_TBL - 1) == 0, "tree select needs a power-of-two table"
-    m = jnp.maximum(mag - 1, 0)  # (N,) in [0, _TBL-1]
-    t = table
+    n, v = mag.shape[0], table.shape[0]
+    m = jnp.maximum(mag - 1, 0).reshape(n // v, v)  # in [0, _TBL-1]
+    t = table[None]
     for bit in range(_TBL.bit_length() - 1):  # halve until 1 entry
-        b = ((m >> bit) & 1).astype(bool)[:, None, None]
-        t = jnp.where(b, t[:, 1::2], t[:, 0::2])
-    return t[:, 0]
+        b = ((m >> bit) & 1).astype(bool)[..., None, None]
+        t = jnp.where(b, t[..., 1::2, :], t[..., 0::2, :])
+    return t[..., 0, :].reshape(n, table.shape[-1])
 
 
 def _select_signed(table_flat: jnp.ndarray, digit: jnp.ndarray) -> CachedPoint:
@@ -554,7 +560,8 @@ def double_scalar_mul_tabled(
     """[s]B + [k]Q with per-key precomputed split tables: sd8 (N, 32)
     SIGNED base-256 digits of s (signed_digits_base256), kd (N, 64)
     signed nibble digits of k, key_tables (N, SPLITS, 8, 3*LIMBS) from
-    build_split_tables (gathered per row).
+    build_split_tables, gathered per row — or the set's (V, ...) tables
+    themselves for N = C*V rows in slot order (_tree_select).
 
     The key side runs SPLIT_W scan iterations x (4 doublings + SPLITS
     mixed adds) — 4*SPLIT_W (=16) doublings total vs 256 for the

@@ -193,9 +193,14 @@ def materialize_sign_bytes(templates, tmpl_idx, ts8):
 
 
 def verify_stage_scan_tabled(sd, kd, tables, a_ok, idx):
-    """Tabled stage 2: gather each row's key table by validator index
-    (device gather along the leading axis — large contiguous rows, DMA
-    friendly) and run the 4*SPLIT_W-doubling split scan."""
+    """Tabled stage 2, GATHERED: gather each row's key table by
+    validator index and run the 4*SPLIT_W-doubling split scan. For
+    batches whose rows are few or out of validator order (vote drains,
+    trusting lookups), and under a mesh. The gather and its re-layouts
+    copy ~30 KB a row three times over: 39.4 ms a 10,240-row launch on
+    a v5e where the slot-order form below takes 22.75 ms for the same
+    arithmetic (PERF.md section 6, PR 30) — whole commits go there
+    (models/verifier.plan_slots)."""
     row_tables = jnp.take(tables, idx, axis=0)
     p = curve.double_scalar_mul_tabled(sd, kd, row_tables)
     return p.x, p.y, p.z, p.t, jnp.take(a_ok, idx, axis=0)
@@ -222,15 +227,29 @@ def verify_stage_scan_tabled_sharded(sd, kd, a_ok, idx, tables):
     return p.x, p.y, p.z, p.t, jnp.take(a_ok, idx, axis=0)
 
 
-def verify_stage_scan_tabled_dense(sd, kd, tables, a_ok):
-    """Tabled stage 2, DENSE case: row i IS validator i (a full commit
-    in validator order — the hot shape), so the per-row table gather
-    disappears entirely. TPU gathers serialize on the scatter/gather
-    unit; skipping it was worth ~10ms of the 35ms stage-2 time at 10k
-    rows when measured (12KB/row tables at SPLITS=8 then; ~30KB now —
-    see BENCHMARKS.md round 4)."""
+def verify_stage_prepare_tabled_slots(pk_all, msgs, sigs):
+    """Tabled stage 1 in SLOT ORDER: the rows are C whole commits of
+    V slots each (C static, read from the shapes), slot c*V + i holding
+    validator i's row of commit c or zeros, so the pubkeys are the
+    set's device-resident (V, 32) matrix as it lies, repeated over the
+    commit axis: no index, no gather. C = 1 is the full-commit shape."""
+    c = sigs.shape[0] // pk_all.shape[0]
+    return verify_stage_prepare_tabled(jnp.tile(pk_all, (c, 1)), msgs, sigs)
+
+
+def verify_stage_scan_tabled_slots(sd, kd, tables, a_ok):
+    """Tabled stage 2 in SLOT ORDER (see verify_stage_prepare_tabled_slots):
+    slot c*V + i reads validator i's key table where it lies in the
+    set's (V, SPLITS, 8, 3*LIMBS) tables. The gathered form above
+    copies ~30 KB of table to every row of every launch (315 MB at
+    10,240 rows); here each row's ~90 bytes went to its validator's
+    slot on the host instead, and curve._tree_select broadcasts the
+    table over the commit axis. Same digits, same additions, same
+    verdict bit per row; empty slots (zero signatures) compute a
+    verdict nobody reads."""
+    c = kd.shape[0] // tables.shape[0]
     p = curve.double_scalar_mul_tabled(sd, kd, tables)
-    return p.x, p.y, p.z, p.t, a_ok
+    return p.x, p.y, p.z, p.t, jnp.tile(a_ok, c)
 
 
 def verify_stage_finish_blocked(px, py, pz, pt, sigs, a_ok, s_ok):

@@ -172,7 +172,7 @@ def _signed_commit(privs, chain_id="seam-chain", absent=()):
 
 
 def test_pipeline_engine_stats_seam_counters():
-    """The verify seam's counts (crypto/batch.SeamCounts) under
+    """The verify seam's counts (crypto/batch.SEAM_COUNTS) under
     engine_stats()["counters"]: an all-ed25519 commit is packed from
     columns with 0 fix-up rows; a set with a secp256k1 key shows that row
     on the other side."""
@@ -200,6 +200,10 @@ def test_pipeline_engine_stats_seam_counters():
         vals.verify_commit("seam-chain", bid, 5, commit, provider=pv)
         assert delta(start) == (4, 4, 1)
         assert pv.stats()["seam_fixup_rows"] == pv.engine_stats()["counters"]["seam_fixup_rows"]
+        # the cached-table path's counts are published beside them
+        # (crypto/batch.TABLED_COUNTS; a CPU provider moves none)
+        for k in ("tabled_slot_rows", "tabled_slot_pad", "tabled_gathered_rows"):
+            assert pv.stats()[k] == pv.engine_stats()["counters"][k] >= 0
 
 
 def _row_case_warm_blocking(v, batch):

@@ -49,9 +49,17 @@ def test_register_valset_prewarms_tabled_path():
         e = m2._valset_tables.get(b"boot-valset-2")
         if e is not None and e.ready:
             rows = int(e.tables.shape[0])
-            ent = m2._entries.get(("tabled", 16, 160, 0, rows, 1))
-            ent_t = m2._entries.get(("tabled-tpl", 16, 160, 2, rows, 1))
-            if ent is not None and ent.ready and ent_t is not None and ent_t.ready:
+            # a full commit's slot-order shape (one commit of `rows`
+            # slots) and the gathered pair at the set's bucket, both
+            # message flavors
+            ents = [
+                m2._entries.get(k)
+                for k in (
+                    ("slots", rows, 160, 0, rows, 1), ("slots-tpl", rows, 160, 2, rows, 1),
+                    ("tabled", 16, 160, 0, rows, 1), ("tabled-tpl", 16, 160, 2, rows, 1),
+                )
+            ]
+            if all(ent is not None and ent.ready for ent in ents):
                 warmed = True
                 break
         _time.sleep(0.25)
@@ -82,10 +90,24 @@ def _templated_rows(n, n_templates=3, seed=11):
     return pks, templates, tmpl_idx, ts8, msgs, sigs
 
 
+def _tabled_counts():
+    from tendermint_tpu.crypto.batch import TABLED_COUNTS
+
+    return TABLED_COUNTS.snapshot()
+
+
+def _grew(before, **want):
+    after = _tabled_counts()
+    got = {k: after[f"tabled_{k}"] - before[f"tabled_{k}"] for k in ("slot_rows", "slot_pad", "gathered_rows")}
+    assert got == {"slot_rows": 0, "slot_pad": 0, "gathered_rows": 0, **want}, got
+
+
 def test_templated_rows_cached_matches_materialized():
     """verify_rows_cached_templated must accept/reject bit-identically
-    to verify_rows_cached on the materialized messages — dense shape,
-    gathered subset (with duplicates), and corrupted rows."""
+    to verify_rows_cached on the materialized messages — the full-set
+    shape (slot order without holes), a gathered subset with duplicate
+    and descending indices (what a trusting lookup by address in an
+    older set may hand over), and corrupted rows."""
     from tendermint_tpu.models.verifier import VerifierModel
 
     n = 16  # the 16-row bucket the other tests of this file compile
@@ -98,11 +120,13 @@ def test_templated_rows_cached_matches_materialized():
     m = VerifierModel(block_on_compile=True)
     key = b"tpl-parity"
     idx = np.arange(n, dtype=np.int32)
+    before = _tabled_counts()
     ok_mat = m.verify_rows_cached(key, pks, idx, msgs, sigs)
     ok_tpl = m.verify_rows_cached_templated(
         key, pks, idx, templates, tmpl_idx, ts8, sigs
     )
     assert ok_mat is not None and ok_tpl is not None
+    _grew(before, slot_rows=2 * n)  # every validator signed: no empty slot
     np.testing.assert_array_equal(ok_mat, ok_tpl)
     assert not ok_tpl[5] and ok_tpl.sum() == n - 1
 
@@ -113,11 +137,107 @@ def test_templated_rows_cached_matches_materialized():
 
     # gathered shape with duplicate validator indices
     sub = np.array([3, 3, 11, 0, 7, 15], dtype=np.int32)
+    before = _tabled_counts()
     ok_sub = m.verify_rows_cached_templated(
         key, pks, sub, templates, tmpl_idx[sub], ts8[sub], sigs[sub]
     )
     assert ok_sub is not None
     np.testing.assert_array_equal(ok_sub, np.ones(len(sub), dtype=bool))
+    _grew(before, gathered_rows=len(sub))  # three runs: 64 slots for 6 rows
+
+
+def _templated_commits(n_vals, n_commits, seed):
+    """n_commits commits of ONE validator set, every validator signing
+    each: (pks, templates, [(tmpl_idx, ts8, msgs, sigs) per commit])."""
+    rng = np.random.default_rng(seed)
+    seeds = [rng.bytes(32) for _ in range(n_vals)]
+    pks = np.frombuffer(
+        b"".join(ref.pubkey_from_seed(s) for s in seeds), dtype=np.uint8
+    ).reshape(n_vals, 32)
+    templates = rng.integers(0, 256, size=(2 * n_commits, 160)).astype(np.uint8)
+    commits = []
+    for k in range(n_commits):
+        # a (commit, nil) template pair per height, as the seam sends
+        tmpl_idx = (2 * k + (rng.random(n_vals) < 0.2)).astype(np.int32)
+        ts8 = rng.integers(0, 256, size=(n_vals, 8)).astype(np.uint8)
+        msgs = templates[tmpl_idx].copy()
+        msgs[:, 93:101] = ts8
+        sigs = np.frombuffer(
+            b"".join(ref.sign(s, m.tobytes()) for s, m in zip(seeds, msgs)),
+            dtype=np.uint8,
+        ).reshape(n_vals, 64).copy()
+        commits.append((tmpl_idx, ts8, msgs, sigs))
+    return pks, templates, commits
+
+
+def _present_rows(commits, absent, forged):
+    """The rows a seam would hand over: each commit's present
+    validators in validator order, `forged` (commit, validator) pairs
+    with one signature bit flipped."""
+    cols = [[] for _ in range(6)]
+    for k, (tmpl_idx, ts8, msgs, sigs) in enumerate(commits):
+        sigs, bad = sigs.copy(), np.zeros(len(sigs), dtype=bool)
+        for val in (val for c, val in forged if c == k):
+            sigs[val, 9] ^= 0x10
+            bad[val] = True
+        present = np.setdiff1d(np.arange(len(sigs)), absent[k]).astype(np.int32)
+        cols[0].append(present)
+        for col, a in zip(cols[1:], (tmpl_idx, ts8, msgs, sigs, bad)):
+            col.append(a[present])
+    return tuple(np.concatenate(col) for col in cols)
+
+
+@pytest.mark.parametrize(
+    "name,n_commits,absent,forged,slots",
+    [
+        # absent validators at both ends and inside; forged signatures
+        # next to each hole and at the first and last present slot
+        ("one commit with holes", 1, [[0, 6, 15]], [(0, 1), (0, 5), (0, 7), (0, 14)], 16),
+        # three commits -> one launch of C = 4: a hole at slot 0, holes
+        # at V-1 and inside, then a commit without holes whose last row
+        # is forged — the last real commit of the padded launch
+        (
+            "three commits, C padded to 4", 3, [[0], [7, 15], []],
+            [(0, 1), (1, 14), (2, 0), (2, 15)], 64,
+        ),
+    ],
+)
+def test_slot_order_equals_gathered_equals_host(monkeypatch, name, n_commits, absent, forged, slots):
+    """Slot order against the gathered pair against the host reference:
+    bit-equal verdicts for every row, both message sources, and the
+    three counters say which table operand each call took."""
+    from tendermint_tpu.models import verifier as vmod
+
+    v = 16  # the padded set: tables.shape[0]
+    pks, templates, commits = _templated_commits(v, n_commits, seed=41)
+    idx, ti, t8, mg, sg, bad = _present_rows(commits, absent, forged)
+    n = len(idx)
+    want = np.array(
+        [ref.verify(pks[i].tobytes(), m.tobytes(), s.tobytes()) for i, m, s in zip(idx, mg, sg)]
+    )
+    np.testing.assert_array_equal(want, ~bad)  # the host reference rejects the forged rows, only them
+    assert bad.sum() == len(forged)
+
+    m = vmod.VerifierModel(block_on_compile=True)
+    key = b"slot-order-" + name.encode()
+    plan = vmod.plan_slots(idx, v)
+    assert plan is not None and sum(c for _, _, c in plan.launches) * v == slots
+
+    before = _tabled_counts()
+    ok_tpl = m.verify_rows_cached_templated(key, pks, idx, templates, ti, t8, sg)
+    ok_mat = m.verify_rows_cached(key, pks, idx, mg, sg)
+    _grew(before, slot_rows=2 * n, slot_pad=2 * (slots - n))
+    assert any(k[0] == "slots-tpl" and k[1] == slots for k in m._entries)
+
+    monkeypatch.setattr(vmod, "_SLOT_GATHER_RATIO", 0.0)  # the same rows, gathered
+    before = _tabled_counts()
+    ok_gathered = m.verify_rows_cached_templated(key, pks, idx, templates, ti, t8, sg)
+    _grew(before, gathered_rows=n)
+
+    for got in (ok_tpl, ok_mat, ok_gathered):
+        assert got is not None and got.shape == (n,)
+        np.testing.assert_array_equal(got, want)
+    assert m.row_counts.snapshot() == (3 * n, 0)  # empty slots are not device rows
 
 
 def test_templated_windowed_boundary_controls(monkeypatch):

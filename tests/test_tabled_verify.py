@@ -212,13 +212,25 @@ def test_small_gathered_batch_against_huge_table_falls_back(monkeypatch):
     pks, msgs, sigs = sign_rows(80, seed=53)
     pk, mg, sg = arrs(pks, msgs, sigs)
     m = vmod.VerifierModel(block_on_compile=True)
-    # full-set call (dense) builds the 80-row (pad 256) tables
+    from tendermint_tpu.crypto.batch import TABLED_COUNTS
+
+    c0 = TABLED_COUNTS.snapshot()
+    # full-set call (slot order: 80 rows in 256 slots against the 256
+    # bucket) builds the 80-row (pad 256) tables
     ok = m.verify_rows_cached(b"gather-valset", pk, np.arange(80, dtype=np.int32), mg, sg)
     assert ok is not None and ok.all()
+    c1 = TABLED_COUNTS.snapshot()
+    assert (c1["tabled_slot_rows"] - c0["tabled_slot_rows"], c1["tabled_slot_pad"] - c0["tabled_slot_pad"]) == (80, 176)
     sub = np.array([5, 2, 9], dtype=np.int32)
-    # below the policy floor: the gathered path still engages
-    out = m.verify_rows_cached(b"gather-valset", pk, sub, mg[sub], sg[sub])
-    assert out is not None and out.all()
+    # below the policy floor: the gathered path still engages — for a
+    # sparse vote batch out of order (three runs) and in order (one
+    # run: 256 slots for a 16-row bucket, beyond _SLOT_GATHER_RATIO)
+    for rows in (sub, np.sort(sub)):
+        out = m.verify_rows_cached(b"gather-valset", pk, rows, mg[rows], sg[rows])
+        assert out is not None and out.all()
+    c2 = TABLED_COUNTS.snapshot()
+    assert c2["tabled_gathered_rows"] - c1["tabled_gathered_rows"] == 6
+    assert c2["tabled_slot_rows"] == c1["tabled_slot_rows"]
     # floor lowered: 256 > 4*16 and 256 > floor -> generic fallback
     monkeypatch.setattr(vmod, "_GATHER_POLICY_MIN_TABLE", 64)
     out = m.verify_rows_cached(b"gather-valset", pk, sub, mg[sub], sg[sub])
