@@ -111,6 +111,15 @@ def make_batch(n, msg_len=MSG_LEN, seed=1234):
     return pks, msgs, sigs
 
 
+def _verify_then_tally(model, pks, msgs, sigs, powers, counted):
+    """model.verify, then the host's column sum over its verdicts (what
+    BatchVerifier.verify_commit_batch does over a provider)."""
+    import numpy as np
+
+    ok = model.verify(pks, msgs, sigs)
+    return ok, int(np.sum(np.where(ok & counted, powers, 0)))
+
+
 def stream_windows(fn, dev_args, n_calls: int) -> float:
     """Launch n_calls invocations of the warm jitted `fn` on
     device-resident args, sync on the LAST output only; returns elapsed
@@ -383,7 +392,7 @@ def run_bench(platform: str):
     # -- device: compile/warm (persistent cache makes re-runs cheap) ------
     cache_before = len(os.listdir(CACHE_DIR)) if os.path.isdir(CACHE_DIR) else 0
     t0 = time.perf_counter()
-    ok, tally = model.verify_commit(pks, msgs, sigs, powers, counted)
+    ok, tally = _verify_then_tally(model, pks, msgs, sigs, powers, counted)
     cold_s = time.perf_counter() - t0
     assert ok.all() and tally == n * 10, (int(ok.sum()), tally)
     cache_after = len(os.listdir(CACHE_DIR)) if os.path.isdir(CACHE_DIR) else 0
@@ -395,7 +404,7 @@ def run_bench(platform: str):
     # -- measure p50 over repeated runs (adaptive count: an asked-for
     # CPU run takes tens of seconds per call, not ms) --------------------
     t0 = time.perf_counter()
-    ok, tally = model.verify_commit(pks, msgs, sigs, powers, counted)
+    ok, tally = _verify_then_tally(model, pks, msgs, sigs, powers, counted)
     first_warm = time.perf_counter() - t0
     _partial.update(
         value_ms=round(first_warm * 1e3, 3),
@@ -407,7 +416,7 @@ def run_bench(platform: str):
     times = [first_warm]
     for _ in range(iters):
         t0 = time.perf_counter()
-        ok, tally = model.verify_commit(pks, msgs, sigs, powers, counted)
+        ok, tally = _verify_then_tally(model, pks, msgs, sigs, powers, counted)
         times.append(time.perf_counter() - t0)
     p50 = sorted(times)[len(times) // 2]
     thr = n / p50
@@ -417,7 +426,7 @@ def run_bench(platform: str):
     # negative control on the warm path
     sigs_bad = sigs.copy()
     sigs_bad[7, 3] ^= 1
-    ok_bad, _ = model.verify_commit(pks, msgs, sigs_bad, powers, counted)
+    ok_bad, _ = _verify_then_tally(model, pks, msgs, sigs_bad, powers, counted)
     assert not ok_bad[7] and ok_bad.sum() == n - 1
 
     # -- per-valset cached-table path (round 3) ---------------------------
@@ -553,19 +562,12 @@ def run_bench(platform: str):
         import jax as _jax
         import jax.numpy as jnp
 
-        from tendermint_tpu.ops import ed25519 as ops_ed
-
-        fn = model._get_fn("tally", 10240, MSG_LEN)
+        fn = model._get_fn(10240, MSG_LEN)
         if fn is not None and n <= 10240:
             pad = lambda a: model._pad(np.asarray(a), 10240)
             dev = [
-                _jax.device_put(jnp.asarray(x))
-                for x in (
-                    pad(pks.astype(np.uint8)), pad(msgs.astype(np.uint8)),
-                    pad(sigs.astype(np.uint8)),
-                    pad(ops_ed.split_powers(powers)),
-                    pad(counted.astype(bool)),
-                )
+                _jax.device_put(jnp.asarray(pad(x.astype(np.uint8))))
+                for x in (pks, msgs, sigs)
             ]
             K = 64  # the generic chain is ~70 ms/commit: less depth needed
             pipelined_ms = stream_windows(fn, dev, K) / K
@@ -876,13 +878,12 @@ def mesh_bench(device: bool = True) -> dict:
                 mesh=make_mesh(devs[:d]) if d > 1 else None,
                 block_on_compile=True,
             )
-            ok, tally = model.verify_commit(
-                pks, msgs, sigs, powers, counted
-            )  # compile + warm
+            # compile + warm
+            ok, tally = _verify_then_tally(model, pks, msgs, sigs, powers, counted)
             times = []
             for _ in range(5):
                 t0 = time.perf_counter()
-                ok, tally = model.verify_commit(pks, msgs, sigs, powers, counted)
+                ok, tally = _verify_then_tally(model, pks, msgs, sigs, powers, counted)
                 times.append(time.perf_counter() - t0)
             p50 = sorted(times)[len(times) // 2]
             ok = np.asarray(ok)
@@ -2437,7 +2438,7 @@ def _coldstart() -> None:
 
     t0 = time.perf_counter()
     model = VerifierModel()
-    ok, tally = model.verify_commit(pks, msgs, sigs, powers, counted)
+    ok, tally = _verify_then_tally(model, pks, msgs, sigs, powers, counted)
     first_s = time.perf_counter() - t0
     assert ok.all() and tally == n * 10
 

@@ -160,7 +160,7 @@ def bench_sig_scaling():
             import jax
             import jax.numpy as jnp
 
-            fn = prov.model._get_fn("verify", 10240, 160)
+            fn = prov.model._get_fn(10240, 160)
             assert fn is not None  # block_on_compile=True provider
             dev = [
                 jax.device_put(jnp.asarray(x))

@@ -12,8 +12,9 @@ TPU-first:
   voting-power quorum tally (reference: types/vote_set.go:142,
   types/validator_set.go:629, lite2/verifier.go) -- runs on TPU as batched
   JAX programs: vmap'd limb-arithmetic ed25519 in ``tendermint_tpu.ops``
-  with a fused segment-sum tally, sharded over a ``jax.sharding.Mesh`` for
-  multi-chip scale in ``tendermint_tpu.parallel``.
+  returning a verdict per signature (the tally is a host column sum over
+  them), sharded over a ``jax.sharding.Mesh`` for multi-chip scale in
+  ``tendermint_tpu.parallel``.
 
 Layer map (mirrors SURVEY.md section 1):
 

@@ -63,8 +63,9 @@ class BaseConfig:
     crypto_pipeline_flush_ms: int = 0
     # Shard the verify batch over a device mesh when this many JAX
     # devices are available (0/1 = single device). The sharded program
-    # is shard_map'd per stage with the quorum tally psum'd over ICI
-    # (models/verifier.py); on hosts with fewer devices the node falls
+    # is shard_map'd per stage, rows sharded and verdicts returned to
+    # the host, which tallies the quorum (models/verifier.py, no
+    # collective); on hosts with fewer devices the node falls
     # back to single-device and logs it.
     crypto_mesh_devices: int = 0
     # The seam-level mesh runtime (parallel/topology.py): discover the

@@ -5,7 +5,8 @@ signature serially (crypto/ed25519/ed25519.go:151, looped at
 types/validator_set.go:641 and types/vote_set.go:201). Per the BASELINE
 north star, this seam is where VoteSet.add_vote, ValidatorSet
 .verify_commit and the light client drain (pubkey, msg, sig) triples into
-one batched device call, with the quorum tally fused on device.
+batched device calls; the quorum tally is a column sum on the host over
+the verdicts that come back.
 
 Providers:
 - "cpu": serial loop over host ed25519 (OpenSSL) -- the reference-parity
@@ -124,12 +125,13 @@ class BatchVerifier:
         powers: np.ndarray,
         counted: np.ndarray,
     ) -> Tuple[np.ndarray, int]:
-        """Fused verify + voting-power tally.
+        """Verify, then tally voting power over the verdicts on the host.
 
         `powers` (N,) int64 voting power per signer; `counted` (N,) bool --
         whether this row's power counts toward the tally (e.g. votes for
         the right BlockID). Returns (ok (N,) bool, talled power int where
-        ok & counted). Default composition; device providers fuse it.
+        ok & counted). The one definition: every provider, wrapped or
+        not, inherits it over its own verify_batch.
         """
         ok = self.verify_batch(pubkeys, msgs, sigs)
         talled = int(np.sum(np.where(ok & counted.astype(bool), powers, 0)))
@@ -203,7 +205,7 @@ class CPUBatchVerifier(BatchVerifier):
 
 
 class TPUBatchVerifier(BatchVerifier):
-    """Batched JAX ed25519 + fused tally on the accelerator.
+    """Batched JAX ed25519 on the accelerator.
 
     ``block_on_compile=False`` (the live-node setting) keeps consensus
     latency-safe: a cold batch bucket is verified on host while a
@@ -304,17 +306,6 @@ class TPUBatchVerifier(BatchVerifier):
             return out
         return self._model.verify(pubkeys, msgs, sigs, msg_lens=msg_lens)
 
-    def verify_commit_batch(self, pubkeys, msgs, sigs, powers, counted):
-        if len(pubkeys) < self.min_device_batch:
-            return self._cpu.verify_commit_batch(pubkeys, msgs, sigs, powers, counted)
-        ran, out = self._meshed(
-            len(pubkeys),
-            lambda m: m.verify_commit(pubkeys, msgs, sigs, powers, counted),
-        )
-        if ran:
-            return out
-        return self._model.verify_commit(pubkeys, msgs, sigs, powers, counted)
-
     def verify_rows_cached(self, valset_key, all_pubkeys, row_idx, msgs, sigs):
         if len(row_idx) < self.min_device_batch:
             return None
@@ -364,9 +355,9 @@ class MeshRoutedVerifier(BatchVerifier):
     chunk — the same MeshRouter admission/breaker semantics with no
     jax dependency, which is exactly what the simulator's determinism
     rig and the degraded-topology tests need (logical host lanes).
-    Verdict order is preserved by concatenation and the quorum tally
-    is an exact integer sum, so results are bit-identical to the
-    unrouted inner verifier by construction."""
+    Verdict order is preserved by concatenation (the inherited quorum
+    tally sums over the concatenated verdicts), so results are
+    bit-identical to the unrouted inner verifier by construction."""
 
     def __init__(self, inner: BatchVerifier, router):
         self.inner = inner
@@ -407,30 +398,6 @@ class MeshRoutedVerifier(BatchVerifier):
             )
         except Exception:
             return self.inner.verify_batch(pubkeys, msgs, sigs, msg_lens=msg_lens)
-
-    def verify_commit_batch(self, pubkeys, msgs, sigs, powers, counted):
-        plan = self.router.plan(len(pubkeys))
-        if not plan.collective:
-            return self.inner.verify_commit_batch(pubkeys, msgs, sigs, powers, counted)
-
-        def _combine(outs):
-            ok = np.concatenate([o[0] for o in outs])
-            return ok, int(sum(o[1] for o in outs))
-
-        try:
-            return self.router.run(
-                plan,
-                lambda s: self.inner.verify_commit_batch(
-                    pubkeys[s.lo : s.hi],
-                    msgs[s.lo : s.hi],
-                    sigs[s.lo : s.hi],
-                    powers[s.lo : s.hi],
-                    counted[s.lo : s.hi],
-                ),
-                _combine,
-            )
-        except Exception:
-            return self.inner.verify_commit_batch(pubkeys, msgs, sigs, powers, counted)
 
     def verify_rows_cached(self, valset_key, all_pubkeys, row_idx, msgs, sigs):
         plan = self.router.plan(len(row_idx))

@@ -578,9 +578,9 @@ class PipelinedVerifier(BatchVerifier):
             serial,
         )
 
-    # verify_commit_batch: inherited — composes over verify_batch (the
-    # host tally is microseconds; routing the rows through the shared
-    # queue matters more than the fused device tally here)
+    # verify_commit_batch: inherited — composes over verify_batch, so
+    # the rows go through the shared queue (the host tally is
+    # microseconds)
 
     # -- inner passthroughs -------------------------------------------------
 

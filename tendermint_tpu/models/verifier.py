@@ -35,9 +35,12 @@ Two compile disciplines:
 
 Multi-chip: the mesh path uses ``shard_map`` so the per-device program
 is exactly the single-device program (compile cost does not scale with
-mesh size, unlike whole-graph GSPMD partitioning); the fused tally is a
-``psum`` over the batch axis riding ICI; cached tables replicate across
-the mesh while rows shard.
+mesh size, unlike whole-graph GSPMD partitioning). Rows shard over the
+batch axis, cached tables replicate, verdicts come back; no stage runs
+a collective. A commit's voting-power tally is a column sum on the
+host over those verdicts (crypto/batch.BatchVerifier
+.verify_commit_batch, types/validator_set.py) on one device and on a
+mesh alike.
 """
 
 from __future__ import annotations
@@ -51,6 +54,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from tendermint_tpu.ops import ed25519 as ops_ed
 from tendermint_tpu.parallel import pad_to_multiple
@@ -156,19 +160,6 @@ class _Entry:
 # around a validator-set change).
 MAX_CACHED_VALSETS = 2
 
-# Largest validator slice per table-build dispatch: the build's affine
-# conversion holds (rows*SPLITS*8, 20, 20) int32 intermediates, so one
-# 65536-row dispatch wants ~30GB of HBM (observed OOM at 50k
-# validators) while 16384 rows stay ~3.4GB in flight — chosen so every
-# build at the DEFAULT MAX_TABLED_VALSET (16384) remains one-shot and
-# chunking only engages for env-raised caps.
-_TABLE_BUILD_CHUNK = 16384
-
-# The small-gathered-batch policy below only applies to tables beyond
-# this row count: the ~50x pathology was measured against a 65536-row
-# (~2GB) table; small and mid tables gather fine (round-3 ingest data).
-_GATHER_POLICY_MIN_TABLE = 16384
-
 # Slot order against gathered order (plan_slots): a batch goes to its
 # validators' slots when the slots it would launch are at most this
 # many times the padded rows the gathered pair would launch for it.
@@ -178,14 +169,19 @@ _GATHER_POLICY_MIN_TABLE = 16384
 # 1.51-1.59 slots; the lower edge, rounded down.
 _SLOT_GATHER_RATIO = 1.5
 
-# Largest valset served by ONE device table. The reference caps
-# commits at 10k votes (types/vote_set.go:18 MaxVotesCount); beyond
-# ~16k rows a single table's gathers go pathological (the 50k-ingest
-# eval measured the whole process slowing ~50x while a 65536-row table
-# was resident — round-4 ledger). Larger sets up to MAX_SHARDED_VALSET
-# ride SHARDED tables: equal <=16384-row shards with per-shard bounded
-# gathers in one program (ops_ed.verify_stage_scan_tabled_sharded).
-MAX_TABLED_VALSET = int(os.environ.get("TM_MAX_TABLED_VALSET", "16384"))
+# Largest valset served by ONE device table, and the most rows a table,
+# a shard or a table-build dispatch holds. The reference caps commits
+# at 10k votes (types/vote_set.go:18 MaxVotesCount); beyond ~16k rows a
+# single table's gathers go pathological (the 50k-ingest eval measured
+# the whole process slowing ~50x while a 65536-row table was resident —
+# round-4 ledger), and the build's affine conversion holds
+# (rows*SPLITS*8, 20, 20) int32 intermediates: one 65536-row dispatch
+# wants ~30GB of HBM (observed OOM at 50k validators) while 16384 rows
+# stay ~3.4GB in flight. Larger sets up to MAX_SHARDED_VALSET ride
+# SHARDED tables: equal shards of this many rows, each built by its own
+# dispatch and gathered bounded in one program
+# (ops_ed.verify_stage_scan_tabled_sharded).
+MAX_TABLED_VALSET = MAX_DEVICE_ROWS
 
 # Largest valset for the sharded-table path (HBM is the bound:
 # ~30KB/validator => ~2GB at 65536). The figure is SINGLE-device; on a
@@ -194,7 +190,7 @@ MAX_TABLED_VALSET = int(os.environ.get("TM_MAX_TABLED_VALSET", "16384"))
 # budget divides by N — VerifierModel.sharded_valset_cap() computes
 # the live cap from the mesh size (N=1 reproduces this constant
 # exactly). Beyond the cap the generic pipeline takes over.
-MAX_SHARDED_VALSET = int(os.environ.get("TM_MAX_SHARDED_VALSET", str(1 << 16)))
+MAX_SHARDED_VALSET = 1 << 16
 
 
 class SlotPlan(NamedTuple):
@@ -278,6 +274,39 @@ class _TablesEntry:
         self.source: Optional[str] = None  # "build" | "disk"
 
 
+# Every device program of the model: AOT tag -> (function, in_specs,
+# out_specs). One device jits the function as it is; a mesh shard_maps
+# it with the specs (VerifierModel._program), so the per-device program
+# is the single-device one. Rows (_B) shard over the batch axis; the
+# valset's tables, a_ok and pubkey matrix and the KB-scale templates
+# replicate (_R): each device gathers its rows from a full local copy,
+# ~30KB/validator/device, no cross-device gather. No specs = a plain jit
+# on a mesh too: the table build gives every device the full table (a
+# sharded build would save build time but force a cross-device gather
+# per verify), slot order runs on one device only (plan_slots), and
+# the sharded scan takes its shards as a tuple.
+_B, _R = PartitionSpec(BATCH_AXIS), PartitionSpec()
+_PROGRAMS = {
+    "prepare": (ops_ed.verify_stage_prepare, (_B,) * 3, (_B,) * 8),
+    "scan": (ops_ed.verify_stage_scan, (_B,) * 6, (_B,) * 4),
+    "finish": (ops_ed.verify_stage_finish, (_B,) * 7, _B),
+    "t-prepare-g": (
+        ops_ed.verify_stage_prepare_tabled_gathered, (_R, _B, _B, _B), (_B,) * 3,
+    ),
+    "t-scan": (ops_ed.verify_stage_scan_tabled, (_B, _B, _R, _R, _B), (_B,) * 5),
+    "t-finish": (ops_ed.verify_stage_finish_blocked, (_B,) * 7, _B),
+    "t-build": (ops_ed.build_valset_tables, None, None),
+    "t-materialize": (ops_ed.materialize_sign_bytes, (_R, _B, _B), _B),
+    "t-prepare-s": (ops_ed.verify_stage_prepare_tabled_slots, None, None),
+    "t-scan-s": (ops_ed.verify_stage_scan_tabled_slots, None, None),
+    "t-scan-sh": (ops_ed.verify_stage_scan_tabled_sharded, None, None),
+}
+# Skip executable persistence on XLA:CPU (aot_cache.AotJit): the
+# materializer is the crash class that motivated splitting it from
+# prepare, and is trivial to recompile.
+_FRAGILE = frozenset({"t-materialize"})
+
+
 class VerifierModel:
     def __init__(
         self, mesh=None, block_on_compile: bool = True, logger=None,
@@ -297,7 +326,8 @@ class VerifierModel:
         self._cpu = CPUBatchVerifier(row_counts=self.row_counts)
         self._tabled_counts = TABLED_COUNTS
         self._lock = threading.Lock()
-        self._entries: Dict[Tuple[str, int, int], _Entry] = {}
+        self._entries: Dict[tuple, _Entry] = {}  # see compile_stats
+        self._programs: Dict[str, object] = {}  # tag -> AotJit (_program)
         self._valset_tables: Dict[bytes, _TablesEntry] = {}  # insertion-ordered LRU
         # Table-build failure used to latch `e.failed` FOREVER: one
         # transient device hiccup (OOM during a vote storm, a wedged
@@ -309,149 +339,73 @@ class VerifierModel:
 
     # -- compiled function cache ------------------------------------------
 
-    def _shard_specs(self):
-        from jax.sharding import PartitionSpec as P
+    def _program(self, tag: str):
+        """The model's one AotJit for `tag` (_PROGRAMS), made on first
+        use: the plain jit on one device; on a mesh the same function
+        shard_mapped with the tag's specs (plain where it has none),
+        its AOT tag suffixed with the mesh shape."""
+        prog = self._programs.get(tag)  # every launch looks its stages up here
+        if prog is not None:
+            return prog
+        with self._lock:
+            prog = self._programs.get(tag)
+            if prog is None:
+                from tendermint_tpu.models.aot_cache import AotJit
 
-        return P(BATCH_AXIS), P()
+                fn, in_specs, out_specs = _PROGRAMS[tag]
+                fragile = tag in _FRAGILE
+                if self.mesh is None:
+                    prog = AotJit(fn, tag, fragile=fragile)
+                else:
+                    if out_specs is not None:
+                        fn = jax.shard_map(
+                            fn, mesh=self.mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False,
+                        )
+                    prog = AotJit(
+                        None, f"{tag}-mesh{tuple(self.mesh.shape.values())}",
+                        jit_fn=jax.jit(fn), fragile=fragile,
+                    )
+                self._programs[tag] = prog
+            return prog
 
     def _stages(self):
-        """Shared stage-1/2 jit wrappers, built once per model.
+        """Generic stages 1 and 2. They depend only on input shapes, so
+        one wrapper serves every bucket (jit re-specializes per shape
+        internally): the dominant scan is traced and compiled once per
+        n_pad, not once per msg_len."""
+        return self._program("prepare"), self._program("scan")
 
-        prepare and scan depend only on input shapes, not on `kind` or
-        msg_len-vs-tally flavor, so one jit wrapper serves every bucket
-        (jit re-specializes per shape internally) — the dominant scan
-        stage is traced/compiled once per n_pad, not once per
-        (kind, msg_len) combination."""
-        cached = getattr(self, "_stage_fns", None)
-        if cached is not None:
-            return cached
-        from tendermint_tpu.models.aot_cache import AotJit
-
-        if self.mesh is None:
-            s1 = AotJit(ops_ed.verify_stage_prepare, "prepare")
-            s2 = AotJit(ops_ed.verify_stage_scan, "scan")
-        else:
-            batch, _ = self._shard_specs()
-            tag = f"mesh{tuple(self.mesh.shape.values())}"
-            s1 = AotJit(
-                None, f"prepare-{tag}",
-                jit_fn=self._smap(ops_ed.verify_stage_prepare, 3, (batch,) * 8),
-            )
-            s2 = AotJit(
-                None, f"scan-{tag}",
-                jit_fn=self._smap(ops_ed.verify_stage_scan, 6, (batch,) * 4),
-            )
-        self._stage_fns = (s1, s2)
-        return self._stage_fns
-
-    def _smap(self, f, n_in, out_specs, in_specs=None):
-        batch, _ = self._shard_specs()
-        in_specs = (batch,) * n_in if in_specs is None else in_specs
-        return jax.jit(
-            jax.shard_map(
-                f, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=False,
-            )
-        )
-
-    def _build(self, kind: str):
-        """Build the (lazily compiled) callable for `kind`.
-
-        The verify program is jitted as THREE chained stages (prepare /
-        scan / finish) rather than one graph: XLA compile time is
+    def _build(self):
+        """The generic verify callable: THREE chained stages (prepare /
+        scan / finish) rather than one graph. XLA compile time is
         superlinear in program size — the fused graph compiles in ~220s
         on a v5e, the stages in ~33s total. Intermediates stay
         device-resident between stages, so warm latency is unchanged
-        (two extra ~0.1ms dispatches).
-
-        Mesh path: shard_map keeps the per-device program identical to
-        the single-device one — compile time is O(1) in mesh size and
-        XLA inserts exactly one psum (over ICI) for the tally. Stages
-        are shard_mapped independently; every intermediate is sharded
-        over the batch axis so no collective moves between stages."""
-        from tendermint_tpu.models.aot_cache import AotJit
-
+        (two extra ~0.1ms dispatches). On a mesh the stages are
+        shard_mapped independently and every intermediate is sharded
+        over the batch axis, so nothing moves between devices."""
         s1, s2 = self._stages()
-        if self.mesh is None:
-            if kind == "verify":
-                s3 = AotJit(ops_ed.verify_stage_finish, "finish")
+        s3 = self._program("finish")
 
-                def fn(pk, mg, sg):
-                    pre = s1(pk, mg, sg)
-                    coords = s2(*pre[:6])
-                    return s3(*coords, sg, pre[6], pre[7])
-
-                return fn
-
-            s3t = AotJit(ops_ed.verify_stage_finish_tally, "finish-tally")
-
-            def fn(pk, mg, sg, chunks, counted):
-                pre = s1(pk, mg, sg)
-                coords = s2(*pre[:6])
-                return s3t(*coords, sg, pre[6], pre[7], chunks, counted)
-
-            return fn
-
-        batch, rep = self._shard_specs()
-        tag = f"mesh{tuple(self.mesh.shape.values())}"
-        if kind == "verify":
-            s3 = AotJit(
-                None, f"finish-{tag}",
-                jit_fn=self._smap(ops_ed.verify_stage_finish, 7, batch),
-            )
-
-            def fn(pk, mg, sg):
-                pre = s1(pk, mg, sg)
-                coords = s2(*pre[:6])
-                return s3(*coords, sg, pre[6], pre[7])
-
-            return fn
-
-        def finish_tally_psum(px, py, pz, pt, sg, a_ok, s_ok, chunks, counted):
-            ok, local = ops_ed.verify_stage_finish_tally(
-                px, py, pz, pt, sg, a_ok, s_ok, chunks, counted
-            )
-            return ok, jax.lax.psum(local, BATCH_AXIS)
-
-        s3t = AotJit(
-            None, f"finish-tally-{tag}",
-            jit_fn=self._smap(finish_tally_psum, 9, (batch, rep)),
-        )
-
-        def fn(pk, mg, sg, chunks, counted):
+        def fn(pk, mg, sg):
             pre = s1(pk, mg, sg)
             coords = s2(*pre[:6])
-            return s3t(*coords, sg, pre[6], pre[7], chunks, counted)
+            return s3(*coords, sg, pre[6], pre[7])
 
         return fn
 
-    def _entry(self, kind: str, n_pad: int, msg_len: int) -> _Entry:
-        key = (kind, n_pad, msg_len)
+    def _entry(self, n_pad: int, msg_len: int) -> _Entry:
+        key = ("verify", n_pad, msg_len)
         with self._lock:
             e = self._entries.get(key)
-            if e is None:
-                e = _Entry(self._build(kind))
-                self._entries[key] = e
-            return e
+        if e is None:
+            fn = self._build()  # takes the lock itself
+            with self._lock:
+                e = self._entries.setdefault(key, _Entry(fn))
+        return e
 
-    def _zero_args(self, kind: str, n_pad: int, msg_len: int):
-        # Build from HOST arrays exactly like the live call sites do:
-        # jit specializes on input layout provenance, so warming with
-        # device-native jnp.zeros compiles an executable the live
-        # host-transferred inputs then miss (observed: a second ~11s
-        # compile on the first real call after warmup).
-        pk = jnp.asarray(np.zeros((n_pad, 32), dtype=np.uint8))
-        mg = jnp.asarray(np.zeros((n_pad, msg_len), dtype=np.uint8))
-        sg = jnp.asarray(np.zeros((n_pad, 64), dtype=np.uint8))
-        if kind == "verify":
-            return (pk, mg, sg)
-        return (
-            pk, mg, sg,
-            jnp.asarray(np.zeros((n_pad, ops_ed.POWER_CHUNKS), dtype=np.int32)),
-            jnp.asarray(np.zeros((n_pad,), dtype=bool)),
-        )
-
-    def _warm_entry(self, e: _Entry, kind: str, n_pad: int, msg_len: int) -> None:
+    def _warm_entry(self, e: _Entry, n_pad: int, msg_len: int) -> None:
         """Force compilation AND a first full execution by running on
         zeros. The device-to-host read makes the warm-up end where a
         live call ends (results on the host), so whatever a first
@@ -460,13 +414,22 @@ class VerifierModel:
         Whether block_until_ready alone would do on an attached chip
         is to be re-measured."""
         t0 = time.perf_counter()
-        out = e.fn(*self._zero_args(kind, n_pad, msg_len))
-        jax.tree_util.tree_map(np.asarray, out)
+        # zeros from HOST arrays exactly like the live call sites: jit
+        # specializes on input layout provenance, so warming with
+        # device-native jnp.zeros compiles an executable the live
+        # host-transferred inputs then miss (observed: a second ~11s
+        # compile on the first real call after warmup)
+        np.asarray(
+            e.fn(*(
+                jnp.asarray(np.zeros((n_pad, w), dtype=np.uint8))
+                for w in (32, msg_len, 64)
+            ))
+        )
         e.compile_s = time.perf_counter() - t0
         e.ready = True
         self.logger.info(
             "verifier bucket compiled",
-            kind=kind, rows=n_pad, msg_len=msg_len,
+            kind="verify", rows=n_pad, msg_len=msg_len,
             seconds=round(e.compile_s, 2),
         )
 
@@ -479,32 +442,32 @@ class VerifierModel:
             e.compiling = True
             return True
 
-    def _compile_async(self, e: _Entry, kind: str, n_pad: int, msg_len: int) -> None:
+    def _compile_async(self, e: _Entry, n_pad: int, msg_len: int) -> None:
         if not self._claim_compile(e):
             return
 
         def work():
             try:
-                self._warm_entry(e, kind, n_pad, msg_len)
+                self._warm_entry(e, n_pad, msg_len)
             except Exception as ex:  # pragma: no cover - defensive
                 self.logger.error("background compile failed", err=repr(ex))
             finally:
                 e.compiling = False
 
-        t = threading.Thread(target=work, daemon=True, name=f"compile-{kind}-{n_pad}")
+        t = threading.Thread(target=work, daemon=True, name=f"compile-verify-{n_pad}")
         _track_compile_thread(t)
         t.start()
 
-    def _get_fn(self, kind: str, n_pad: int, msg_len: int):
+    def _get_fn(self, n_pad: int, msg_len: int):
         """Returns the compiled callable, or None when non-blocking and
         the bucket is still cold (background compile kicked off)."""
-        e = self._entry(kind, n_pad, msg_len)
+        e = self._entry(n_pad, msg_len)
         if e.ready:
             return e.fn
         if self.block_on_compile:
             e.ready = True  # first call compiles inline
             return e.fn
-        self._compile_async(e, kind, n_pad, msg_len)
+        self._compile_async(e, n_pad, msg_len)
         return None
 
     # -- padding ----------------------------------------------------------
@@ -519,16 +482,6 @@ class VerifierModel:
         shard_map batch axis must split evenly across devices)."""
         mult = self._pad_multiple()
         return max((cap // mult) * mult, mult)
-
-    def _full_window_outputs(self, fn, arrays, n: int, window: int):
-        """Dispatch `fn` over every FULL window of `arrays` (all windows
-        stay in flight; no padding — each slice is exactly `window`
-        rows). Returns (outputs, tail_start)."""
-        outs = []
-        full_end = (n // window) * window
-        for off in range(0, full_end, window):
-            outs.append(fn(*(jnp.asarray(a[off : off + window]) for a in arrays)))
-        return outs, full_end
 
     def _pad(self, arr: np.ndarray, n_pad: int) -> np.ndarray:
         n = arr.shape[0]
@@ -562,7 +515,7 @@ class VerifierModel:
         if n > MAX_DEVICE_ROWS:
             return self._verify_windowed(pubkeys, msgs, sigs, msg_len)
         n_pad = _bucket(n, self._pad_multiple())
-        fn = self._get_fn("verify", n_pad, msg_len)
+        fn = self._get_fn(n_pad, msg_len)
         if fn is None:  # cold bucket, non-blocking: host fallback
             return self._cpu.verify_batch(pubkeys, msgs, sigs)
         faults.maybe("device.verify")
@@ -581,74 +534,23 @@ class VerifierModel:
         must not pay a full-window execution)."""
         n = int(pubkeys.shape[0])
         window = self._window_size(MAX_DEVICE_ROWS)
-        fn = self._get_fn("verify", window, msg_len)
+        fn = self._get_fn(window, msg_len)
         if fn is None:  # cold bucket, non-blocking: host fallback
             return self._cpu.verify_batch(pubkeys, msgs, sigs)
         pk = np.asarray(pubkeys, dtype=np.uint8)
         mg = np.asarray(msgs, dtype=np.uint8)
         sg = np.asarray(sigs, dtype=np.uint8)
-        outs, tail_start = self._full_window_outputs(fn, (pk, mg, sg), n, window)
+        # every full window in flight, exactly `window` rows a slice
+        tail_start = (n // window) * window
+        outs = [
+            fn(*(jnp.asarray(a[off : off + window]) for a in (pk, mg, sg)))
+            for off in range(0, tail_start, window)
+        ]
         parts = [np.asarray(o) for o in outs]
         self.row_counts.add(device=tail_start)
         if tail_start < n:
             parts.append(self.verify(pk[tail_start:], mg[tail_start:], sg[tail_start:]))
         return np.concatenate(parts)
-
-    def verify_commit(self, pubkeys, msgs, sigs, powers, counted) -> Tuple[np.ndarray, int]:
-        """Fused verify + tally; returns (ok (N,) bool, tallied power).
-
-        Batches beyond MAX_TALLY_ROWS (int32 tally-chunk headroom, which
-        coincides with the MAX_DEVICE_ROWS dispatch window) stream as
-        in-flight full-bucket windows with one final sync and a host-side
-        tally merge — same rationale as verify(), and no recursive
-        halving into oddly-padded sub-buckets."""
-        n = int(pubkeys.shape[0])
-        if n == 0:
-            return np.zeros(0, dtype=bool), 0
-        msg_len = int(msgs.shape[1])
-        window = self._window_size(min(ops_ed.MAX_TALLY_ROWS, MAX_DEVICE_ROWS))
-        if n > window:
-            fn = self._get_fn("tally", window, msg_len)
-            if fn is None:  # cold bucket, non-blocking: host fallback
-                return self._cpu.verify_commit_batch(
-                    pubkeys, msgs, sigs, powers, counted
-                )
-            pk = np.asarray(pubkeys, dtype=np.uint8)
-            mg = np.asarray(msgs, dtype=np.uint8)
-            sg = np.asarray(sigs, dtype=np.uint8)
-            ch = ops_ed.split_powers(powers)
-            ct = np.asarray(counted, dtype=bool)
-            outs, tail_start = self._full_window_outputs(
-                fn, (pk, mg, sg, ch, ct), n, window
-            )
-            ok_parts = [np.asarray(o) for o, _ in outs]
-            tallies = [
-                ops_ed.combine_power_chunks(np.asarray(sums)) for _, sums in outs
-            ]
-            self.row_counts.add(device=tail_start)
-            if tail_start < n:
-                ok_t, t_t = self.verify_commit(
-                    pk[tail_start:], mg[tail_start:], sg[tail_start:],
-                    np.asarray(powers)[tail_start:], ct[tail_start:],
-                )
-                ok_parts.append(ok_t)
-                tallies.append(t_t)
-            return np.concatenate(ok_parts), sum(tallies)
-        n_pad = _bucket(n, self._pad_multiple())
-        fn = self._get_fn("tally", n_pad, msg_len)
-        if fn is None:  # cold bucket, non-blocking: host fallback
-            return self._cpu.verify_commit_batch(pubkeys, msgs, sigs, powers, counted)
-        chunks = ops_ed.split_powers(powers)
-        ok, sums = fn(
-            jnp.asarray(self._pad(np.asarray(pubkeys, dtype=np.uint8), n_pad)),
-            jnp.asarray(self._pad(np.asarray(msgs, dtype=np.uint8), n_pad)),
-            jnp.asarray(self._pad(np.asarray(sigs, dtype=np.uint8), n_pad)),
-            jnp.asarray(self._pad(chunks, n_pad)),
-            jnp.asarray(self._pad(np.asarray(counted, dtype=bool), n_pad)),
-        )
-        out = np.asarray(ok)[:n], ops_ed.combine_power_chunks(np.asarray(sums))
-        self.row_counts.add(device=n)
-        return out
 
     # -- per-valset cached tables ------------------------------------------
     #
@@ -663,86 +565,18 @@ class VerifierModel:
     # on device.
 
     def _table_stage_fns(self):
-        cached = getattr(self, "_table_stages", None)
-        if cached is not None:
-            return cached
-        from tendermint_tpu.models.aot_cache import AotJit
-
-        if self.mesh is None:
-            self._table_stages = (
-                AotJit(ops_ed.verify_stage_prepare_tabled_gathered, "t-prepare-g"),
-                AotJit(ops_ed.verify_stage_scan_tabled, "t-scan"),
-                AotJit(ops_ed.verify_stage_finish_blocked, "t-finish"),
-                AotJit(ops_ed.build_valset_tables, "t-build"),
-            )
-            return self._table_stages
-        # Mesh path: rows shard over the batch axis, the valset tables
-        # REPLICATE (each device gathers its shard's rows from a full
-        # local copy — ~30KB/validator/device; no cross-device gather).
-        # The per-device program is identical to the single-device one,
-        # so compile cost is O(1) in mesh size, like the generic stages.
-        batch, rep = self._shard_specs()
-        tag = f"mesh{tuple(self.mesh.shape.values())}"
-        self._table_stages = (
-            AotJit(
-                None, f"t-prepare-g-{tag}",
-                # pubkey matrix replicates (like the tables); rows shard
-                jit_fn=self._smap(
-                    ops_ed.verify_stage_prepare_tabled_gathered, 4, (batch,) * 3,
-                    in_specs=(rep, batch, batch, batch),
-                ),
-            ),
-            AotJit(
-                None, f"t-scan-{tag}",
-                jit_fn=self._smap(
-                    ops_ed.verify_stage_scan_tabled, 5, (batch,) * 5,
-                    in_specs=(batch, batch, rep, rep, batch),
-                ),
-            ),
-            AotJit(
-                None, f"t-finish-{tag}",
-                jit_fn=self._smap(ops_ed.verify_stage_finish_blocked, 7, batch),
-            ),
-            # tables build once per valset: replicated output (every
-            # device computes the full table; a sharded build would save
-            # build time but force a cross-device gather per verify)
-            AotJit(None, f"t-build-{tag}", jit_fn=jax.jit(ops_ed.build_valset_tables)),
+        """Gathered tabled stages 1-3 and the table build."""
+        return tuple(
+            self._program(t) for t in ("t-prepare-g", "t-scan", "t-finish", "t-build")
         )
-        return self._table_stages
 
     def _materialize_fn(self):
         """The tiny templated-message materializer (one program per
         (t_pad, n_pad) shape): its u8 output feeds the SAME compiled
         prepare executables the materialized path uses — see
         ops_ed.materialize_sign_bytes for why this is a separate
-        program. fragile: skip executable persistence on XLA:CPU (the
-        crash class that motivated the split; the program is trivial
-        to recompile)."""
-        cached = getattr(self, "_materialize", None)
-        if cached is not None:
-            return cached
-        from tendermint_tpu.models.aot_cache import AotJit
-
-        with self._lock:  # one AotJit per model (warm threads race here)
-            cached = getattr(self, "_materialize", None)
-            if cached is not None:
-                return cached
-            if self.mesh is None:
-                self._materialize = AotJit(
-                    ops_ed.materialize_sign_bytes, "t-materialize", fragile=True
-                )
-            else:
-                batch, rep = self._shard_specs()
-                tag = f"mesh{tuple(self.mesh.shape.values())}"
-                self._materialize = AotJit(
-                    None, f"t-materialize-{tag}", fragile=True,
-                    # templates replicate (KB-scale); per-row columns shard
-                    jit_fn=self._smap(
-                        ops_ed.materialize_sign_bytes, 3, batch,
-                        in_specs=(rep, batch, batch),
-                    ),
-                )
-        return self._materialize
+        program."""
+        return self._program("t-materialize")
 
     def _slot_stage_fns(self):
         """Single-device tabled stages 1 and 2 in SLOT ORDER (plan_slots):
@@ -751,16 +585,7 @@ class VerifierModel:
         of ~30 KB of table (the gathered scan's copy is 315 MB a
         10,240-row launch, 16.7 of its 39.4 ms on a v5e). C = 1 is a full
         commit's shape."""
-        cached = getattr(self, "_slot_stages", None)
-        if cached is not None:
-            return cached
-        from tendermint_tpu.models.aot_cache import AotJit
-
-        self._slot_stages = (
-            AotJit(ops_ed.verify_stage_prepare_tabled_slots, "t-prepare-s"),
-            AotJit(ops_ed.verify_stage_scan_tabled_slots, "t-scan-s"),
-        )
-        return self._slot_stages
+        return self._program("t-prepare-s"), self._program("t-scan-s")
 
     def _build_tables(self, e: _TablesEntry, key: bytes, pubkeys: np.ndarray) -> None:
         from tendermint_tpu.models import aot_cache
@@ -776,17 +601,14 @@ class VerifierModel:
         # resolve the cache dir NOW: on the async-build path the env
         # var may point somewhere else by the time the thread saves
         tables_dir = aot_cache.tables_dir()
-        # Sets past the single-table bound keep their tables as
-        # equal-size <=MAX_TABLED_VALSET-row shards: the sharded scan
-        # gathers each shard bounded instead of one pathological
-        # huge-table gather. The shard size also respects the BUILD
-        # chunk (HBM bound of the build program's intermediates).
+        # Sets past the single-table bound keep their tables as equal
+        # MAX_TABLED_VALSET-row shards, each built by its own dispatch
+        # (the build's HBM bound) and gathered bounded by the sharded
+        # scan instead of one pathological huge-table gather.
         sharded = v_pad > MAX_TABLED_VALSET
-        shard_rows = (
-            min(MAX_TABLED_VALSET, _TABLE_BUILD_CHUNK) if sharded else v_pad
-        )
+        shard_rows = MAX_TABLED_VALSET if sharded else v_pad
         loaded = aot_cache.load_tables(key, v_pad, pk_digest)
-        shards = None
+        tables = shards = None
         if loaded is not None:
             # restart path: pure data from disk, no build program at all
             if sharded:
@@ -794,31 +616,18 @@ class VerifierModel:
                     jnp.asarray(loaded[0][off : off + shard_rows])
                     for off in range(0, v_pad, shard_rows)
                 )
-                tables = None
             else:
                 tables = jnp.asarray(loaded[0])
             a_ok = jnp.asarray(loaded[1])
             e.source = "disk"
         else:
-            build = self._table_stage_fns()[3]
-            # one build call per shard when sharded (shard_rows already
-            # respects the build chunk), else the plain HBM chunking
-            chunk = shard_rows if sharded else _TABLE_BUILD_CHUNK
-            if v_pad > chunk:
-                # the build program's post-scan affine conversion holds
-                # (rows*SPLITS*8, 20, 20) intermediates — one shot at
-                # 65536 rows wants ~30GB of HBM (observed OOM at 50k
-                # validators). Chunk the BUILD; past the single-table
-                # bound the chunks STAY separate as the scan's shards.
+            build = self._program("t-build")
+            if sharded:
                 parts = [
-                    build(jnp.asarray(pk_pad[off : off + chunk]))
-                    for off in range(0, v_pad, chunk)
+                    build(jnp.asarray(pk_pad[off : off + shard_rows]))
+                    for off in range(0, v_pad, shard_rows)
                 ]
-                if sharded:
-                    shards = tuple(t for t, _ in parts)
-                    tables = None
-                else:
-                    tables = jnp.concatenate([t for t, _ in parts])
+                shards = tuple(t for t, _ in parts)
                 a_ok = jnp.concatenate([a for _, a in parts])
             else:
                 tables, a_ok = build(jnp.asarray(pk_pad))
@@ -834,9 +643,7 @@ class VerifierModel:
             # to one device would re-broadcast ~30KB/validator to every
             # device on every verify dispatch (sharded entries only
             # reach a mesh when the set fits sharded_valset_cap())
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            rep = NamedSharding(self.mesh, PartitionSpec())
+            rep = NamedSharding(self.mesh, _R)
             if sharded:
                 shards = tuple(jax.device_put(s, rep) for s in shards)
             else:
@@ -977,8 +784,7 @@ class VerifierModel:
         return None
 
     def verify_rows_cached(
-        self, valset_key: bytes, all_pubkeys, row_idx, msgs, sigs,
-        _window_tail: bool = False,
+        self, valset_key: bytes, all_pubkeys, row_idx, msgs, sigs
     ) -> Optional[np.ndarray]:
         """Verify rows whose pubkeys are all_pubkeys[row_idx] against the
         per-valset cached tables (single device, or a mesh: rows shard
@@ -990,20 +796,14 @@ class VerifierModel:
         duplicate indices are fine (the trusting path may produce them).
         Its shape alone picks the table operand: whole commits in
         validator order go to their validators' slots (plan_slots),
-        anything else gathers. _window_tail is internal: the windowed
-        path's tail slice must not hit the small-batch gather policy
-        (the windows already ran; nullifying the tail would discard all
-        their device work).
+        anything else gathers.
         """
         src = ("mat", np.asarray(msgs, dtype=np.uint8))
-        return self._rows_cached_core(
-            valset_key, all_pubkeys, row_idx, src, sigs, _window_tail
-        )
+        return self._rows_cached_core(valset_key, all_pubkeys, row_idx, src, sigs)
 
     def verify_rows_cached_templated(
         self, valset_key: bytes, all_pubkeys, row_idx,
         templates, tmpl_idx, ts8, sigs,
-        _window_tail: bool = False,
     ) -> Optional[np.ndarray]:
         """verify_rows_cached with TEMPLATED messages: row r's sign
         bytes are templates[tmpl_idx[r]] with ts8[r] (8 bytes,
@@ -1021,9 +821,7 @@ class VerifierModel:
             np.asarray(tmpl_idx, dtype=np.int32),
             np.asarray(ts8, dtype=np.uint8),
         )
-        return self._rows_cached_core(
-            valset_key, all_pubkeys, row_idx, src, sigs, _window_tail
-        )
+        return self._rows_cached_core(valset_key, all_pubkeys, row_idx, src, sigs)
 
     # -- shared cached-path machinery (mat | tpl message sources) ---------
 
@@ -1045,19 +843,8 @@ class VerifierModel:
         """Dispatch the right stage-2 flavor: single table (gathered)
         or sharded per-shard bounded gathers."""
         if e.shards is not None:
-            fn = getattr(self, "_sharded_scan", None)
-            if fn is None:
-                from tendermint_tpu.models.aot_cache import AotJit
-
-                with self._lock:  # one AotJit per model, like the stage tuples
-                    fn = getattr(self, "_sharded_scan", None)
-                    if fn is None:
-                        fn = self._sharded_scan = AotJit(
-                            ops_ed.verify_stage_scan_tabled_sharded, "t-scan-sh"
-                        )
-            return fn(sd, kd, e.a_ok, idx_dev, e.shards)
-        s2 = self._table_stage_fns()[1]
-        return s2(sd, kd, e.tables, e.a_ok, idx_dev)
+            return self._program("t-scan-sh")(sd, kd, e.a_ok, idx_dev, e.shards)
+        return self._program("t-scan")(sd, kd, e.tables, e.a_ok, idx_dev)
 
     @staticmethod
     def _src_msg_len(src) -> int:
@@ -1112,10 +899,11 @@ class VerifierModel:
     def _gathered_launch(self, e: _TablesEntry, src, n_pad: int, idx_dev, sg_dev):
         """Stages 1-3 of the gathered pair over one padded launch;
         returns the device verdicts."""
-        s1, _, s3 = self._table_stage_fns()[:3]
-        sd, kd, s_ok = s1(e.pk_dev, idx_dev, self._src_messages(src, n_pad), sg_dev)
+        sd, kd, s_ok = self._program("t-prepare-g")(
+            e.pk_dev, idx_dev, self._src_messages(src, n_pad), sg_dev
+        )
         px, py, pz, pt, a_ok = self._scan_rows(e, sd, kd, idx_dev)
-        return s3(px, py, pz, pt, sg_dev, a_ok, s_ok)
+        return self._program("t-finish")(px, py, pz, pt, sg_dev, a_ok, s_ok)
 
     def _slot_launch(self, e: _TablesEntry, src, n_slots: int, sg_dev):
         """Stages 1-3 in slot order over one launch of n_slots = C*V
@@ -1123,11 +911,10 @@ class VerifierModel:
         s1, s2 = self._slot_stage_fns()
         sd, kd, s_ok = s1(e.pk_dev, self._src_messages(src, n_slots), sg_dev)
         px, py, pz, pt, a_ok = s2(sd, kd, e.tables, e.a_ok)
-        return self._table_stage_fns()[2](px, py, pz, pt, sg_dev, a_ok, s_ok)
+        return self._program("t-finish")(px, py, pz, pt, sg_dev, a_ok, s_ok)
 
     def _rows_cached_core(
-        self, valset_key: bytes, all_pubkeys, row_idx, src, sigs,
-        _window_tail: bool = False,
+        self, valset_key: bytes, all_pubkeys, row_idx, src, sigs
     ) -> Optional[np.ndarray]:
         n = int(len(row_idx))
         if n == 0:
@@ -1135,48 +922,61 @@ class VerifierModel:
         e = self._tables_entry(valset_key, np.asarray(all_pubkeys, dtype=np.uint8))
         if e is None:
             return None
-        idx_np = np.asarray(row_idx, dtype=np.int32)
-        plan = None if _window_tail else plan_slots(idx_np, self._slot_table_rows(e))
+        idx = np.asarray(row_idx, dtype=np.int32)
+        sg = np.asarray(sigs, dtype=np.uint8)
+        plan = plan_slots(idx, self._slot_table_rows(e))
         if plan is not None:
             # whole commits of a set they mostly fill: rows go to their
             # validators' slots and stage 2 reads the tables in place
-            return self._rows_cached_slots(e, plan, src, sigs)
-        if n > MAX_DEVICE_ROWS:
-            # cross-height streaming (eval 3): full windows through the
-            # tabled stages, all in flight, one sync — the per-window
-            # decompress and table build the generic path pays are
-            # already hoisted into the cached tables
-            return self._rows_cached_windowed(
-                valset_key, e, all_pubkeys, idx_np, src, sigs
-            )
+            return self._rows_cached_slots(e, plan, src, sg)
+        return self._rows_cached_gathered(e, idx, src, sg)
+
+    def _rows_cached_gathered(
+        self, e: _TablesEntry, idx: np.ndarray, src, sg: np.ndarray
+    ) -> Optional[np.ndarray]:
+        """Verify a batch in gathered order: one bucketed launch, or past
+        MAX_DEVICE_ROWS (cross-height streaming, eval 3) full windows
+        and a bucketed tail — every launch in flight, one sync. The
+        per-window decompress and table build the generic path pays are
+        already hoisted into the cached tables."""
+        n = int(idx.shape[0])
+        window = self._window_size(MAX_DEVICE_ROWS)
+        # (first row, end row, padded rows) a launch; the entry key
+        # includes the table's padded row count (_tabled_bucket_entry):
+        # a valset that grows past its pad bucket must re-warm, not run
+        # a synchronous compile on the live path
+        launches = [
+            (lo, lo + window, window) for lo in range(0, n - window + 1, window)
+        ]
+        if n % window:
+            launches.append((n - n % window, n, _bucket(n % window, self._pad_multiple())))
+        ents = {
+            pad: self._tabled_bucket_entry(e, pad, src)
+            for pad in {pad for _, _, pad in launches}
+        }
+        cold = [(ent, pad) for pad, ent in ents.items() if not ent.ready]
+        if cold and not self.block_on_compile:
+            # every bucket must be warm before anything is dispatched:
+            # discovering a cold tail after the windows already ran
+            # would throw away all that device work and re-verify the
+            # whole batch on the fallback path
+            for ent, pad in cold:
+                self._compile_tabled_async(ent, e, pad, src)
+            return None
         faults.maybe("device.verify")
-        n_pad = _bucket(n, self._pad_multiple())
-        if (
-            not _window_tail
-            and e.shards is None
-            and self._table_rows(e) > _GATHER_POLICY_MIN_TABLE
-            and self._table_rows(e) > 4 * n_pad
-        ):
-            # small gathered batch against a huge SINGLE table: the
-            # per-row ~30KB table gather goes pathological when the
-            # table dwarfs the batch (measured: 50k-validator ingest in
-            # 2048-vote drains fell from 19.9k votes/s generic to 436
-            # through this path) — the generic pipeline wins there.
-            # Sharded entries are exempt: their gathers are bounded per
-            # shard, which is the whole point of sharding.
-            return None
-        # the bucket key includes the table's padded row count (see
-        # _tabled_bucket_entry): a valset that grows past its pad bucket
-        # must re-warm, not run a synchronous compile on the live path
-        ent = self._tabled_bucket_entry(e, n_pad, src)
-        if not ent.ready and not self.block_on_compile:
-            self._compile_tabled_async(ent, e, n_pad, src)
-            return None
-        sg = jnp.asarray(self._pad(np.asarray(sigs, dtype=np.uint8), n_pad))
         t0 = time.perf_counter()
         try:
-            idx = jnp.asarray(self._pad(idx_np, n_pad))
-            out = np.asarray(self._gathered_launch(e, src, n_pad, idx, sg))[:n]
+            outs = [
+                self._gathered_launch(
+                    e, self._src_slice(src, slice(lo, hi)), pad,
+                    jnp.asarray(self._pad(idx[lo:hi], pad)),
+                    jnp.asarray(self._pad(sg[lo:hi], pad)),
+                )
+                for lo, hi, pad in launches
+            ]
+            out = np.concatenate(
+                [np.asarray(o)[: hi - lo] for o, (lo, hi, _) in zip(outs, launches)]
+            )
             self.row_counts.add(device=n)
             self._tabled_counts.add(gathered_rows=n)
         except Exception as ex:
@@ -1189,13 +989,13 @@ class VerifierModel:
                 "tabled verify failed (falling back)", rows=n, err=repr(ex)[:200]
             )
             return None
-        if not ent.ready:
+        for ent, _ in cold:
             ent.compile_s = time.perf_counter() - t0
             ent.ready = True
         return out
 
     def _rows_cached_slots(
-        self, e: _TablesEntry, plan: SlotPlan, src, sigs
+        self, e: _TablesEntry, plan: SlotPlan, src, sg: np.ndarray
     ) -> Optional[np.ndarray]:
         """Verify a planned batch in slot order: every launch in flight,
         one sync, the verdicts read back from the rows' slots. Same
@@ -1214,7 +1014,6 @@ class VerifierModel:
                 self._compile_tabled_async(ent, e, c * v, src, slots=True)
             return None
         faults.maybe("device.verify")
-        sg = np.asarray(sigs, dtype=np.uint8)
         t0 = time.perf_counter()
         try:
             outs, base = [], 0
@@ -1256,70 +1055,6 @@ class VerifierModel:
                 ent = _Entry(None)
                 self._entries[key] = ent
             return ent
-
-    def _rows_cached_windowed(
-        self, valset_key: bytes, e: _TablesEntry, all_pubkeys, row_idx, src, sigs
-    ) -> Optional[np.ndarray]:
-        n = int(len(row_idx))
-        window = self._window_size(MAX_DEVICE_ROWS)
-        full_end = (n // window) * window
-        tail_pad = _bucket(n - full_end, self._pad_multiple()) if full_end < n else 0
-        win_ent = self._tabled_bucket_entry(e, window, src)
-        tail_ent = (
-            self._tabled_bucket_entry(e, tail_pad, src) if tail_pad else None
-        )
-        if not self.block_on_compile:
-            # BOTH buckets must be warm before dispatching anything:
-            # discovering a cold tail after the windows already ran
-            # would throw away all that device work and re-verify the
-            # whole batch on the fallback path
-            cold = [
-                (ent, pad)
-                for ent, pad in ((win_ent, window), (tail_ent, tail_pad))
-                if ent is not None and not ent.ready
-            ]
-            if cold:
-                for ent, pad in cold:
-                    self._compile_tabled_async(ent, e, pad, src)
-                return None
-        sg = np.asarray(sigs, dtype=np.uint8)
-        idx = np.asarray(row_idx, dtype=np.int32)
-        try:
-            outs = []
-            for off in range(0, full_end, window):
-                sl = slice(off, off + window)
-                idx_d = jnp.asarray(idx[sl])
-                sg_d = jnp.asarray(sg[sl])
-                outs.append(
-                    self._gathered_launch(e, self._src_slice(src, sl), window, idx_d, sg_d)
-                )
-            win_ent.ready = True  # compile timing lives in the AOT layer
-            parts = [np.asarray(o) for o in outs]
-            self.row_counts.add(device=full_end)
-            self._tabled_counts.add(gathered_rows=full_end)
-        except Exception as ex:
-            # same None-means-fallback contract as the bucketed branch:
-            # a transient device/compile failure mid-window degrades the
-            # whole batch to the generic path, never crashes replay
-            self.logger.error(
-                "tabled windowed verify failed (falling back)",
-                rows=n, err=repr(ex)[:200],
-            )
-            return None
-        if full_end < n:
-            # true reuse of the bucketed path for the tail slice;
-            # _window_tail bypasses the small-batch gather policy (the
-            # windows already ran — nullifying the tail would discard
-            # all their device work)
-            tail = self._rows_cached_core(
-                valset_key, all_pubkeys, idx[full_end:],
-                self._src_slice(src, slice(full_end, n)),
-                sg[full_end:], _window_tail=True,
-            )
-            if tail is None:  # racing eviction or compile failure
-                return None
-            parts.append(tail)
-        return np.concatenate(parts) if parts else np.zeros(0, dtype=bool)
 
     def register_valset(self, valset_key: bytes, all_pubkeys, msg_len: int = 160) -> None:
         """Pre-build the cached tables for a valset and warm its tabled
@@ -1453,7 +1188,8 @@ class VerifierModel:
     # -- warmup ------------------------------------------------------------
 
     def warmup(self, sizes=(16, 1024), msg_len: int = 160, background: bool = False):
-        """Pre-compile buckets so live commits pay no compile.
+        """Pre-compile the generic buckets (one program chain a bucket)
+        so live calls pay no compile.
 
         ``background=True`` returns immediately; a daemon thread warms
         each bucket in turn (node-start path). Returns the thread (or
@@ -1466,20 +1202,19 @@ class VerifierModel:
 
         def work():
             for n_pad in pads:
-                for kind in ("verify", "tally"):
-                    e = self._entry(kind, n_pad, msg_len)
-                    if not self._claim_compile(e):
-                        continue  # a live call is already compiling it
-                    try:
-                        self._warm_entry(e, kind, n_pad, msg_len)
-                    except Exception as ex:
-                        self.logger.error(
-                            "warmup compile failed", kind=kind, rows=n_pad,
-                            err=repr(ex),
-                        )
-                        return
-                    finally:
-                        e.compiling = False
+                e = self._entry(n_pad, msg_len)
+                if not self._claim_compile(e):
+                    continue  # a live call is already compiling it
+                try:
+                    self._warm_entry(e, n_pad, msg_len)
+                except Exception as ex:
+                    self.logger.error(
+                        "warmup compile failed", kind="verify", rows=n_pad,
+                        err=repr(ex),
+                    )
+                    return
+                finally:
+                    e.compiling = False
 
         if background:
             t = threading.Thread(target=work, daemon=True, name="verifier-warmup")
@@ -1489,7 +1224,11 @@ class VerifierModel:
         work()
         return None
 
-    def compile_stats(self) -> Dict[Tuple[str, int, int], Optional[float]]:
-        """(kind, rows, msg_len) -> compile seconds (None = inline/unknown)."""
+    def compile_stats(self) -> Dict[tuple, Optional[float]]:
+        """Ready entry -> compile seconds (None = inline/unknown). A
+        generic bucket's key is ("verify", rows, msg_len); a tabled
+        shape's is (kind, rows or slots, msg_len, padded templates,
+        table rows, shards) with kind "tabled" or "slots", "-tpl" for
+        templated messages (_tabled_bucket_entry)."""
         with self._lock:
             return {k: e.compile_s for k, e in self._entries.items() if e.ready}

@@ -487,8 +487,9 @@ class Node(Service):
 
     def _build_crypto_mesh(self, want: int):
         """Mesh over the first `want` local JAX devices, or None (logged)
-        when the host has fewer. The batch axis is the only sharded axis;
-        the quorum tally is psum'd over ICI (SURVEY §5.8)."""
+        when the host has fewer. The batch axis is the only sharded axis:
+        verdicts come back per row and the host tallies the quorum
+        (SURVEY §5.8)."""
         try:
             import jax
 

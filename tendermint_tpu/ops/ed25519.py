@@ -14,25 +14,19 @@ This is exactly Go x/crypto's cofactorless acceptance (R is never
 decompressed; non-canonical A.y accepted mod p), so a batch accepts a
 signature iff the reference's serial verifier does -- consensus-safe.
 
-The fused commit tally additionally sums voting power over verified
-rows (the reference's tally loop at types/validator_set.go:656),
-returning int32 chunk sums (TPU has no int64) recombined on host.
+The kernels return one verdict bit per row and nothing else: summing
+voting power over the verified rows (the reference's tally loop at
+types/validator_set.go:656) is a column sum on the host
+(crypto/batch.BatchVerifier.verify_commit_batch).
 """
 
 from __future__ import annotations
-
-from typing import Tuple
 
 import jax.numpy as jnp
 
 from tendermint_tpu.ops import curve
 from tendermint_tpu.ops import sc
 from tendermint_tpu.ops.sha512 import sha512
-
-POWER_CHUNKS = 4
-POWER_CHUNK_BITS = 16
-# Max rows per tally: chunk sums stay below 2^31 (2^16 * 2^14 = 2^30).
-MAX_TALLY_ROWS = 1 << 14
 
 
 def verify_core(
@@ -87,15 +81,6 @@ def verify_stage_finish(px, py, pz, pt, sigs, a_ok, s_ok):
     enc = curve.encode(curve.Point(px, py, pz, pt))
     r_match = jnp.all(enc == sigs[:, :32].astype(jnp.int32), axis=-1)
     return r_match & a_ok & s_ok
-
-
-def verify_stage_finish_tally(px, py, pz, pt, sigs, a_ok, s_ok, power_chunks, counted):
-    """Stage 3 (tally flavor): encode+compare fused with the voting-power
-    segment sum."""
-    ok = verify_stage_finish(px, py, pz, pt, sigs, a_ok, s_ok)
-    mask = (ok & counted).astype(jnp.int32)
-    chunk_sums = jnp.sum(power_chunks * mask[:, None], axis=0)
-    return ok, chunk_sums
 
 
 # -- per-valset cached-table pipeline ----------------------------------------
@@ -181,7 +166,7 @@ def materialize_sign_bytes(templates, tmpl_idx, ts8):
     T is static and tiny (2 per commit; one pair per height in a
     cross-height batch), so the per-row template gather reads ~160 B
     rows from a KB-scale table — nothing like the pathological
-    30 KB-row valset-table gathers (models/verifier.py policy)."""
+    30 KB-row valset-table gathers (models/verifier.MAX_TABLED_VALSET)."""
     if templates.shape[0] == 1:
         rows = jnp.broadcast_to(
             templates, (tmpl_idx.shape[0],) + templates.shape[1:]
@@ -258,41 +243,3 @@ def verify_stage_finish_blocked(px, py, pz, pt, sigs, a_ok, s_ok):
     enc = curve.encode(curve.Point(px, py, pz, pt), blocked=True)
     r_match = jnp.all(enc == sigs[:, :32].astype(jnp.int32), axis=-1)
     return r_match & a_ok & s_ok
-
-
-def split_powers(powers) -> jnp.ndarray:
-    """Host helper: (N,) int64 voting powers -> (N, 4) int32 16-bit
-    chunks (little-endian)."""
-    import numpy as np
-
-    p = np.asarray(powers, dtype=np.int64)
-    chunks = np.stack(
-        [(p >> (POWER_CHUNK_BITS * i)) & 0xFFFF for i in range(POWER_CHUNKS)], axis=-1
-    )
-    return chunks.astype(np.int32)
-
-
-def combine_power_chunks(chunk_sums) -> int:
-    """Host helper: (4,) int32 chunk sums -> python int total power."""
-    total = 0
-    for i in range(POWER_CHUNKS):
-        total += int(chunk_sums[i]) << (POWER_CHUNK_BITS * i)
-    return total
-
-
-def verify_and_tally(
-    pubkeys: jnp.ndarray,
-    msgs: jnp.ndarray,
-    sigs: jnp.ndarray,
-    power_chunks: jnp.ndarray,
-    counted: jnp.ndarray,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Fused verify + voting-power segment-sum.
-
-    power_chunks (N, 4) int32; counted (N,) bool. Returns (ok (N,) bool,
-    chunk_sums (4,) int32 summing power where ok & counted).
-    """
-    ok = verify_core(pubkeys, msgs, sigs)
-    mask = (ok & counted).astype(jnp.int32)
-    chunk_sums = jnp.sum(power_chunks * mask[:, None], axis=0)
-    return ok, chunk_sums

@@ -2,8 +2,8 @@
 
 One logical axis ("batch") carries the signature dimension. On a single
 chip the mesh is trivial; on a pod slice it spans all devices and the
-batched verify shards rows across chips with the fused tally reduced by
-XLA collectives over ICI.
+batched verify shards rows across chips; per-row verdicts come back to
+the host, which tallies them (no collective).
 """
 
 from __future__ import annotations

@@ -8,10 +8,10 @@ UpdateWithChangeSet :803 region, VerifyCommit :629, VerifyCommitTrusting
 The TPU-first change: ``verify_commit`` / ``verify_commit_trusting`` do
 NOT loop ``pubkey.verify`` per signature like the reference
 (types/validator_set.go:641-668). They pack all present signatures into
-rectangular arrays and make ONE BatchVerifier call (device segment-sum
-tally fused), then replay the reference's sequential-early-return
-semantics over the returned ok/power vectors so acceptance is bit-for-bit
-identical to the serial loop.
+rectangular arrays and make ONE BatchVerifier call for the verdicts,
+then replay the reference's sequential-early-return semantics (tally
+included) over the returned ok/power vectors on the host so acceptance
+is bit-for-bit identical to the serial loop.
 """
 
 from __future__ import annotations
@@ -443,9 +443,8 @@ class ValidatorSet:
         verify serially through their own PubKey.verify — the
         reference accepts any registered key type for validators
         (types/validator_set.go:641 calls the interface method)."""
-        # verify_batch, not verify_commit_batch: the tally would be
-        # discarded (the host replay recomputes it), and this kernel is
-        # the one vote ingest already keeps warm.
+        # verify_batch: verdicts only (the host replay tallies them),
+        # and this kernel is the one vote ingest already keeps warm.
         if ed.all():
             return self._ed_rows(
                 provider, np.asarray(vals_idx, dtype=np.int64), pk, mg, sg,

@@ -7,6 +7,7 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
+from tendermint_tpu.crypto.batch import BatchVerifier
 from tendermint_tpu.models.verifier import VerifierModel
 from tendermint_tpu.parallel import make_mesh
 
@@ -42,6 +43,21 @@ def signed_batch(n, msg_len=96, seed=11):
         msgs[i] = np.frombuffer(msg, dtype=np.uint8)
         sigs[i] = np.frombuffer(keys[i % len(keys)].sign(msg), dtype=np.uint8)
     return pks, msgs, sigs
+
+
+class _OverModel(BatchVerifier):
+    """A provider over one VerifierModel: verify_batch is model.verify,
+    verify_commit_batch the host tally every provider inherits."""
+
+    def __init__(self, model):
+        self.model = model
+
+    def verify_batch(self, pubkeys, msgs, sigs, msg_lens=None):
+        return self.model.verify(pubkeys, msgs, sigs, msg_lens=msg_lens)
+
+
+def verify_then_tally(model, pk, mg, sg, powers, counted):
+    return _OverModel(model).verify_commit_batch(pk, mg, sg, powers, counted)
 
 
 @pytest.fixture(scope="module")

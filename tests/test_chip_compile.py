@@ -113,18 +113,6 @@ def test_tabled_slot_scan_compiles_for_v5e(topo, compile_for):
     assert compiled.memory_analysis().temp_size_in_bytes <= 700e6
 
 
-def test_finish_tally_compiles_for_v5e(topo, compile_for):
-    """Stage 3 of the generic path with the fused voting-power tally."""
-    S, like = shapes(SingleDeviceSharding(topo.devices[0]))
-    pk, mg, sg = S((N, 32), u8), S((N, 160), u8), S((N, 64), u8)
-    pre = like(jax.eval_shape(E.verify_stage_prepare, pk, mg, sg))
-    co = like(jax.eval_shape(E.verify_stage_scan, *pre[:6]))
-    compile_for(
-        E.verify_stage_finish_tally, 60,
-        *co, sg, pre[6], pre[7], S((N, E.POWER_CHUNKS), i32), S((N,), jnp.bool_),
-    )
-
-
 def test_shard_map_scan_compiles_for_four_chips(topo, compile_for):
     """The mesh form of the generic scan: rows shard over four devices,
     and the per-device program is the single-device one at N/4 rows."""

@@ -67,21 +67,6 @@ def test_verify_core_matches_reference(mixed_batch):
     assert [bool(b) for b in ok] == want
 
 
-def test_fused_tally(mixed_batch):
-    rows, want = mixed_batch
-    pks, msgs, sigs = _pack(rows)
-    powers = np.arange(1, len(rows) + 1, dtype=np.int64) * 7
-    counted = np.ones(len(rows), dtype=bool)
-    counted[0] = False  # a verified-but-not-counted row (nil vote)
-    ok, chunks = jax.jit(dev.verify_and_tally)(
-        pks, msgs, sigs, jnp.asarray(dev.split_powers(powers)), jnp.asarray(counted)
-    )
-    got = dev.combine_power_chunks(np.asarray(chunks))
-    expect = sum(int(p) for p, w, c in zip(powers, want, counted) if w and c)
-    assert got == expect
-    assert [bool(b) for b in np.asarray(ok)] == want
-
-
 def test_rfc8032_vector():
     pk = bytes.fromhex("d75a980182b10ab7d54bfed3c964073a0ee172f3daa62325af021a68f707511a")
     sig = bytes.fromhex(
@@ -100,18 +85,27 @@ class TestVerifierModel:
     def test_model_verify_and_commit(self, mixed_batch):
         from tendermint_tpu.models.verifier import VerifierModel
 
+        from tendermint_tpu.crypto.batch import TPUBatchVerifier
+
         rows, want = mixed_batch
         pks, msgs, sigs = _pack(rows)
-        model = VerifierModel()
+        prov = TPUBatchVerifier()
+        model = prov.model
+        assert isinstance(model, VerifierModel)
         ok = model.verify(np.asarray(pks), np.asarray(msgs), np.asarray(sigs))
         assert [bool(b) for b in ok] == want
 
-        powers = np.full(len(rows), 3, dtype=np.int64)
+        # the commit form: the same program, the tally summed on the
+        # host over its verdicts (a verified row that is not counted —
+        # a nil vote — adds nothing)
+        powers = np.arange(1, len(rows) + 1, dtype=np.int64) * 7
         counted = np.ones(len(rows), dtype=bool)
-        ok2, tally = model.verify_commit(
+        counted[0] = False
+        ok2, tally = prov.verify_commit_batch(
             np.asarray(pks), np.asarray(msgs), np.asarray(sigs), powers, counted
         )
-        assert tally == 3 * sum(want)
+        assert [bool(b) for b in ok2] == want
+        assert tally == sum(int(p) for p, w, c in zip(powers, want, counted) if w and c)
 
     def test_model_sharded_matches_unsharded(self, mixed_batch, cpu_mesh):
         from tendermint_tpu.models.verifier import VerifierModel

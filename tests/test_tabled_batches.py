@@ -24,7 +24,6 @@ def test_sharded_tables_large_valset(monkeypatch, tmp_path):
 
     monkeypatch.setenv("TM_TABLES_CACHE_DIR", str(tmp_path))
     monkeypatch.setattr(vmod, "MAX_TABLED_VALSET", 8)
-    monkeypatch.setattr(vmod, "_TABLE_BUILD_CHUNK", 8)
     monkeypatch.setattr(vmod, "MAX_SHARDED_VALSET", 64)
 
     v = 20

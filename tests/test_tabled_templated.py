@@ -23,6 +23,7 @@ def test_register_valset_prewarms_tabled_path():
     build completes)."""
     import time as _time
 
+    import tendermint_tpu.models.verifier as mv
     from tendermint_tpu.models.verifier import VerifierModel
 
     # msg_len 160 = the commit sign-bytes width register_valset warms
@@ -38,32 +39,36 @@ def test_register_valset_prewarms_tabled_path():
     assert len(m._valset_tables) == 1  # no rebuild
 
     # Non-blocking: the warmup ALONE (no live traffic) must build the
-    # tables and warm the valset-size bucket — polled WITHOUT calling
-    # verify_rows_cached, which would otherwise kick the lazy build
-    # itself and mask a broken warmup.
+    # tables and warm the valset-size bucket — awaited on the warm-up's
+    # own threads, WITHOUT calling verify_rows_cached, which would
+    # otherwise kick the lazy build itself and mask a broken warmup.
+    with mv._compile_threads_lock:
+        before = set(mv._compile_threads)
     m2 = VerifierModel(block_on_compile=False)
     m2.register_valset(b"boot-valset-2", pk)
-    deadline = _time.monotonic() + 120
-    warmed = False
-    while _time.monotonic() < deadline:
-        e = m2._valset_tables.get(b"boot-valset-2")
-        if e is not None and e.ready:
-            rows = int(e.tables.shape[0])
-            # a full commit's slot-order shape (one commit of `rows`
-            # slots) and the gathered pair at the set's bucket, both
-            # message flavors
-            ents = [
-                m2._entries.get(k)
-                for k in (
-                    ("slots", rows, 160, 0, rows, 1), ("slots-tpl", rows, 160, 2, rows, 1),
-                    ("tabled", 16, 160, 0, rows, 1), ("tabled-tpl", 16, 160, 2, rows, 1),
-                )
-            ]
-            if all(ent is not None and ent.ready for ent in ents):
-                warmed = True
-                break
-        _time.sleep(0.25)
-    assert warmed, "warmup alone never built tables + warmed the bucket"
+    # the build thread, the thread that waits for it, then the four
+    # warm passes it starts: joined until none this test started is
+    # left, however slowly a loaded machine compiles them
+    deadline = _time.monotonic() + 540
+    while True:
+        with mv._compile_threads_lock:
+            mine = [t for t in mv._compile_threads if t not in before and t.is_alive()]
+        if not mine:
+            break
+        for t in mine:
+            t.join(timeout=max(0.0, deadline - _time.monotonic()))
+        assert _time.monotonic() < deadline, f"warm-up still running: {mine}"
+    e = m2._valset_tables.get(b"boot-valset-2")
+    assert e is not None and e.ready, "warmup alone never built the tables"
+    rows = int(e.tables.shape[0])
+    # a full commit's slot-order shape (one commit of `rows` slots) and
+    # the gathered pair at the set's bucket, both message flavors
+    for k in (
+        ("slots", rows, 160, 0, rows, 1), ("slots-tpl", rows, 160, 2, rows, 1),
+        ("tabled", 16, 160, 0, rows, 1), ("tabled-tpl", 16, 160, 2, rows, 1),
+    ):
+        ent = m2._entries.get(k)
+        assert ent is not None and ent.ready, f"warmup alone never warmed {k}"
     # and the first live call is served immediately (no None fallback)
     ok2 = m2.verify_rows_cached(b"boot-valset-2", pk, idx, mg, sg)
     assert ok2 is not None and ok2.all()

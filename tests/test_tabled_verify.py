@@ -202,11 +202,11 @@ def test_oversized_valset_skips_tabled_path(monkeypatch):
     assert b"big-valset" not in m._valset_tables  # nothing was built
 
 
-def test_small_gathered_batch_against_huge_table_falls_back(monkeypatch):
-    """A gathered batch the table dwarfs (>4x padded rows, table above
-    the policy floor) returns None rather than running the pathological
-    per-row table gather. Below the floor the tabled path still serves
-    small drains (the pathology was only measured on ~2GB tables)."""
+def test_small_sparse_batch_against_one_table_rides_gathered_pair():
+    """A batch the table dwarfs — a sparse vote drain, out of validator
+    order or in it — is served by the gathered pair, not declined and
+    not sent to the slots: a table holds at most MAX_TABLED_VALSET
+    rows, where gathers are fine (larger sets ride bounded shards)."""
     from tendermint_tpu.models import verifier as vmod
 
     pks, msgs, sigs = sign_rows(80, seed=53)
@@ -222,19 +222,15 @@ def test_small_gathered_batch_against_huge_table_falls_back(monkeypatch):
     c1 = TABLED_COUNTS.snapshot()
     assert (c1["tabled_slot_rows"] - c0["tabled_slot_rows"], c1["tabled_slot_pad"] - c0["tabled_slot_pad"]) == (80, 176)
     sub = np.array([5, 2, 9], dtype=np.int32)
-    # below the policy floor: the gathered path still engages — for a
-    # sparse vote batch out of order (three runs) and in order (one
-    # run: 256 slots for a 16-row bucket, beyond _SLOT_GATHER_RATIO)
+    # the gathered path engages for a sparse vote batch out of order
+    # (three runs) and in order (one run: 256 slots for a 16-row
+    # bucket, beyond _SLOT_GATHER_RATIO)
     for rows in (sub, np.sort(sub)):
         out = m.verify_rows_cached(b"gather-valset", pk, rows, mg[rows], sg[rows])
         assert out is not None and out.all()
     c2 = TABLED_COUNTS.snapshot()
     assert c2["tabled_gathered_rows"] - c1["tabled_gathered_rows"] == 6
     assert c2["tabled_slot_rows"] == c1["tabled_slot_rows"]
-    # floor lowered: 256 > 4*16 and 256 > floor -> generic fallback
-    monkeypatch.setattr(vmod, "_GATHER_POLICY_MIN_TABLE", 64)
-    out = m.verify_rows_cached(b"gather-valset", pk, sub, mg[sub], sg[sub])
-    assert out is None
 
 
 def test_tables_disk_cache_bounded(tmp_path, monkeypatch):

@@ -189,17 +189,18 @@ def test_tpu_provider_small_batch_routes_to_host():
 
 
 def test_verify_commit_windows_large_batches(monkeypatch):
-    """Batches beyond the tally window stream as full-bucket windows
-    with a host-side tally merge; results are identical to the direct
-    path (window shrunk via monkeypatch so the test stays fast)."""
+    """Batches beyond MAX_DEVICE_ROWS stream as full windows and a
+    bucketed tail (VerifierModel._verify_windowed), the tally summed on
+    the host over all their verdicts; an invalid row in the middle
+    window and an uncounted row come out as on the direct path (window
+    shrunk via monkeypatch so the test stays fast)."""
     import numpy as np
 
     import tendermint_tpu.models.verifier as mv
     from tendermint_tpu.models.verifier import VerifierModel
     from tendermint_tpu.ops import ref_ed25519 as ref
 
-    n = 40  # spans 3 windows of 16
-    monkeypatch.setattr(mv.ops_ed, "MAX_TALLY_ROWS", 16)
+    n = 40  # two full windows of 16 and a tail of 8
     monkeypatch.setattr(mv, "MAX_DEVICE_ROWS", 16)
 
     seeds = [bytes([i + 1]) * 32 for i in range(4)]
@@ -224,9 +225,46 @@ def test_verify_commit_windows_large_batches(monkeypatch):
     sigs = sigs.copy()
     sigs[17, 0] ^= 1  # invalid row in the middle window
 
-    model = VerifierModel()
-    ok, tally = model.verify_commit(pks, msgs, sigs, powers, counted)
+    prov = TPUBatchVerifier()
+    assert isinstance(prov.model, VerifierModel)
+    ok, tally = prov.verify_commit_batch(pks, msgs, sigs, powers, counted)
     assert ok.shape == (n,)
     assert not ok[17] and ok[np.arange(n) != 17].all()
     expected = int(powers[(np.arange(n) != 17) & counted].sum())
     assert tally == expected
+    # windows and tail all rode the one 16-row bucket, on the device
+    assert set(prov.model.compile_stats()) == {("verify", 16, 160)}
+    assert prov.row_counts.snapshot() == (n, 0)
+
+
+def test_warmup_compiles_one_generic_program_a_bucket():
+    """warmup readies the verify chain of each bucket and nothing else:
+    no program is compiled at node start that no call will launch."""
+    from tendermint_tpu.models.verifier import VerifierModel
+
+    model = VerifierModel()
+    assert model.warmup(sizes=(16,), msg_len=160) is None
+    stats = model.compile_stats()
+    assert set(stats) == {("verify", 16, 160)}
+    assert stats[("verify", 16, 160)] > 0
+    # the three stages of the chain, each made once
+    assert set(model._programs) == {"prepare", "scan", "finish"}
+
+
+@pytest.mark.parametrize("cls_path", [
+    "tendermint_tpu.crypto.batch.TPUBatchVerifier",
+    "tendermint_tpu.crypto.batch.MeshRoutedVerifier",
+    "tendermint_tpu.crypto.batch.CPUBatchVerifier",
+    "tendermint_tpu.crypto.pipeline.PipelinedVerifier",
+])
+def test_one_definition_of_verify_then_tally(cls_path):
+    """verify_commit_batch is BatchVerifier's host composition for
+    every ed25519 provider, wrapped or not: none carries its own."""
+    import importlib
+
+    from tendermint_tpu.crypto.batch import BatchVerifier
+
+    mod, name = cls_path.rsplit(".", 1)
+    cls = getattr(importlib.import_module(mod), name)
+    assert issubclass(cls, BatchVerifier)
+    assert cls.verify_commit_batch is BatchVerifier.verify_commit_batch
