@@ -84,10 +84,16 @@ class NamedCounts:
 # ``fixup_rows`` those of them off the common shape — a non-64-byte
 # signature or a non-ed25519 key (verified row by row outside the
 # batch) or an unknown address (dropped). An all-ed25519 commit reads 0
-# fix-up rows; a BLS or mixed set shows the other side. The seam packs
-# before a provider is chosen (types/ knows none), so its counts are
-# the process's, as crypto/merkle.device_stats() are.
-SEAM_COUNTS = NamedCounts("seam", ("column_rows", "packed_rows", "fixup_rows"))
+# fix-up rows; a BLS or mixed set shows the other side.
+# ``overlapped_rows`` are the packed rows a provider pulled from a
+# RowGroups after an earlier group of the same call — packed while the
+# device ran the launch before them; 7/8 of a 128-commit chain of 1,024
+# slots, 0 for one commit. The seam packs before a provider is chosen
+# (types/ knows none), so its counts are the process's, as
+# crypto/merkle.device_stats() are.
+SEAM_COUNTS = NamedCounts(
+    "seam", ("column_rows", "packed_rows", "fixup_rows", "overlapped_rows")
+)
 
 # Which table operand the cached-table path took (models/verifier.py
 # plan_slots): ``slot_rows`` real rows verified in slot order (tables
@@ -97,10 +103,39 @@ SEAM_COUNTS = NamedCounts("seam", ("column_rows", "packed_rows", "fixup_rows"))
 TABLED_COUNTS = NamedCounts("tabled", ("slot_rows", "slot_pad", "gathered_rows"))
 
 
+class RowGroups:
+    """The rows of whole commits of ONE all-ed25519 validator set, in
+    commit order, packed when a provider asks for them (the seam's
+    source is types/validator_set._SpecRows). It stands in for the row
+    arguments of ``verify_rows_cached_templated`` with a provider whose
+    ``takes_row_groups`` is true.
+
+    The provider takes a launch's worth of commits, dispatches the
+    launch, and only then takes the next: device dispatch is
+    asynchronous, so the caller's thread packs group k+1 while the
+    device runs launch k. It answers with the verdicts of every row it
+    took, in the order taken, having taken them all — or with None,
+    at any point, and the seam finishes the packing and sends every
+    row down the generic path."""
+
+    left = 0  # whole commits not yet taken
+
+    def take(self, commits: int):
+        """Pack the next ``commits`` commits (fewer at the end):
+        (row_idx (n,) i32, templates (2k, 160) u8, tmpl_idx (n,) i32,
+        ts8 (n, 8) u8, sigs (n, 64) u8), the templates the group's own.
+        None when a row is off the common shape (a non-64-byte
+        signature): the provider answers None in turn."""
+        raise NotImplementedError
+
+
 class BatchVerifier:
     """Batch signature verification over rectangular u8 arrays."""
 
     name = "abstract"
+    # whether verify_rows_cached_templated takes a RowGroups for its
+    # row arguments and feeds its launches as the groups are packed
+    takes_row_groups = False
 
     def verify_batch(
         self,
@@ -160,17 +195,31 @@ class BatchVerifier:
         self,
         valset_key: bytes,
         all_pubkeys: np.ndarray,
-        row_idx: np.ndarray,
-        templates: np.ndarray,
-        tmpl_idx: np.ndarray,
-        ts8: np.ndarray,
-        sigs: np.ndarray,
+        row_idx,
+        templates: Optional[np.ndarray] = None,
+        tmpl_idx: Optional[np.ndarray] = None,
+        ts8: Optional[np.ndarray] = None,
+        sigs: Optional[np.ndarray] = None,
     ) -> Optional[np.ndarray]:
         """verify_rows_cached with TEMPLATED messages: row r's sign
         bytes are templates[tmpl_idx[r]] (T, 160) with ts8[r] (8 bytes)
         spliced at the timestamp offset (codec/signbytes.py layout).
         Device providers materialize rows on device, cutting per-row
-        H2D from ~228 B to ~80 B. Same None-means-fallback contract."""
+        H2D from ~228 B to ~80 B. Same None-means-fallback contract.
+
+        A provider whose ``takes_row_groups`` is true also takes a
+        RowGroups as ``row_idx`` (the other row arguments left out): still
+        ONE call that answers with every row's verdict in row order,
+        but its launches are fed as the groups are packed — it takes
+        group k+1 only after dispatching launch k, and syncs once at
+        the end. None at any point (tables or a shape cold, a failed
+        launch, a row off the common shape) throws the launches away:
+        the caller finishes the packing and sends every row down the
+        generic path, so each row is verified and counted once. The
+        provider knows no row count when it starts: a size gate is held
+        against the slots the commits span, and the set's tables are
+        looked up (or their build started) even if every spec then
+        fails its pre-checks and brings no row."""
         return None
 
 
@@ -319,9 +368,23 @@ class TPUBatchVerifier(BatchVerifier):
             valset_key, all_pubkeys, row_idx, msgs, sigs
         )
 
+    @property
+    def takes_row_groups(self) -> bool:
+        # a router plans over a row count no lazy source can state
+        return self.router is None
+
     def verify_rows_cached_templated(
-        self, valset_key, all_pubkeys, row_idx, templates, tmpl_idx, ts8, sigs
+        self, valset_key, all_pubkeys, row_idx, templates=None, tmpl_idx=None,
+        ts8=None, sigs=None,
     ):
+        if isinstance(row_idx, RowGroups):
+            # no row is packed yet: the gate is held against the slots
+            # the commits span, the most rows they can bring
+            if row_idx.left * len(all_pubkeys) < self.min_device_batch:
+                return None
+            return self._model.verify_rows_cached_templated(
+                valset_key, all_pubkeys, row_idx
+            )
         if len(row_idx) < self.min_device_batch:
             return None
         ran, out = self._meshed(

@@ -233,6 +233,16 @@ def verify_chain(
     header checks run sequentially first; the per-link accept/reject replay
     preserves the step-by-step semantics, so the first failing link raises
     exactly what the per-step path would have raised.
+
+    One device call is one provider call, its launches fed as they are
+    packed: a chain of adjacent headers under one validator set reaches a
+    provider that takes row groups (crypto/batch.RowGroups) a launch's
+    worth of commits at a time, so every commit after the first group is
+    read into columns and packed while the device runs the launch before
+    it (types/validator_set.verify_commits_batched). Should the provider
+    decline at any point, the packing is finished and the whole chain
+    goes down the generic path, each row verified once; the verdicts and
+    the exception raised are the same either way.
     """
     now = _now_ns(now_ns)
     specs: List[CommitVerifySpec] = []

@@ -109,7 +109,13 @@ def verify_specs(
     A pipelined provider gets the specs via ``submit_commit`` so that
     concurrent callers share one cross-height device bundle; everything
     else goes through ``verify_commits_batched`` directly (still ONE
-    device call for this spec list)."""
+    device call for this spec list). Either way "one device call" is one
+    synchronous provider call a spec list or bundle: full-mode specs of
+    one validator set are packed a launch's worth of commits at a time,
+    each group while the device runs the launch before it; if the
+    provider declines (None) at any point the packing is finished and
+    every row goes down the generic path once — the results are those of
+    the direct calls in every case (``verify_commits_batched``)."""
     if not specs:
         return []
     p = provider or get_default_provider()

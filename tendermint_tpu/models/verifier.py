@@ -207,6 +207,17 @@ def _gathered_rows(n: int) -> int:
     return n - tail + (_bucket(tail, 1) if tail else 0)
 
 
+def _commits_per_launch(v: int) -> int:
+    """Whole commits of V slots one slot-order launch holds."""
+    return max(1, MAX_DEVICE_ROWS // v)
+
+
+def _tail_commits(rest: int, per: int) -> int:
+    """The commits' worth of slots the launch of the last ``rest``
+    commits (fewer than ``per``) is rounded up to: a power of two."""
+    return min(per, 1 << (rest - 1).bit_length()) if rest else 0
+
+
 def plan_slots(row_idx, v: int) -> Optional[SlotPlan]:
     """Slot order for a batch against a V-row table, or None where the
     gathered pair is cheaper. Pure numpy, from row_idx and V alone.
@@ -230,9 +241,9 @@ def plan_slots(row_idx, v: int) -> Optional[SlotPlan]:
         return None
     run_start = np.flatnonzero(idx[1:] <= idx[:-1]) + 1  # rows that open a run
     runs = run_start.shape[0] + 1
-    per = max(1, MAX_DEVICE_ROWS // v)  # whole commits a launch holds
+    per = _commits_per_launch(v)
     full, rest = divmod(runs, per)
-    last = min(per, 1 << (rest - 1).bit_length()) if rest else 0
+    last = _tail_commits(rest, per)
     if (full * per + last) * v > _SLOT_GATHER_RATIO * _gathered_rows(n):
         return None
     run_of = np.zeros(n, dtype=np.int64)
@@ -799,11 +810,11 @@ class VerifierModel:
         anything else gathers.
         """
         src = ("mat", np.asarray(msgs, dtype=np.uint8))
-        return self._rows_cached_core(valset_key, all_pubkeys, row_idx, src, sigs)
+        return self._rows_cached_arrays(valset_key, all_pubkeys, row_idx, src, sigs)
 
     def verify_rows_cached_templated(
         self, valset_key: bytes, all_pubkeys, row_idx,
-        templates, tmpl_idx, ts8, sigs,
+        templates=None, tmpl_idx=None, ts8=None, sigs=None,
     ) -> Optional[np.ndarray]:
         """verify_rows_cached with TEMPLATED messages: row r's sign
         bytes are templates[tmpl_idx[r]] with ts8[r] (8 bytes,
@@ -814,14 +825,24 @@ class VerifierModel:
 
         templates (T, 160) u8 — T is padded up to a small bucket so
         cross-height batches (one template pair per height) don't
-        compile per T. Same None-means-fallback contract."""
-        src = (
-            "tpl",
-            np.asarray(templates, dtype=np.uint8),
-            np.asarray(tmpl_idx, dtype=np.int32),
-            np.asarray(ts8, dtype=np.uint8),
-        )
-        return self._rows_cached_core(valset_key, all_pubkeys, row_idx, src, sigs)
+        compile per T. Same None-means-fallback contract.
+
+        row_idx may be a crypto/batch.RowGroups (whole commits of the
+        set, the other row arguments None): still one call and one
+        sync, the verdicts of every row in row order — but each
+        slot-order launch's worth of commits is taken from the source
+        only after the launch before it is dispatched, so the caller's
+        seam packs it while the device runs (_group_pieces). None, at
+        any point, leaves the source to its owner to finish."""
+        from tendermint_tpu.crypto.batch import RowGroups
+
+        if not isinstance(row_idx, RowGroups):
+            src = self._tpl_src(templates, tmpl_idx, ts8)
+            return self._rows_cached_arrays(valset_key, all_pubkeys, row_idx, src, sigs)
+        e = self._tables_entry(valset_key, np.asarray(all_pubkeys, dtype=np.uint8))
+        if e is None:
+            return None
+        return self._rows_cached_core(e, self._group_pieces(e, row_idx))
 
     # -- shared cached-path machinery (mat | tpl message sources) ---------
 
@@ -871,14 +892,13 @@ class VerifierModel:
         return ("tpl", src[1], src[2][sl], src[3][sl])
 
     @staticmethod
-    def _src_to_slots(src, rows: slice, at: np.ndarray, n_slots: int):
-        """The source's rows `rows` scattered to slots `at` of a zeroed
+    def _src_to_slots(src, at: np.ndarray, n_slots: int):
+        """The source's rows scattered to slots `at` of a zeroed
         n_slots-row source (templates are shared)."""
         if src[0] == "mat":
-            return ("mat", _to_slots(src[1][rows], at, n_slots))
+            return ("mat", _to_slots(src[1], at, n_slots))
         return (
-            "tpl", src[1],
-            _to_slots(src[2][rows], at, n_slots), _to_slots(src[3][rows], at, n_slots),
+            "tpl", src[1], _to_slots(src[2], at, n_slots), _to_slots(src[3], at, n_slots),
         )
 
     def _src_messages(self, src, n_pad: int):
@@ -913,132 +933,182 @@ class VerifierModel:
         px, py, pz, pt, a_ok = s2(sd, kd, e.tables, e.a_ok)
         return self._program("t-finish")(px, py, pz, pt, sg_dev, a_ok, s_ok)
 
-    def _rows_cached_core(
+    @staticmethod
+    def _tpl_src(templates, tmpl_idx, ts8):
+        return (
+            "tpl",
+            np.asarray(templates, dtype=np.uint8),
+            np.asarray(tmpl_idx, dtype=np.int32),
+            np.asarray(ts8, dtype=np.uint8),
+        )
+
+    def _rows_cached_arrays(
         self, valset_key: bytes, all_pubkeys, row_idx, src, sigs
     ) -> Optional[np.ndarray]:
-        n = int(len(row_idx))
-        if n == 0:
+        """A batch handed over as arrays: one piece."""
+        if len(row_idx) == 0:
             return np.zeros(0, dtype=bool)
         e = self._tables_entry(valset_key, np.asarray(all_pubkeys, dtype=np.uint8))
         if e is None:
             return None
-        idx = np.asarray(row_idx, dtype=np.int32)
-        sg = np.asarray(sigs, dtype=np.uint8)
+        piece = (
+            np.asarray(row_idx, dtype=np.int32), src, np.asarray(sigs, dtype=np.uint8), (),
+        )
+        return self._rows_cached_core(e, (piece,))
+
+    def _group_pieces(self, e: _TablesEntry, groups):
+        """A RowGroups as pieces for _rows_cached_core: the whole
+        commits one slot-order launch holds (MAX_DEVICE_ROWS // V; the
+        last group is rounded by plan_slots as any batch's tail is),
+        each taken — packed by the seam, on this thread — when the loop
+        comes back for it, the launches before it dispatched. Where
+        slot order never applies (a mesh and sharded tables gather)
+        everything at once: an eager batch. None where the source
+        declines.
+
+        The first piece names the shape of the last group's launch
+        ahead of it, by arithmetic from the commits left (C the power
+        of two over the rest, a template pair a commit): a chain
+        shorter or longer than the last has another tail shape, and one
+        that is cold is found before anything is dispatched, not after
+        the launches before it ran."""
+        v = self._slot_table_rows(e)
+        per = _commits_per_launch(v) if 0 < v <= MAX_DEVICE_ROWS else max(1, groups.left)
+        full, rest = divmod(groups.left, per)
+        while groups.left:
+            got = groups.take(per)
+            if got is None:
+                yield None
+                return
+            idx, templates, tmpl_idx, ts8, sg = got
+            src = self._tpl_src(templates, tmpl_idx, ts8)
+            ahead = ()
+            if full and rest:
+                tail = ("tpl", np.empty((2 * rest, self._src_msg_len(src)), dtype=np.uint8))
+                ahead = ((_tail_commits(rest, per) * v, tail, True),)
+                rest = 0
+            yield (
+                np.asarray(idx, dtype=np.int32), src, np.asarray(sg, dtype=np.uint8), ahead,
+            )
+
+    def _plan_launches(self, e: _TablesEntry, idx: np.ndarray) -> list:
+        """(first row, end row, padded rows, the rows' slots) a launch
+        of a piece. Whole commits of a set they mostly fill go to
+        their validators' slots, C*V a launch, and stage 2 reads the
+        tables in place (plan_slots). Anything else gathers (slots
+        None): one bucketed launch, or past MAX_DEVICE_ROWS
+        (cross-height streaming, eval 3) full windows and a bucketed
+        tail."""
         plan = plan_slots(idx, self._slot_table_rows(e))
         if plan is not None:
-            # whole commits of a set they mostly fill: rows go to their
-            # validators' slots and stage 2 reads the tables in place
-            return self._rows_cached_slots(e, plan, src, sg)
-        return self._rows_cached_gathered(e, idx, src, sg)
-
-    def _rows_cached_gathered(
-        self, e: _TablesEntry, idx: np.ndarray, src, sg: np.ndarray
-    ) -> Optional[np.ndarray]:
-        """Verify a batch in gathered order: one bucketed launch, or past
-        MAX_DEVICE_ROWS (cross-height streaming, eval 3) full windows
-        and a bucketed tail — every launch in flight, one sync. The
-        per-window decompress and table build the generic path pays are
-        already hoisted into the cached tables."""
+            v = int(e.tables.shape[0])
+            bases = np.cumsum([0] + [c * v for _, _, c in plan.launches])
+            return [
+                (lo, hi, c * v, plan.slots[lo:hi] - base)
+                for (lo, hi, c), base in zip(plan.launches, bases)
+            ]
         n = int(idx.shape[0])
         window = self._window_size(MAX_DEVICE_ROWS)
-        # (first row, end row, padded rows) a launch; the entry key
-        # includes the table's padded row count (_tabled_bucket_entry):
-        # a valset that grows past its pad bucket must re-warm, not run
-        # a synchronous compile on the live path
         launches = [
-            (lo, lo + window, window) for lo in range(0, n - window + 1, window)
+            (lo, lo + window, window, None) for lo in range(0, n - window + 1, window)
         ]
         if n % window:
-            launches.append((n - n % window, n, _bucket(n % window, self._pad_multiple())))
-        ents = {
-            pad: self._tabled_bucket_entry(e, pad, src)
-            for pad in {pad for _, _, pad in launches}
-        }
-        cold = [(ent, pad) for pad, ent in ents.items() if not ent.ready]
-        if cold and not self.block_on_compile:
-            # every bucket must be warm before anything is dispatched:
-            # discovering a cold tail after the windows already ran
-            # would throw away all that device work and re-verify the
-            # whole batch on the fallback path
-            for ent, pad in cold:
-                self._compile_tabled_async(ent, e, pad, src)
-            return None
-        faults.maybe("device.verify")
-        t0 = time.perf_counter()
+            tail = _bucket(n % window, self._pad_multiple())
+            launches.append((n - n % window, n, tail, None))
+        return launches
+
+    def _rows_cached_core(self, e: _TablesEntry, pieces) -> Optional[np.ndarray]:
+        """Verify a batch that comes in pieces — (row_idx, src, sigs,
+        shapes ahead) each: the one piece of an array call, or a
+        RowGroups' groups (_group_pieces), each pulled only after the
+        launches of the one before it are dispatched. Every launch in
+        flight, one sync, the verdicts in row order; only the real rows
+        count as device rows. The per-window decompress and table build
+        the generic path pays are already hoisted into the cached
+        tables.
+
+        None means fallback, at any point and never an exception into
+        commit verification: a shape cold in non-blocking mode, a
+        source that declines, a transient device or compile failure.
+        Every shape of a piece, and every shape it names ahead (the
+        last group's), must be warm before any of its launches is
+        dispatched: a cold tail found after the windows ran would throw
+        that device work away. NOT latched as e.failed — the tables
+        themselves are fine and the next call may succeed. What was
+        dispatched is dropped uncounted."""
+        outs = []  # (device verdicts, the rows' places in them) a launch
+        cold = {}  # id -> entry this call compiles inline
+        rows = slot_rows = slots = 0
+        t0 = None
         try:
-            outs = [
-                self._gathered_launch(
-                    e, self._src_slice(src, slice(lo, hi)), pad,
-                    jnp.asarray(self._pad(idx[lo:hi], pad)),
-                    jnp.asarray(self._pad(sg[lo:hi], pad)),
-                )
-                for lo, hi, pad in launches
-            ]
-            out = np.concatenate(
-                [np.asarray(o)[: hi - lo] for o, (lo, hi, _) in zip(outs, launches)]
+            for piece in pieces:
+                if piece is None:
+                    return None
+                idx, src, sg, ahead = piece
+                launches = self._plan_launches(e, idx)
+                shapes = [(pad, src, at is not None) for _, _, pad, at in launches]
+                if not self.block_on_compile:
+                    shapes.extend(ahead)
+                # the entry key includes the table's padded row count
+                # (_tabled_bucket_entry): a valset that grows past its
+                # pad bucket must re-warm, not run a synchronous
+                # compile on the live path
+                fresh = [
+                    (ent, pad, of, in_slots)
+                    for pad, of, in_slots in shapes
+                    for ent in [self._tabled_bucket_entry(e, pad, of, slots=in_slots)]
+                    if not ent.ready
+                ]
+                if fresh and not self.block_on_compile:
+                    for ent, pad, of, in_slots in fresh:
+                        self._compile_tabled_async(ent, e, pad, of, slots=in_slots)
+                    return None
+                cold.update((id(ent), ent) for ent, *_ in fresh)
+                if t0 is None:
+                    faults.maybe("device.verify")
+                    t0 = time.perf_counter()
+                for lo, hi, pad, at in launches:
+                    rows_src = self._src_slice(src, slice(lo, hi))
+                    outs.append(self._launch(e, idx[lo:hi], rows_src, sg[lo:hi], pad, at))
+                    if at is not None:
+                        slot_rows += hi - lo
+                        slots += pad
+                rows += int(idx.shape[0])
+            out = (
+                np.concatenate([np.asarray(o)[take] for o, take in outs])
+                if outs else np.zeros(0, dtype=bool)
             )
-            self.row_counts.add(device=n)
-            self._tabled_counts.add(gathered_rows=n)
+            self.row_counts.add(device=rows)
+            self._tabled_counts.add(
+                slot_rows=slot_rows, slot_pad=slots - slot_rows,
+                gathered_rows=rows - slot_rows,
+            )
+        except faults.InjectedFault:
+            raise
         except Exception as ex:
-            # None-means-fallback, never an exception into commit
-            # verification: a transient device or compile failure must
-            # degrade to the generic path, not crash the node. NOT
-            # latched as e.failed — the tables themselves are fine and
-            # the next call may succeed.
             self.logger.error(
-                "tabled verify failed (falling back)", rows=n, err=repr(ex)[:200]
+                "tabled verify failed (falling back)", rows=rows, err=repr(ex)[:200]
             )
             return None
-        for ent, _ in cold:
+        for ent in cold.values():
             ent.compile_s = time.perf_counter() - t0
             ent.ready = True
         return out
 
-    def _rows_cached_slots(
-        self, e: _TablesEntry, plan: SlotPlan, src, sg: np.ndarray
-    ) -> Optional[np.ndarray]:
-        """Verify a planned batch in slot order: every launch in flight,
-        one sync, the verdicts read back from the rows' slots. Same
-        None-means-fallback contract as the gathered paths; only the
-        real rows count as device rows."""
-        n = int(plan.slots.shape[0])
-        v = int(e.tables.shape[0])
-        ents = {
-            c: self._tabled_bucket_entry(e, c * v, src, slots=True)
-            for c in {c for _, _, c in plan.launches}
-        }
-        cold = [(ent, c) for c, ent in ents.items() if not ent.ready]
-        if cold and not self.block_on_compile:
-            # every shape must be warm before anything is dispatched
-            for ent, c in cold:
-                self._compile_tabled_async(ent, e, c * v, src, slots=True)
-            return None
-        faults.maybe("device.verify")
-        t0 = time.perf_counter()
-        try:
-            outs, base = [], 0
-            for lo, hi, c in plan.launches:
-                rows, at = slice(lo, hi), plan.slots[lo:hi] - base
-                outs.append(
-                    self._slot_launch(
-                        e, self._src_to_slots(src, rows, at, c * v), c * v,
-                        jnp.asarray(_to_slots(sg[rows], at, c * v)),
-                    )
-                )
-                base += c * v
-            out = np.concatenate([np.asarray(o) for o in outs])[plan.slots]
-            self.row_counts.add(device=n)
-            self._tabled_counts.add(slot_rows=n, slot_pad=base - n)
-        except Exception as ex:
-            self.logger.error(
-                "tabled slot-order verify failed (falling back)",
-                rows=n, err=repr(ex)[:200],
-            )
-            return None
-        for ent, _ in cold:
-            ent.compile_s = time.perf_counter() - t0
-            ent.ready = True
-        return out
+    def _launch(self, e: _TablesEntry, idx, src, sg, n_pad: int, at):
+        """Dispatch one launch over the rows given: in slot order when
+        ``at`` gives their slots of the n_pad = C*V, else gathered and
+        padded to n_pad. (device verdicts, the rows' places in them)."""
+        if at is None:
+            n = int(idx.shape[0])
+            return self._gathered_launch(
+                e, src, n_pad,
+                jnp.asarray(self._pad(idx, n_pad)), jnp.asarray(self._pad(sg, n_pad)),
+            ), slice(0, n)
+        return self._slot_launch(
+            e, self._src_to_slots(src, at, n_pad), n_pad,
+            jnp.asarray(_to_slots(sg, at, n_pad)),
+        ), at
 
     def _tabled_bucket_entry(
         self, e: _TablesEntry, n_pad: int, src, slots: bool = False

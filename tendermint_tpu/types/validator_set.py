@@ -26,7 +26,9 @@ import numpy as np
 from tendermint_tpu.codec.binary import Reader, Writer
 from tendermint_tpu.codec.signbytes import splice_timestamps
 from tendermint_tpu.crypto import merkle
-from tendermint_tpu.crypto.batch import SEAM_COUNTS, BatchVerifier, get_default_provider
+from tendermint_tpu.crypto.batch import (
+    SEAM_COUNTS, BatchVerifier, RowGroups, get_default_provider,
+)
 from tendermint_tpu.types.block import BLOCK_ID_FLAG_COMMIT, MAX_SIGNATURE_SIZE, first_true
 from tendermint_tpu.types.validator import Validator
 
@@ -969,6 +971,100 @@ class CommitVerifySpec:
         self.trust_level = trust_level
 
 
+# what a group none of whose specs passed its pre-checks hands over
+_NO_ROWS = tuple(
+    np.zeros(shape, dtype=dtype)
+    for shape, dtype in (
+        (0, np.int32), ((0, 160), np.uint8), (0, np.int32), ((0, 8), np.uint8),
+        ((0, 64), np.uint8),
+    )
+)
+
+
+class _SpecRows(RowGroups):
+    """The rows of a spec list, packed a spec at a time in spec order.
+
+    Packing a spec is its host pre-checks (structure, height/BlockID
+    match, set size) and its columns gathered into arrays
+    (_commit_batch_arrays); it needs nothing from any other spec. A
+    spec that fails contributes no rows and leaves its exception in
+    ``results``. ``take`` is a provider's side (crypto/batch.RowGroups),
+    ``finish`` and the parts lists are verify_commits_batched's."""
+
+    def __init__(self, specs, results):
+        self.specs, self.results = specs, results
+        self.left = len(specs)
+        # (spec_idx, idxs, vals_idx, powers, counted, rows, ed) of the
+        # specs packed so far that passed their pre-checks
+        self.segments: list = []
+        self.pk, self.mg, self.sg, self.tpl = [], [], [], []
+
+    def _pack(self, count: int) -> None:
+        """Pack the next ``count`` specs."""
+        done = len(self.specs) - self.left
+        self.left -= count
+        for si in range(done, done + count):
+            s = self.specs[si]
+            try:
+                if s.mode == "trusting":
+                    ValidatorSet._validate_trust_level(s.trust_level)
+                else:
+                    s.valset._check_commit_size(s.commit)
+                s.valset._verify_commit_basic(s.commit, s.height, s.block_id)
+                idxs, vals_idx, pk, mg, sg, powers, counted, ed, tpl = (
+                    s.valset._commit_batch_arrays(
+                        s.chain_id, s.commit, by_address=(s.mode == "trusting")
+                    )
+                )
+            except Exception as e:
+                self.results[si] = e
+                continue
+            self.segments.append((si, idxs, vals_idx, powers, counted, len(idxs), ed))
+            self.pk.append(pk)
+            self.mg.append(mg)
+            self.sg.append(sg)
+            self.tpl.append(tpl)
+
+    def finish(self) -> None:
+        """Pack every spec not yet packed."""
+        self._pack(self.left)
+
+    def one_ed25519_set(self, spec_idxs) -> bool:
+        """Whether these specs check against ONE all-ed25519 validator
+        set: the shape the per-valset cached tables serve."""
+        caches = [self.specs[si].valset.batch_cache() for si in spec_idxs]
+        key0, _, ed0 = caches[0]
+        return bool(ed0.all()) and all(c[0] == key0 for c in caches[1:])
+
+    def stacked(self, lo: int, hi: int) -> Tuple:
+        """Templated row arguments of segments lo..hi but the
+        signatures: (row_idx i32, templates (2k, 160), tmpl_idx, ts8),
+        each segment's template pair at its offset in the stacked
+        template matrix."""
+        tpl = self.tpl[lo:hi]
+        return (
+            np.concatenate(
+                [np.asarray(seg[2], dtype=np.int32) for seg in self.segments[lo:hi]]
+            ),
+            np.concatenate([t[0] for t in tpl], axis=0),
+            np.concatenate([t[1] + 2 * k for k, t in enumerate(tpl)]),
+            np.concatenate([t[2] for t in tpl], axis=0),
+        )
+
+    def take(self, commits: int):
+        overlapped = self.left < len(self.specs)
+        lo = len(self.segments)
+        self._pack(min(commits, self.left))
+        segs = self.segments[lo:]
+        if not segs:
+            return _NO_ROWS
+        if not all(seg[6].all() for seg in segs):
+            return None
+        if overlapped:
+            SEAM_COUNTS.add(overlapped_rows=sum(seg[5] for seg in segs))
+        return self.stacked(lo, len(self.segments)) + (np.concatenate(self.sg[lo:], axis=0),)
+
+
 def verify_commits_batched(
     specs: Sequence[CommitVerifySpec],
     provider: Optional[BatchVerifier] = None,
@@ -985,93 +1081,45 @@ def verify_commits_batched(
     direct method call would have raised. Host-side pre-checks (structure,
     height/BlockID match, set-size) run per spec before packing; a spec
     failing pre-checks contributes no device rows.
+
+    "One device call" is one synchronous provider call whose launches
+    are fed as they are packed: when the specs are full-mode commits of
+    one all-ed25519 validator set (a light client's chain, a fast-sync
+    window, a pipeline bundle) and the provider takes row groups
+    (crypto/batch.RowGroups), it pulls a launch's worth of commits,
+    dispatches the launch and pulls the next, so all but the first
+    group are packed while the device runs (SEAM_COUNTS
+    ``overlapped_rows``). Mixed sets, a trusting spec, a non-ed25519
+    key, a provider that cannot stream: the rows are packed first and
+    go as one eager batch. Either way the provider may decline (None)
+    at any point — cold tables or shape, a failed launch, a non-64-byte
+    signature met mid-list: the packing is finished here and the whole
+    list goes down the generic path with the rows an eager call would
+    have sent, each verified and counted once; the replay runs per spec
+    on its own slice.
     """
     results: List[Optional[Exception]] = [None] * len(specs)
-    segments = []  # (spec_idx, idxs, vals_idx, powers, counted)
-    pk_parts, mg_parts, sg_parts = [], [], []
-    tpl_templates, tpl_idx_parts, ts8_parts = [], [], []
-    for si, s in enumerate(specs):
-        try:
-            if s.mode == "trusting":
-                ValidatorSet._validate_trust_level(s.trust_level)
-            else:
-                s.valset._check_commit_size(s.commit)
-            s.valset._verify_commit_basic(s.commit, s.height, s.block_id)
-            idxs, vals_idx, pk, mg, sg, powers, counted, ed, tpl = (
-                s.valset._commit_batch_arrays(
-                    s.chain_id, s.commit, by_address=(s.mode == "trusting")
-                )
-            )
-        except Exception as e:
-            results[si] = e
-            continue
-        segments.append((si, idxs, vals_idx, powers, counted, len(idxs), ed))
-        pk_parts.append(pk)
-        mg_parts.append(mg)
-        sg_parts.append(sg)
-        # each spec contributes its own template pair; row indices
-        # offset into the stacked (2S, 160) template matrix
-        tpl_templates.append(tpl[0])
-        tpl_idx_parts.append(tpl[1] + 2 * (len(tpl_templates) - 1))
-        ts8_parts.append(tpl[2])
-
+    rows = _SpecRows(specs, results)
+    v = provider or get_default_provider()
+    # whole commits of one set in validator order: the shape a provider
+    # can take a group at a time
+    chain = (
+        bool(specs)
+        and all(s.mode == "full" for s in specs)
+        and rows.one_ed25519_set(range(len(specs)))
+    )
+    ok = None
+    streamed = chain and getattr(v, "takes_row_groups", False)
+    if streamed:
+        key0, all_pk0, _ = specs[0].valset.batch_cache()
+        ok = v.verify_rows_cached_templated(key0, all_pk0, rows)
+    rows.finish()
+    segments = rows.segments
     if not segments:
         return results
-
-    pk = np.concatenate(pk_parts, axis=0)
-    mg = np.concatenate(mg_parts, axis=0)
-    sg = np.concatenate(sg_parts, axis=0)
-    ed_all = np.concatenate([seg[6] for seg in segments])
-    v = provider or get_default_provider()
-    if ed_all.all():
-        # When every spec checks against the SAME validator set (the
-        # fast-sync window / light-client sequential shape: the set is
-        # stable across heights), the whole cross-height batch rides
-        # the per-valset cached tables — per-window decompression and
-        # table builds are hoisted out entirely (eval 3). The templated
-        # form uploads one template pair per HEIGHT plus 12 B/row of
-        # deltas instead of 160 B/row of materialized messages — the
-        # message upload was the measured bottleneck of the whole
-        # multi-height eval (the device sat idle behind H2D).
-        ok = None
-        key0, all_pk0, ed0 = specs[segments[0][0]].valset.batch_cache()
-        same_set = ed0.all() and all(
-            specs[si].valset.batch_cache()[0] == key0
-            for si, *_ in segments[1:]
-        )
-        if same_set:
-            all_idx = np.concatenate(
-                [np.asarray(seg[2], dtype=np.int32) for seg in segments]
-            )
-            f_t = getattr(v, "verify_rows_cached_templated", None)
-            if f_t is not None:
-                ok = f_t(
-                    key0, all_pk0, all_idx,
-                    np.concatenate(tpl_templates, axis=0),
-                    np.concatenate(tpl_idx_parts),
-                    np.concatenate(ts8_parts, axis=0),
-                    sg,
-                )
-            if ok is None:
-                f = getattr(v, "verify_rows_cached", None)
-                if f is not None:
-                    ok = f(key0, all_pk0, all_idx, mg, sg)
-        if ok is None:
-            ok = np.asarray(v.verify_batch(pk, mg, sg))  # ★ ONE device call, all heights
-        else:
-            ok = np.asarray(ok)
-    else:
-        # non-ed25519 validator keys verify serially via their own type
-        ok = np.zeros(len(ed_all), dtype=bool)
-        sub = np.nonzero(ed_all)[0]
-        if sub.size:
-            ok[sub] = np.asarray(v.verify_batch(pk[sub], mg[sub], sg[sub]))
-        off0 = 0
-        for si, idxs, vals_idx, powers, counted, n, ed in segments:
-            specs[si].valset._serial_fill_non_ed(
-                ok, specs[si].commit, idxs, vals_idx, mg, ed, mg_off=off0
-            )
-            off0 += n
+    if ok is None:
+        ok = _verify_packed(specs, rows, v, same_set=chain, declined=streamed)
+    ok = np.asarray(ok)
 
     off = 0
     for si, idxs, vals_idx, powers, counted, n, _ed in segments:
@@ -1088,3 +1136,56 @@ def verify_commits_batched(
         except Exception as e:
             results[si] = e
     return results
+
+
+def _verify_packed(
+    specs, rows: _SpecRows, v, same_set: bool, declined: bool
+) -> np.ndarray:
+    """Verdicts of a fully packed spec list as ONE eager batch: the
+    per-valset cached tables when every row is ed25519 of one set
+    (``same_set``: already known of the whole list, else asked of the
+    specs that passed their pre-checks; templated form first, unless
+    the provider has just declined these rows as groups), else the
+    generic kernel, with non-ed25519 rows verified serially by their
+    own key type."""
+    segments = rows.segments
+    pk = np.concatenate(rows.pk, axis=0)
+    mg = np.concatenate(rows.mg, axis=0)
+    sg = np.concatenate(rows.sg, axis=0)
+    ed_all = np.concatenate([seg[6] for seg in segments])
+    if not ed_all.all():
+        # non-ed25519 validator keys verify serially via their own type
+        ok = np.zeros(len(ed_all), dtype=bool)
+        sub = np.nonzero(ed_all)[0]
+        if sub.size:
+            ok[sub] = np.asarray(v.verify_batch(pk[sub], mg[sub], sg[sub]))
+        off0 = 0
+        for si, idxs, vals_idx, powers, counted, n, ed in segments:
+            specs[si].valset._serial_fill_non_ed(
+                ok, specs[si].commit, idxs, vals_idx, mg, ed, mg_off=off0
+            )
+            off0 += n
+        return ok
+    # When every spec checks against the SAME validator set (the
+    # fast-sync window / light-client sequential shape: the set is
+    # stable across heights), the whole cross-height batch rides
+    # the per-valset cached tables — per-window decompression and
+    # table builds are hoisted out entirely (eval 3). The templated
+    # form uploads one template pair per HEIGHT plus 12 B/row of
+    # deltas instead of 160 B/row of materialized messages — the
+    # message upload was the measured bottleneck of the whole
+    # multi-height eval (the device sat idle behind H2D).
+    ok = None
+    if same_set or rows.one_ed25519_set([seg[0] for seg in segments]):
+        key0, all_pk0, _ = specs[segments[0][0]].valset.batch_cache()
+        all_idx, templates, tmpl_idx, ts8 = rows.stacked(0, len(segments))
+        f_t = getattr(v, "verify_rows_cached_templated", None)
+        if f_t is not None and not declined:
+            ok = f_t(key0, all_pk0, all_idx, templates, tmpl_idx, ts8, sg)
+        if ok is None:
+            f = getattr(v, "verify_rows_cached", None)
+            if f is not None:
+                ok = f(key0, all_pk0, all_idx, mg, sg)
+    if ok is None:
+        return np.asarray(v.verify_batch(pk, mg, sg))  # ★ ONE device call, all heights
+    return np.asarray(ok)

@@ -334,6 +334,7 @@ class CryptoMetrics:
         ("seam_column_rows", "seam_column_rows"),
         ("seam_packed_rows", "seam_packed_rows"),
         ("seam_fixup_rows", "seam_fixup_rows"),
+        ("seam_overlapped_rows", "seam_overlapped_rows"),
         ("tabled_slot_rows", "tabled_slot_rows"),
         ("tabled_slot_pad", "tabled_slot_pad"),
         ("tabled_gathered_rows", "tabled_gathered_rows"),
@@ -355,6 +356,7 @@ class CryptoMetrics:
         self.seam_column_rows = reg(Counter("seam_column_rows_total", "Commit signature slots read into columns (once per Commit object).", namespace, sub))
         self.seam_packed_rows = reg(Counter("seam_packed_rows_total", "Commit rows packed from columns for a provider.", namespace, sub))
         self.seam_fixup_rows = reg(Counter("seam_fixup_rows_total", "Packed rows off the common shape: non-64-byte signature, non-ed25519 key, unknown address.", namespace, sub))
+        self.seam_overlapped_rows = reg(Counter("seam_overlapped_rows_total", "Packed rows a provider took as a later group of one call: packed while the device ran the launch before them.", namespace, sub))
         self.tabled_slot_rows = reg(Counter("tabled_slot_rows_total", "Rows verified in slot order: key tables read in place.", namespace, sub))
         self.tabled_slot_pad = reg(Counter("tabled_slot_pad_total", "Empty slots launched with the slot-order rows.", namespace, sub))
         self.tabled_gathered_rows = reg(Counter("tabled_gathered_rows_total", "Rows verified with their key tables gathered per row.", namespace, sub))

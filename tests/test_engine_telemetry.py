@@ -206,6 +206,38 @@ def test_pipeline_engine_stats_seam_counters():
             assert pv.stats()[k] == pv.engine_stats()["counters"][k] >= 0
 
 
+def test_pipeline_publishes_overlapped_rows():
+    """A bundle of submitted commits of one set reaches the inner
+    provider as row groups (crypto/batch.RowGroups): the rows of every
+    group after a bundle's first are ``seam_overlapped_rows``, beside
+    the other seam counts in engine_stats(), stats() and the metrics."""
+    from tendermint_tpu.crypto.keys import Ed25519PrivKey
+    from tendermint_tpu.crypto.pipeline import PipelinedVerifier, SigCache
+    from tendermint_tpu.lightserve import core
+    from tendermint_tpu.types.validator_set import CommitVerifySpec
+    from tendermint_tpu.utils.metrics import CryptoMetrics, Registry
+    from tests.seam_helpers import GroupStub
+
+    eds = [Ed25519PrivKey.from_secret(f"seam-{i}".encode()) for i in range(7)]
+    vals, bid, commit = _signed_commit(eds, absent=(2,))
+    stub = GroupStub(1)
+    with PipelinedVerifier(stub, cache=SigCache()) as pv:
+        start = pv.engine_stats()["counters"]
+        specs = [CommitVerifySpec(vals, "seam-chain", bid, 5, commit) for _ in range(6)]
+        assert core.verify_specs(specs, provider=pv) == [None] * 6
+        # however the dispatcher cut the six submits into bundles: the
+        # first group of each call is packed before anything runs
+        later = sum(rows for _, k, rows, _ in stub.of("take") if k)
+        assert later == 6 * (6 - len([t for t in stub.of("take") if not t[1]]))
+        now = pv.engine_stats()["counters"]
+        assert now["seam_overlapped_rows"] - start["seam_overlapped_rows"] == later
+        assert now["seam_packed_rows"] - start["seam_packed_rows"] == 36
+        assert pv.stats()["seam_overlapped_rows"] == now["seam_overlapped_rows"]
+        cm = CryptoMetrics(Registry())
+        cm.update(pv.stats())
+        assert cm.seam_overlapped_rows.value == now["seam_overlapped_rows"]
+
+
 def _row_case_warm_blocking(v, batch):
     pk, mg, sg = batch
     assert v.verify_batch(pk, mg, sg).all()

@@ -395,3 +395,63 @@ def test_verify_chain_forged_row_after_quorum_is_accepted():
     chain = [(forged(h, 4) if h == 3 else headers[h], vals[h]) for h in (2, 3, 4)]
     with pytest.raises(ErrInvalidCommitSignature, match="wrong signature #4"):
         verify_chain(CHAIN_ID, headers[1], vals[1], chain, PERIOD, now_ns=NOW)
+
+
+# -- the chain's commits taken a group at a time ----------------------------
+
+
+@pytest.mark.parametrize("case", sorted(_CHAIN_CASES))
+def test_verify_chain_overlapped_raises_what_the_eager_call_raises(case):
+    """verify_chain through a provider that takes row groups
+    (crypto/batch.RowGroups; tests/seam_helpers.GroupStub takes 2
+    commits at a time): the same first failing link, and every commit
+    after the first group packed after a launch was on its way."""
+    from tendermint_tpu.light.types import SignedHeader
+    from tendermint_tpu.light.verifier import verify_chain
+    from tendermint_tpu.types.block import Commit
+    from tests.seam_helpers import GroupStub, seam_counts, seam_grew
+
+    headers, vals = _mixed_chain(7, _CHAIN_CASES[case])
+    heights = range(2, 8)
+
+    def outcome(provider):
+        chain = [
+            (SignedHeader(headers[h].header, Commit(
+                h, 0, headers[h].commit.block_id, list(headers[h].commit.signatures)
+            )), vals[h])
+            for h in heights
+        ]
+        try:
+            verify_chain(CHAIN_ID, headers[1], vals[1], chain, PERIOD, now_ns=NOW, provider=provider)
+        except Exception as e:
+            return type(e).__name__, str(e)
+        return None
+
+    want = outcome(None)
+    assert (want is not None) == bool(_CHAIN_CASES[case])
+    stub = GroupStub(2)
+    before = seam_counts()
+    assert outcome(stub) == want
+    grew = seam_grew(before)
+    # six commits of 7 slots, one absent each: three groups of 12 rows
+    assert [e[0] for e in stub.events] == ["take", "launch"] * 3
+    assert [(t[2], t[3]) for t in stub.of("take")] == [(12, 14), (12, 28), (12, 42)]
+    assert grew["packed_rows"] == 36 and grew["overlapped_rows"] == 24
+
+
+@pytest.mark.parametrize("decline_at", [0, 2])
+def test_verify_chain_overlapped_provider_declines(decline_at):
+    """The provider answers None at the first or the last group: the
+    chain is verified all the same, every row once, on the generic
+    path."""
+    from tendermint_tpu.light.verifier import verify_chain
+    from tendermint_tpu.types.validator_set import ErrInvalidCommitSignature
+    from tests.seam_helpers import GroupStub
+
+    headers, vals = _mixed_chain(7, {6: [("forge", 3)]})
+    chain = [(headers[h], vals[h]) for h in range(2, 8)]
+    stub = GroupStub(2, decline_at=decline_at)
+    with pytest.raises(ErrInvalidCommitSignature, match="wrong signature #3"):
+        verify_chain(CHAIN_ID, headers[1], vals[1], chain, PERIOD, now_ns=NOW, provider=stub)
+    assert len(stub.of("launch")) == decline_at
+    assert stub.of("batch") == [("batch", 36)] and stub.row_counts.snapshot() == (0, 36)

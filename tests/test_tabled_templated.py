@@ -12,6 +12,7 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
+from tendermint_tpu.crypto.batch import RowGroups
 from tendermint_tpu.ops import ref_ed25519 as ref
 from tests.tabled_helpers import arrs, sign_rows
 
@@ -243,6 +244,138 @@ def test_slot_order_equals_gathered_equals_host(monkeypatch, name, n_commits, ab
         assert got is not None and got.shape == (n,)
         np.testing.assert_array_equal(got, want)
     assert m.row_counts.snapshot() == (3 * n, 0)  # empty slots are not device rows
+
+
+@pytest.fixture(scope="module")
+def slot_model():
+    """One model for the tests that launch 16 validators' slots: a
+    stage-2 program costs minutes on XLA:CPU, once a shape and model."""
+    from tendermint_tpu.models.verifier import VerifierModel
+
+    return VerifierModel(block_on_compile=True)
+
+
+class _CommitGroups(RowGroups):
+    """A crypto/batch.RowGroups over _templated_commits' commits, as
+    the verify seam's: each group's rows in commit order with the
+    group's own (commit, nil) template pairs. ``seen`` keeps, at each
+    take, what ``probe()`` read then; ``decline_at`` answers None."""
+
+    def __init__(self, templates, commits, absent, forged, probe, decline_at=None):
+        self.templates, self.commits = templates, commits
+        self.absent, self.forged = absent, forged
+        self.probe, self.decline_at = probe, decline_at
+        self.left, self.seen = len(commits), []
+
+    def take(self, count):
+        lo = len(self.commits) - self.left
+        hi = min(lo + count, len(self.commits))
+        self.left -= hi - lo
+        self.seen.append(self.probe())
+        if len(self.seen) - 1 == self.decline_at:
+            return None
+        idx, ti, t8, _mg, sg, _bad = _present_rows(
+            self.commits[lo:hi], self.absent[lo:hi],
+            [(c - lo, val) for c, val in self.forged if lo <= c < hi],
+        )
+        return idx, self.templates[2 * lo : 2 * hi], ti - 2 * lo, t8, sg
+
+
+def test_row_groups_equal_the_array_form(monkeypatch, slot_model):
+    """verify_rows_cached_templated fed a RowGroups against the same
+    rows as arrays: the same verdicts, the same launch shapes (entry
+    keys apart from the template pad: a group brings its own template
+    pairs), the same counters — and group k+1 is taken only after
+    launch k is dispatched. A source that declines mid-way leaves
+    nothing counted."""
+    from tendermint_tpu.models import verifier as vmod
+
+    monkeypatch.setattr(vmod, "MAX_DEVICE_ROWS", 64)  # 4 commits of 16 slots a launch
+    v, n_commits = 16, 9  # launches of 4, 4 and 1 commits
+    pks, templates, commits = _templated_commits(v, n_commits, seed=43)
+    absent = [[0], [], [7, 15], [], [3], [], [], [15], [1, 2]]
+    forged = [(0, 1), (2, 14), (4, 4), (5, 0), (7, 14), (8, 15)]  # first, middle and last group
+    idx, ti, t8, _mg, sg, bad = _present_rows(commits, absent, forged)
+    n = len(idx)
+
+    m, rows0 = slot_model, slot_model.row_counts.snapshot()[0]
+    key = b"row-groups"
+    launches, shapes = [], []
+    launch, entry = m._launch, m._tabled_bucket_entry
+    monkeypatch.setattr(m, "_launch", lambda *a: launches.append(1) or launch(*a))
+
+    def recorded_entry(*a, **kw):
+        ent = entry(*a, **kw)
+        shapes.extend(k for k, known in m._entries.items() if known is ent)
+        return ent
+
+    monkeypatch.setattr(m, "_tabled_bucket_entry", recorded_entry)
+
+    before = _tabled_counts()
+    ok_arrays = m.verify_rows_cached_templated(key, pks, idx, templates, ti, t8, sg)
+    _grew(before, slot_rows=n, slot_pad=9 * v - n)
+    np.testing.assert_array_equal(ok_arrays, ~bad)
+    shapes_arrays, launches_arrays = list(shapes), len(launches)
+    del shapes[:], launches[:]
+
+    groups = _CommitGroups(templates, commits, absent, forged, lambda: len(launches))
+    before = _tabled_counts()
+    ok_groups = m.verify_rows_cached_templated(key, pks, groups)
+    _grew(before, slot_rows=n, slot_pad=9 * v - n)
+    np.testing.assert_array_equal(ok_groups, ok_arrays)
+    assert groups.left == 0 and groups.seen == [0, 1, 2]  # launch k went before take k+1
+    assert len(launches) == launches_arrays == 3
+    drop_pad = lambda keys: [k[:3] + k[4:] for k in keys]
+    assert drop_pad(shapes) == drop_pad(shapes_arrays)
+    assert [k[:2] for k in shapes] == [("slots-tpl", 64)] * 2 + [("slots-tpl", 16)]
+    assert m.row_counts.snapshot() == (rows0 + 2 * n, 0)
+
+    declining = _CommitGroups(templates, commits, absent, forged, lambda: 0, decline_at=1)
+    before = _tabled_counts()
+    assert m.verify_rows_cached_templated(key, pks, declining) is None
+    _grew(before)
+    assert len(declining.seen) == 2 and declining.left == 1  # the rest is the seam's to pack
+    assert m.row_counts.snapshot() == (rows0 + 2 * n, 0)
+
+
+def test_row_groups_cold_tail_declines_before_any_launch(monkeypatch, slot_model):
+    """Without block_on_compile a chain whose LAST group's shape is
+    cold is declined before anything is dispatched: the tail's shape is
+    worked out from the commits left when the first group is taken, its
+    compile started, no second group taken. A chain whose shapes are
+    all warm runs."""
+    from tendermint_tpu.models import verifier as vmod
+
+    monkeypatch.setattr(vmod, "MAX_DEVICE_ROWS", 64)  # 4 commits of 16 slots a launch
+    v = 16
+    pks, templates, commits = _templated_commits(v, 9, seed=43)
+    none = [[]] * 9
+    m, key = slot_model, b"row-groups"
+    chain = lambda k, probe: _CommitGroups(templates[: 2 * k], commits[:k], none, [], probe)
+    # launches of 4, 4 and 1 commits: warm (a second or so after the test before)
+    assert m.verify_rows_cached_templated(key, pks, chain(9, int)).all()
+
+    monkeypatch.setattr(m, "block_on_compile", False)
+    launches, asked = [], []
+    launch = m._launch
+    monkeypatch.setattr(m, "_launch", lambda *a: launches.append(1) or launch(*a))
+    monkeypatch.setattr(
+        m, "_compile_tabled_async",
+        lambda ent, e, pad, src, slots=False: asked.append((pad, m._src_tpl_pad(src), slots)),
+    )
+    rows0 = m.row_counts.snapshot()
+    short = chain(6, lambda: len(launches))  # 4 and 2 commits: C = 2 has never run
+    before = _tabled_counts()
+    assert m.verify_rows_cached_templated(key, pks, short) is None
+    _grew(before)
+    assert launches == [] and short.seen == [0] and short.left == 2
+    assert asked == [(2 * v, 8, True)]  # 32 slots, 4 templates in the bucket of 8
+    assert m.row_counts.snapshot() == rows0
+
+    whole = chain(9, lambda: len(launches))
+    ok = m.verify_rows_cached_templated(key, pks, whole)
+    assert ok is not None and ok.all() and whole.seen == [0, 1, 2]
+    assert asked == [(2 * v, 8, True)]
 
 
 def test_templated_windowed_boundary_controls(monkeypatch):

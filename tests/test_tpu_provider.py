@@ -188,6 +188,26 @@ def test_tpu_provider_small_batch_routes_to_host():
     assert list(ok) == [True, False] and called["n"] == 0
 
 
+def test_tpu_provider_row_groups_below_the_gate_are_declined_unpacked():
+    """A crypto/batch.RowGroups states no row count: min_device_batch
+    is held against the slots its commits span, and a list under it is
+    declined (None: the seam's generic path, which routes to the host)
+    before a row is packed or the set's tables are looked up."""
+    from tendermint_tpu.crypto.batch import RowGroups
+
+    class Unpacked(RowGroups):
+        left = 3
+
+        def take(self, commits):
+            raise AssertionError("packed below the gate")
+
+    v = TPUBatchVerifier(block_on_compile=False, min_device_batch=64)
+    v._model.verify_rows_cached_templated = lambda *a, **kw: pytest.fail("reached the model")
+    assert v.takes_row_groups
+    pks = np.zeros((16, 32), dtype=np.uint8)  # 3 commits of 16 slots: 48 < 64
+    assert v.verify_rows_cached_templated(b"gate", pks, Unpacked()) is None
+
+
 def test_verify_commit_windows_large_batches(monkeypatch):
     """Batches beyond MAX_DEVICE_ROWS stream as full windows and a
     bucketed tail (VerifierModel._verify_windowed), the tally summed on

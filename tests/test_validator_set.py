@@ -932,3 +932,233 @@ def test_columns_die_with_the_commit():
         vs.verify_commit("test-chain", bid, 5, forged)
     assert SEAM_COUNTS.snapshot()["seam_column_rows"] - again["seam_column_rows"] == 6
     vs.verify_commit("test-chain", bid, 5, fresh)  # the original stands
+
+
+# -- the overlapped seam: a spec list's rows taken a group at a time --------
+#
+# verify_commits_batched hands a same-set chain to a provider that takes
+# row groups as a lazy source (crypto/batch.RowGroups): group k+1 is
+# packed only when the provider comes back for it, launch k dispatched.
+# tests/seam_helpers.GroupStub is such a provider on the host; what is
+# pinned is counts and orders of events, and that every result is the
+# direct call's.
+
+from tendermint_tpu.types.validator_set import CommitVerifySpec, verify_commits_batched
+from tests.seam_helpers import GroupStub, seam_counts, seam_grew
+
+_PER = 3  # commits the stub takes at a time
+
+
+def _chain_specs(n, faults=None, vs=None, by_addr=None):
+    """n full-mode specs of one 7-validator set (power 10 each: the
+    quorum falls on row 4, rows 5 and 6 are never visited).
+    ``faults[j]``: ("forge", i) a zeroed signature in slot i,
+    ("height",) a spec that asks for another height than its commit's,
+    ("short", i) slot i's signature cut to 63 bytes."""
+    if vs is None:
+        vs, by_addr = make_vals([10] * 7)
+    specs = []
+    for j in range(n):
+        fault = (faults or {}).get(j, ())
+        commit, bid = make_commit(
+            vs, by_addr, height=5 + j, absent_idx={j % 7} if j % 2 else None,
+            bad_idx={fault[1]} if fault[:1] == ("forge",) else None,
+        )
+        if fault[:1] == ("short",):
+            cs = commit.signatures[fault[1]]
+            commit.signatures[fault[1]] = CommitSig(
+                cs.block_id_flag, cs.validator_address, cs.timestamp_ns, cs.signature[:63]
+            )
+        height = 5 + j + (100 if fault == ("height",) else 0)
+        specs.append(CommitVerifySpec(vs, "test-chain", bid, height, commit))
+    return specs
+
+
+def _fresh(specs):
+    """The same specs over Commit objects nothing has read yet."""
+    return [
+        CommitVerifySpec(
+            s.valset, s.chain_id, s.block_id, s.height,
+            Commit(s.commit.height, s.commit.round, s.commit.block_id, list(s.commit.signatures)),
+            mode=s.mode, trust_level=s.trust_level,
+        )
+        for s in specs
+    ]
+
+
+def _direct(spec):
+    """What the direct method call makes of the spec: None or
+    (exception type, text)."""
+    (s,) = _fresh([spec])
+    if s.mode == "trusting":
+        return _outcome(
+            s.valset.verify_commit_trusting, s.chain_id, s.block_id, s.height, s.commit, s.trust_level
+        )
+    return _outcome(s.valset.verify_commit, s.chain_id, s.block_id, s.height, s.commit)
+
+
+def _texts(results):
+    return [None if e is None else (type(e).__name__, str(e)) for e in results]
+
+
+def _rows_of(specs, want):
+    """Present rows of the specs that pass their pre-checks."""
+    return [
+        sum(not cs.absent_() for cs in s.commit.signatures)
+        for s, w in zip(specs, want)
+        if w is None or w[0] != "ErrInvalidCommit"
+    ]
+
+
+_OVERLAP_CASES = {
+    "accepted chain": (9, {}),
+    "forged before quorum, first group": (8, {1: ("forge", 2)}),
+    "forged before quorum, middle group": (8, {4: ("forge", 2)}),
+    "forged before quorum, last group": (8, {7: ("forge", 2)}),
+    "forged after quorum, first group": (8, {0: ("forge", 6)}),
+    "forged after quorum, middle group": (8, {3: ("forge", 6)}),
+    "forged after quorum, last group": (8, {6: ("forge", 6)}),
+    "pre-checks fail mid-list": (8, {4: ("height",)}),
+    "a whole group fails its pre-checks": (8, {3: ("height",), 4: ("height",), 5: ("height",)}),
+    "not a multiple of the group": (7, {2: ("forge", 0), 6: ("forge", 1)}),
+    "a list of one": (1, {}),
+    "a list of one, forged": (1, {0: ("forge", 3)}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_OVERLAP_CASES))
+def test_overlapped_seam_results_are_the_direct_calls(case):
+    """(a) and (b): one result a spec, the direct call's exception type
+    and text; group k+1 has its columns read only after launch k; the
+    counter reads the rows of groups 2..n."""
+    n, faults = _OVERLAP_CASES[case]
+    specs = _chain_specs(n, faults)
+    want = [_direct(s) for s in specs]
+    assert [w is not None for w in want] == [
+        j in faults and faults[j] != ("forge", 6) for j in range(n)
+    ]
+
+    stub = GroupStub(_PER)
+    before = seam_counts()
+    got = verify_commits_batched(_fresh(specs), provider=stub)
+    grew = seam_grew(before)
+    assert _texts(got) == want
+
+    groups = -(-n // _PER)
+    takes = stub.of("take")
+    assert [e[0] for e in stub.events] == ["take", "launch"] * groups  # no eager call
+    # when group k is handed over, only the commits up to its end have
+    # had columns() read: the launches before it were on their way
+    assert [t[3] for t in takes] == [7 * min((k + 1) * _PER, n) for k in range(groups)]
+    rows = _rows_of(specs, want)
+    assert sum(t[2] for t in takes) == sum(rows) == grew["packed_rows"]
+    assert grew["overlapped_rows"] == sum(t[2] for t in takes[1:])
+    assert grew["column_rows"] == 7 * n and grew["fixup_rows"] == 0
+    assert stub.row_counts.snapshot() == (sum(rows), 0)
+
+
+@pytest.mark.parametrize("decline_at", [0, 1, 2])
+def test_overlapped_seam_provider_declines(decline_at):
+    """(c): the provider answers None at group 0 or later; the generic
+    path sees every row exactly once, the results are the direct
+    calls', and no row is counted twice."""
+    faults = {1: ("forge", 2), 4: ("height",), 7: ("forge", 6)}
+    specs = _chain_specs(8, faults)
+    want = [_direct(s) for s in specs]
+    total = sum(_rows_of(specs, want))
+
+    stub = GroupStub(_PER, decline_at=decline_at)
+    before = seam_counts()
+    got = verify_commits_batched(_fresh(specs), provider=stub)
+    assert _texts(got) == want
+    assert len(stub.of("take")) == decline_at + 1 and len(stub.of("launch")) == decline_at
+    # the rest is packed by the seam, then ONE eager batch: the cached
+    # path's materialized form, then the generic kernel — not the
+    # templated form the provider has just declined
+    assert stub.events[2 * decline_at + 1 :] == [("rows", total), ("batch", total)]
+    assert stub.row_counts.snapshot() == (0, total)
+    grew = seam_grew(before)
+    assert grew["packed_rows"] == total and grew["column_rows"] == 7 * 8
+
+
+def test_overlapped_seam_short_signature_met_mid_list():
+    """A non-64-byte signature is seen only when its commit is packed:
+    the source declines at that group and the whole list takes the
+    path of lists with rows off the common shape."""
+    specs = _chain_specs(8, {4: ("short", 2), 6: ("forge", 1)})
+    want = [_direct(s) for s in specs]
+    assert want[4] == ("ErrInvalidCommitSignature", want[4][1]) and "#2" in want[4][1]
+    stub = GroupStub(_PER)
+    before = seam_counts()
+    assert _texts(verify_commits_batched(_fresh(specs), provider=stub)) == want
+    total = sum(_rows_of(specs, want))
+    assert [t[2] for t in stub.of("take")] == [sum(_rows_of(specs[:3], want[:3])), None]
+    assert stub.of("batch") == [("batch", total - 1)]  # the short row verifies by itself
+    assert stub.row_counts.snapshot() == (0, total - 1)
+    assert seam_grew(before)["fixup_rows"] == 1
+
+
+def _two_sets():
+    vs, by_addr = make_vals([10] * 7)
+    privs = [Ed25519PrivKey.from_secret(f"other{i}".encode()) for i in range(7)]
+    other = ValidatorSet([Validator(p.pub_key(), 10) for p in privs])
+    specs = _chain_specs(4, {1: ("forge", 2)}, vs, by_addr) + _chain_specs(
+        4, {2: ("forge", 0)}, other, {p.pub_key().address(): p for p in privs}
+    )
+    return specs, [("batch", sum(_rows_of(specs, [None] * 8)))]
+
+
+def _with_trusting_spec():
+    specs = _chain_specs(5, {3: ("forge", 1)})
+    s = specs[2]
+    specs[2] = CommitVerifySpec(
+        s.valset, s.chain_id, s.block_id, s.height, s.commit,
+        mode="trusting", trust_level=Fraction(1, 3),
+    )
+    total = sum(_rows_of(specs, [None] * 5))
+    return specs, [("arrays", total), ("rows", total), ("batch", total)]
+
+
+def _with_secp_key():
+    from tendermint_tpu.crypto.secp256k1 import Secp256k1PrivKey
+
+    privs = [Ed25519PrivKey.from_secret(f"val{i}".encode()) for i in range(6)]
+    privs.append(Secp256k1PrivKey.from_secret(b"seam-secp"))
+    vs = ValidatorSet([Validator(p.pub_key(), 10) for p in privs])
+    specs = _chain_specs(4, {1: ("forge", 0)}, vs, {p.pub_key().address(): p for p in privs})
+    secp_rows = sum(
+        not cs.absent_() and len(vs.validators[i].pub_key.bytes()) != 32
+        for s in specs for i, cs in enumerate(s.commit.signatures)
+    )
+    return specs, [("batch", sum(_rows_of(specs, [None] * 4)) - secp_rows)]
+
+
+@pytest.mark.parametrize(
+    "build", [_two_sets, _with_trusting_spec, _with_secp_key],
+    ids=["mixed sets", "a trusting spec", "a non-ed25519 key"],
+)
+def test_lists_that_are_no_same_set_chain_take_the_eager_path(build):
+    """(d): nothing is taken as groups, nothing counts as overlapped,
+    the eager calls are the ones made before there were groups."""
+    specs, eager_calls = build()
+    want = [_direct(s) for s in specs]
+    assert any(w is not None for w in want)
+    stub = GroupStub(_PER)
+    before = seam_counts()
+    assert _texts(verify_commits_batched(_fresh(specs), provider=stub)) == want
+    assert stub.events == eager_calls
+    assert seam_grew(before)["overlapped_rows"] == 0
+
+
+def test_provider_that_takes_no_groups_gets_one_eager_batch():
+    """A provider without ``takes_row_groups`` (the CPU provider, a
+    pipeline, a mesh router) is handed arrays, as before."""
+    specs = _chain_specs(7, {5: ("forge", 2)})
+    want = [_direct(s) for s in specs]
+    stub = GroupStub(_PER)
+    stub.takes_row_groups = False
+    before = seam_counts()
+    assert _texts(verify_commits_batched(_fresh(specs), provider=stub)) == want
+    total = sum(_rows_of(specs, want))
+    assert stub.events == [("arrays", total), ("rows", total), ("batch", total)]
+    assert seam_grew(before)["overlapped_rows"] == 0
