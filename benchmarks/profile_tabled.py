@@ -54,7 +54,7 @@ def main():
     assert ok is not None and ok.all(), "tabled path must verify the batch"
     print(f"cold (tables+compile+run): {time.perf_counter()-t0:.1f}s", file=sys.stderr)
 
-    e = model._valset_tables[key]
+    e = model._tables_entry(key, pks)  # the set's table operand: the key pool as it lies
     s1, s2, s3 = model._table_stage_fns()[:3]
     mg_d = jax.device_put(jnp.asarray(msgs))
     sg_d = jax.device_put(jnp.asarray(sigs))

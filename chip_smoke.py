@@ -223,19 +223,10 @@ def provider_stats(prov) -> dict:
         return collect_engine_stats([pv])
 
 
-def table_bytes(model) -> int:
-    total = 0
-    for e in model._valset_tables.values():
-        for a in (e.tables, e.a_ok, e.pk_dev, *(e.shards or ())):
-            if a is not None:
-                total += int(a.nbytes)
-    return total
-
-
 def check_device_did_the_work(prov, errors: ErrorLog, rows0, submitted: int, want_kind: str):
     stats = provider_stats(prov)
     say(f"engines: {engine_report(stats)}")
-    say(f"table bytes resident: {table_bytes(prov.model)}")
+    say(f"table bytes resident: {prov.model.table_bytes()}")
     dev, host = prov.row_counts.snapshot()
     check(
         any(k[0] == want_kind and e.ready for k, e in prov.model._entries.items()),

@@ -21,7 +21,7 @@ Select via config ``crypto.provider`` or ``set_default_provider``.
 from __future__ import annotations
 
 import threading
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -88,11 +88,14 @@ class NamedCounts:
 # ``overlapped_rows`` are the packed rows a provider pulled from a
 # RowGroups after an earlier group of the same call — packed while the
 # device ran the launch before them; 7/8 of a 128-commit chain of 1,024
-# slots, 0 for one commit. The seam packs before a provider is chosen
-# (types/ knows none), so its counts are the process's, as
-# crypto/merkle.device_stats() are.
+# slots, 0 for one commit. ``multiset_rows`` are the rows of spec lists
+# over more than one validator set that the cached tables answered: a
+# window that straddled a set change and still rode the tables. The
+# seam packs before a provider is chosen (types/ knows none), so its
+# counts are the process's, as crypto/merkle.device_stats() are.
 SEAM_COUNTS = NamedCounts(
-    "seam", ("column_rows", "packed_rows", "fixup_rows", "overlapped_rows")
+    "seam",
+    ("column_rows", "packed_rows", "fixup_rows", "overlapped_rows", "multiset_rows"),
 )
 
 # Which table operand the cached-table path took (models/verifier.py
@@ -102,13 +105,43 @@ SEAM_COUNTS = NamedCounts(
 # or unordered batches, a mesh, sharded tables). Process-wide too.
 TABLED_COUNTS = NamedCounts("tabled", ("slot_rows", "slot_pad", "gathered_rows"))
 
+# The key pool behind those tables (models/verifier._KeyPool): one key
+# table a validator key, whichever sets it appears in. ``keys_built``
+# keys whose table the device built, ``keys_loaded`` keys read back from
+# the table files, ``keys_reused`` keys a call asked for and found
+# pooled, ``keys_evicted`` least-recently-used keys dropped under the
+# byte bound, ``slabs`` launches whose table operand was gathered from
+# the pool (0 for a set that is the pool as it lies) and
+# ``slab_columns`` the columns those gathers copied. Process-wide.
+TABLE_COUNTS = NamedCounts(
+    "table",
+    ("keys_built", "keys_loaded", "keys_reused", "keys_evicted", "slabs", "slab_columns"),
+)
+
+
+class GroupKeys(NamedTuple):
+    """The distinct ed25519 keys some whole commits are checked
+    against: ``pubkeys`` (U, 32) u8, in an order that keeps every one
+    of those commits' own validator order (the seam gives address
+    order), and ``digest``, which names exactly this matrix (a
+    ValidatorSet.batch_cache() key for one set, a hash over the sets'
+    keys for several) — what a provider memoises the keys' tables
+    under."""
+
+    digest: bytes
+    pubkeys: np.ndarray
+
 
 class RowGroups:
-    """The rows of whole commits of ONE all-ed25519 validator set, in
-    commit order, packed when a provider asks for them (the seam's
-    source is types/validator_set._SpecRows). It stands in for the row
-    arguments of ``verify_rows_cached_templated`` with a provider whose
-    ``takes_row_groups`` is true.
+    """The rows of whole commits of all-ed25519 validator sets — one
+    set or a set a commit — in commit order, packed when a provider
+    asks for them (the seam's source is types/validator_set._SpecRows).
+    It stands in for the row arguments of
+    ``verify_rows_cached_templated`` with a provider whose
+    ``takes_row_groups`` is true. The call's ``valset_key`` and
+    ``all_pubkeys`` are then the FIRST commit's set: what the size gate
+    is held against, and what a group's rows index where ``keys`` gives
+    None; each group's own keys come from ``keys``.
 
     The provider takes a launch's worth of commits, dispatches the
     launch, and only then takes the next: device dispatch is
@@ -120,12 +153,23 @@ class RowGroups:
 
     left = 0  # whole commits not yet taken
 
+    def keys(self, commits: int) -> Optional[GroupKeys]:
+        """The distinct keys of the next ``commits`` commits' sets
+        (fewer at the end), nothing packed: what ``take(commits)``'s
+        row_idx will index. A provider asks before it takes, so that a
+        group whose sets together pass a table bucket is taken as fewer
+        commits. None: the call's own ``all_pubkeys``, every commit of
+        the one set."""
+        return None
+
     def take(self, commits: int):
         """Pack the next ``commits`` commits (fewer at the end):
         (row_idx (n,) i32, templates (2k, 160) u8, tmpl_idx (n,) i32,
-        ts8 (n, 8) u8, sigs (n, 64) u8), the templates the group's own.
-        None when a row is off the common shape (a non-64-byte
-        signature): the provider answers None in turn."""
+        ts8 (n, 8) u8, sigs (n, 64) u8), the templates the group's own
+        and row_idx each row's place in ``keys(commits).pubkeys``, a
+        commit's rows one increasing run. None when a row is off the
+        common shape (a non-64-byte signature): the provider answers
+        None in turn."""
         raise NotImplementedError
 
 

@@ -54,7 +54,7 @@ import time
 import numpy as np
 
 from tendermint_tpu.crypto.batch import (
-    SEAM_COUNTS, TABLED_COUNTS, BatchVerifier, CPUBatchVerifier,
+    SEAM_COUNTS, TABLE_COUNTS, TABLED_COUNTS, BatchVerifier, CPUBatchVerifier,
 )
 from tendermint_tpu.utils import faultinject as faults
 from tendermint_tpu.utils import trace
@@ -627,6 +627,7 @@ class PipelinedVerifier(BatchVerifier):
             s[f"cache_{k}"] = v
         s.update(SEAM_COUNTS.snapshot())
         s.update(TABLED_COUNTS.snapshot())
+        s.update(TABLE_COUNTS.snapshot())
         return s
 
     def engine_stats(self) -> Dict[str, object]:
@@ -661,9 +662,11 @@ class PipelinedVerifier(BatchVerifier):
         counters["cache_misses"] = cache["misses"]
         # the verify seam packs before it reaches any provider, so its
         # counts are the process's (crypto/batch.SEAM_COUNTS), as are the
-        # cached-table path's slot-order / gathered row counts
+        # cached-table path's slot-order / gathered row counts and the
+        # key pool's
         counters.update(SEAM_COUNTS.snapshot())
         counters.update(TABLED_COUNTS.snapshot())
+        counters.update(TABLE_COUNTS.snapshot())
         buckets: Dict[str, dict] = {}
         breakers: Dict[str, dict] = {}
         model = self.model  # the wrapped VerifierModel (None for CPU inner)
@@ -683,6 +686,9 @@ class PipelinedVerifier(BatchVerifier):
                 for key, e in dict(tables).items():
                     label = key.hex()[:12] if isinstance(key, bytes) else str(key)
                     buckets[f"tables:{label}"] = bucket_entry(e)
+            pool = getattr(model, "key_pool", None)
+            if pool is not None and (pool.ready or pool.compiling or pool.failed):
+                buckets["tables:pool"] = bucket_entry(pool)
             breakers = breaker_view(getattr(model, "tables_breaker", None))
         return {
             "engine": "pipeline",

@@ -186,15 +186,19 @@ def save(stage: str, args: Tuple[Any, ...], compiled) -> None:
         _log.info("aot save failed", stage=stage, err=repr(ex))
 
 
-# -- built valset tables (pure data) -----------------------------------
+# -- key tables ----------------------------------------------------------------
 #
-# The split tables a valset build produces are deterministic int32
-# arrays (~12KB/validator). Persisting THEM — not just the build
-# executable — lets a restarting node device_put ~120MB of data instead
+# The split tables a build produces are deterministic int32 arrays
+# (~30KB/validator key). Persisting THEM — not just the build
+# executable — lets a restarting node device_put ~315MB of data instead
 # of loading a ~200MB t-build executable AND re-running the build
 # (measured 15.9s load + ~14-30s run at 10k validators on a v5e).
-# Keyed by the code digest only: tables are device-independent data,
-# so a CPU-built table is valid on TPU and vice versa.
+# One file a BUILD DISPATCH, rows keyed by the pubkeys stored beside
+# them: a key's row is found whichever file holds it and whichever set
+# asks, so a set that shares all but one key with the last one writes
+# one row, not a second copy of the set. Named by the code digest only:
+# tables are device-independent data, so a CPU-built table is valid on
+# TPU and vice versa.
 
 _TABLES_KEEP = int(os.environ.get("TM_TABLES_CACHE_KEEP", "4"))
 
@@ -215,72 +219,102 @@ def _code_digest_cached() -> str:
     return _CODE_DIGEST
 
 
-def _tables_path(valset_key: bytes, v: int, dir_path: Optional[str] = None) -> str:
-    return os.path.join(
-        dir_path or tables_dir(),
-        f"{_code_digest_cached()}-{valset_key.hex()[:32]}-{v}.npz",
-    )
+def key_rows(pubkeys) -> list:
+    """The rows of a C-contiguous (n, 32) u8 key matrix as bytes."""
+    raw = pubkeys.tobytes()
+    return [raw[i : i + 32] for i in range(0, len(raw), 32)]
 
 
-def load_tables(valset_key: bytes, v: int, pk_digest: bytes):
-    """(tables, a_ok) numpy arrays for this valset, or None.
+# file -> the keys it holds, read once a process: a file is named by
+# the hash of its keys, so what a name holds never changes
+_FILE_KEYS: Dict[str, Dict[bytes, int]] = {}
 
-    pk_digest = sha256 of the (padded) pubkey matrix the caller is about
-    to verify against. The stored digest must match: a stale blob under
-    a reused key, a truncated-hex collision, or a tampered cache file
-    would otherwise silently substitute wrong precomputed tables into
+
+def load_tables(pubkeys):
+    """Rows of the table files for the keys ``pubkeys`` (n, 32) u8:
+    (found (n,) bool, tables (n, ...) int32, a_ok (n,) bool) with the
+    rows not found left zero, or None when no file holds any of them.
+
+    A row is taken only from under its own 32 key bytes, stored beside
+    it: a stale or foreign file cannot put another key's tables into
     signature verification — a consensus-safety issue, not a perf one."""
     if not enabled():
         return None
     try:
         import numpy as np
 
-        p = _tables_path(valset_key, v)
-        if not os.path.exists(p):
-            return None
-        with np.load(p) as z:
-            tables, a_ok = z["tables"], z["a_ok"]
-            stored = z["pk_sha"].tobytes() if "pk_sha" in z else b""
-        if stored != pk_digest:
-            _log.info("tables pubkey digest mismatch (rebuilding)",
-                      path=os.path.basename(p))
-            return None
-        if tables.shape[0] < v:  # truncated/foreign blob
-            return None
-        try:
-            os.utime(p)  # LRU recency for _prune_tables
-        except OSError:
-            pass  # read-only cache dir (e.g. baked into an image): the
-            # load itself succeeded and that's what matters
-        return tables, a_ok
+        d = tables_dir()
+        prefix = _code_digest_cached() + "-"
+        files = sorted(
+            (os.path.join(d, f) for f in os.listdir(d)
+             if f.startswith(prefix) and f.endswith(".npz")),
+            key=os.path.getmtime, reverse=True,
+        ) if os.path.isdir(d) else []
+        want = {}
+        for i, k in enumerate(key_rows(np.ascontiguousarray(pubkeys, dtype=np.uint8))):
+            want.setdefault(k, []).append(i)
+        found = np.zeros(len(pubkeys), dtype=bool)
+        tables = a_ok = None
+        for p in files:
+            if not want:
+                break
+            try:
+                stored = _FILE_KEYS.get(p)
+                if stored is not None and want.keys().isdisjoint(stored):
+                    continue
+                with np.load(p) as z:
+                    if stored is None:
+                        pk = np.ascontiguousarray(z["pk"], dtype=np.uint8)
+                        stored = _FILE_KEYS[p] = {k: r for r, k in enumerate(key_rows(pk))}
+                    hits = [k for k in want if k in stored]
+                    if not hits:
+                        continue
+                    f_tables, f_a_ok = z["tables"], z["a_ok"]
+            except Exception as ex:
+                _log.info("tables load failed (skipping file)",
+                          path=os.path.basename(p), err=repr(ex))
+                continue
+            if f_tables.shape[0] != len(stored) or f_a_ok.shape[0] != len(stored):
+                continue  # truncated/foreign blob
+            if tables is None:
+                tables = np.zeros((len(pubkeys),) + f_tables.shape[1:], dtype=f_tables.dtype)
+                a_ok = np.zeros(len(pubkeys), dtype=bool)
+            for k in hits:
+                at, r = want.pop(k), stored[k]
+                tables[at], a_ok[at], found[at] = f_tables[r], f_a_ok[r], True
+            try:
+                os.utime(p)  # LRU recency for _prune_tables
+            except OSError:
+                pass  # read-only cache dir (e.g. baked into an image): the
+                # load itself succeeded and that's what matters
+        return (found, tables, a_ok) if tables is not None else None
     except Exception as ex:
         _log.info("tables load failed (rebuilding)", err=repr(ex))
         return None
 
 
-def save_tables(
-    valset_key: bytes, tables, a_ok, pk_digest: bytes,
-    dir_path: Optional[str] = None,
-) -> None:
-    """Best-effort atomic persist of built tables (uncompressed: field
-    elements don't compress and savez_compressed is ~10x slower). The
-    pubkey digest is stored alongside so load_tables can refuse a blob
-    that doesn't belong to the pubkeys being verified. dir_path lets an
-    async builder pin the directory it resolved at BUILD time (the env
-    var may point elsewhere by the time a background thread saves)."""
+def save_tables(pubkeys, tables, a_ok, dir_path: Optional[str] = None) -> None:
+    """Best-effort atomic persist of one build's rows (uncompressed:
+    field elements don't compress and savez_compressed is ~10x slower):
+    the keys ``pubkeys`` (k, 32) u8 and their k rows of tables and a_ok,
+    so load_tables finds each row under its key. dir_path lets an async
+    builder pin the directory it resolved at BUILD time (the env var
+    may point elsewhere by the time a background thread saves)."""
     if not enabled():
         return
     try:
+        import hashlib
+
         import numpy as np
 
-        os.makedirs(dir_path or tables_dir(), exist_ok=True)
-        p = _tables_path(valset_key, int(a_ok.shape[0]), dir_path)
+        pk = np.ascontiguousarray(pubkeys, dtype=np.uint8)
+        d = dir_path or tables_dir()
+        os.makedirs(d, exist_ok=True)
+        name = hashlib.sha256(pk.tobytes()).hexdigest()[:32]
+        p = os.path.join(d, f"{_code_digest_cached()}-{name}-{pk.shape[0]}.npz")
         tmp = p + f".tmp.{os.getpid()}"
         with open(tmp, "wb") as fh:
-            np.savez(
-                fh, tables=np.asarray(tables), a_ok=np.asarray(a_ok),
-                pk_sha=np.frombuffer(pk_digest, dtype=np.uint8),
-            )
+            np.savez(fh, pk=pk, tables=np.asarray(tables), a_ok=np.asarray(a_ok))
         os.replace(tmp, p)
         _prune_tables()
     except Exception as ex:
@@ -288,20 +322,26 @@ def save_tables(
 
 
 def _prune_tables() -> None:
-    """Bound the on-disk table cache to the newest _TABLES_KEEP files
-    (a 10k-valset file is ~120MB; an unbounded dir would eat the disk
-    across valset changes)."""
+    """Bound the on-disk table cache to the bytes of _TABLES_KEEP files
+    of the largest build it holds, newest first (a 10k-valset file is
+    ~315MB; an unbounded dir would eat the disk across valset changes,
+    and a count alone would let a few one-key files of later changes
+    push the set's own file out)."""
     try:
         d = tables_dir()
         files = [
             os.path.join(d, f) for f in os.listdir(d) if f.endswith(".npz")
         ]
         files.sort(key=os.path.getmtime, reverse=True)
-        for p in files[_TABLES_KEEP:]:
-            try:
-                os.remove(p)
-            except OSError:
-                pass
+        sizes = [os.path.getsize(p) for p in files]
+        room = _TABLES_KEEP * max(sizes, default=0)
+        for p, size in zip(files, sizes):
+            room -= size
+            if room < 0:
+                try:
+                    os.remove(p)
+                except OSError:
+                    pass
     except Exception:
         pass
 

@@ -11,17 +11,20 @@ Two verify pipelines share the buckets:
 
 - the GENERIC staged pipeline (prepare/scan/finish) for arbitrary
   (pubkey, msg, sig) batches;
-- the per-valset CACHED-TABLE pipeline (``verify_rows_cached``):
-  validator pubkeys are stable across heights, so affine-cached split
-  tables of each key (built once per valset digest, LRU of
-  MAX_CACHED_VALSETS, device-resident) remove decompression, the
-  per-row table build, and 7/8 of the scan doublings from the
-  per-commit program. On one device, whole commits of a set they
-  mostly fill run in SLOT ORDER (``plan_slots``): each row goes to its
-  validator's slot and stage 2 reads the tables where they lie; sparse
-  or unordered batches, a mesh and sharded tables GATHER each row's
-  table by validator index. Streams past MAX_DEVICE_ROWS as in-flight
-  launches; ``register_valset`` pre-builds at node start.
+- the CACHED-TABLE pipeline (``verify_rows_cached``): validator
+  pubkeys are stable across heights, so affine-cached split tables of
+  each key (built once per KEY into the device-resident key pool,
+  ``_KeyPool``, whichever sets the key appears in; least-recently-used
+  keys go under MAX_TABLE_BYTES) remove decompression, the per-row
+  table build, and 7/8 of the scan doublings from the per-commit
+  program. A launch's table operand is the pool's columns of the keys
+  its commits are checked against: the pool as it lies where a set is
+  all of it, else a slab gathered on the device. On one device, whole
+  commits of sets they mostly fill run in SLOT ORDER (``plan_slots``):
+  each row goes to its key's slot and stage 2 reads the tables where
+  they lie; sparse or unordered batches, a mesh and sharded tables
+  GATHER each row's table by index. Streams past MAX_DEVICE_ROWS as
+  in-flight launches; ``register_valset`` pre-builds at node start.
 
 Two compile disciplines:
 
@@ -48,6 +51,7 @@ from __future__ import annotations
 import os
 import threading
 import time
+from collections import OrderedDict
 from typing import Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
@@ -56,6 +60,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
+from tendermint_tpu.ops import curve as ops_curve
 from tendermint_tpu.ops import ed25519 as ops_ed
 from tendermint_tpu.parallel import pad_to_multiple
 from tendermint_tpu.parallel.mesh import BATCH_AXIS
@@ -154,11 +159,9 @@ class _Entry:
         self.compile_s: Optional[float] = None
 
 
-# Per-valset cached tables kept device-resident (LRU): ~30KB/validator
-# (SPLITS*8 affine-cached points), so a 10k set is ~315MB of HBM per
-# entry. Two entries cover the live pattern (current set + next set
-# around a validator-set change).
-MAX_CACHED_VALSETS = 2
+# One key's cached tables: SPLITS*8 affine-cached points of 3*LIMBS
+# int32 limbs, ~30 KB — a 10k set is ~315 MB of HBM.
+TABLE_KEY_BYTES = 4 * ops_curve.SPLITS * 8 * 3 * ops_curve.F.LIMBS
 
 # Slot order against gathered order (plan_slots): a batch goes to its
 # validators' slots when the slots it would launch are at most this
@@ -191,6 +194,13 @@ MAX_TABLED_VALSET = MAX_DEVICE_ROWS
 # the live cap from the mesh size (N=1 reproduces this constant
 # exactly). Beyond the cap the generic pipeline takes over.
 MAX_SHARDED_VALSET = 1 << 16
+
+# What the device keeps of key tables, in bytes: the same ~2 GB, however
+# the keys are spread over sets — as many pooled keys as the largest
+# set has (_KeyPool drops the least recently used beyond it), and the
+# whole-set entries of a mesh or of sets past MAX_TABLED_VALSET
+# together (_tables_entry).
+MAX_TABLE_BYTES = MAX_SHARDED_VALSET * TABLE_KEY_BYTES
 
 
 class SlotPlan(NamedTuple):
@@ -282,7 +292,346 @@ class _TablesEntry:
         # deterministic failure on every verify
         self.failed = False
         self.build_s: Optional[float] = None
-        self.source: Optional[str] = None  # "build" | "disk"
+        self.source: Optional[str] = None  # "build" | "disk" | "pool"
+
+
+def _pool_view(tables, a_ok, pk_dev, v: int) -> _TablesEntry:
+    """A launch's table operand out of the key pool, in the form the
+    stages take a whole-set entry in."""
+    e = _TablesEntry(v)
+    e.tables, e.a_ok, e.pk_dev = tables, a_ok, pk_dev
+    e.ready, e.source = True, "pool"
+    return e
+
+
+class _KeyPool:
+    """The device's key tables of one unmeshed model: one column a
+    validator KEY, built once whichever sets the key appears in.
+
+    ``view`` answers a GroupKeys (the distinct keys of a launch's
+    commits, crypto/batch.py) with the launch's table operand. Keys the
+    pool lacks are read from the table files or built — every missing
+    key of the call in ONE build dispatch, padded to a batch bucket —
+    and appended; their columns are memoised under the digest, so a set
+    or a chain seen before costs one dictionary lookup. Where the keys
+    are columns 0..U-1 of a pool exactly their bucket wide — a node's
+    one set, built whole — the operand is the pool's arrays as they lie
+    and nothing is copied; else it is a slab of bucket(U) columns
+    gathered on the device (ops_ed.table_slab: ~30 KB a column), so a
+    set that shares all but one key with the last builds one row and
+    launches the shapes of a set its size. The pool keeps a set built
+    whole in the form the stages read, (P, SPLITS, 8, 3*LIMBS); once a
+    second build joins it, tables lie one 7,680-int32 row a key, the
+    form a slab is gathered from without laying the pool out again
+    (ops_ed.table_slab).
+
+    Bound: MAX_TABLE_BYTES of columns. Beyond it the least recently
+    used keys go and the rest move up (one device gather; the memo is
+    dropped) — never a key of the call that asks: a launch's operand is
+    gathered under the same lock, and a gathered or adopted array is
+    immutable, so a launch in flight keeps what it was given. In
+    non-blocking mode a missing key starts one background build and the
+    call declines (None) until it lands; a failed build trips the
+    model's tables breaker, as a whole-set build does."""
+
+    def __init__(self, model):
+        from tendermint_tpu.crypto.batch import TABLE_COUNTS
+        from tendermint_tpu.models.aot_cache import key_rows
+
+        self._model = model
+        self._counts = TABLE_COUNTS
+        self._rows = key_rows  # a key matrix's rows as bytes
+        self._lock = threading.Lock()  # columns, arrays, memo
+        self._build_serial = threading.Lock()  # one fetch of missing keys at a time
+        self._col: Dict[bytes, int] = {}  # key -> column
+        self._used = 0  # columns 0.._used-1 hold keys
+        self._keys = np.zeros((0, 32), dtype=np.uint8)  # host copy of the key column
+        self._stamp = np.zeros(0, dtype=np.int64)  # column -> tick of its last use
+        self._tick = 0
+        # device arrays of cap columns: tables (cap, 7680) a row a key,
+        # None while the pool is one set as its build left it (_whole)
+        self.tables = self.a_ok = self.pk = None
+        self._whole: Optional[_TablesEntry] = None  # the pool as an operand, stages' form
+        self._memo: "OrderedDict[bytes, np.ndarray]" = OrderedDict()  # digest -> columns
+        self._memo_cols = 0
+        self.building = False  # a background fetch is running
+        self.failed = False  # the last fetch failed (the breaker gates the retry)
+        self.build_s = 0.0  # seconds spent fetching, dispatches made
+        self.dispatches = 0
+
+    @staticmethod
+    def max_keys() -> int:
+        return MAX_TABLE_BYTES // TABLE_KEY_BYTES
+
+    def __len__(self) -> int:
+        return self._used
+
+    def capacity(self) -> int:
+        return 0 if self.a_ok is None else int(self.a_ok.shape[0])
+
+    # the engine_stats bucket protocol (models/telemetry.bucket_entry):
+    # the pool is one "tables:" bucket, ready once it holds keys and no
+    # background build runs
+    @property
+    def ready(self) -> bool:
+        return self._used > 0 and not self.building
+
+    @property
+    def compiling(self) -> bool:
+        return self.building
+
+    @property
+    def compile_s(self) -> Optional[float]:
+        return self.build_s or None
+
+    def nbytes(self) -> int:
+        held = (self.tables is not None) + (self._whole is not None)
+        return held * self.capacity() * TABLE_KEY_BYTES
+
+    def _flat(self):
+        """The tables a row a key (lock held): laid out so once, when a
+        set built whole stops being all of the pool."""
+        if self.tables is None:
+            cap = self.capacity()
+            self.tables, self._whole = self._whole.tables.reshape(cap, -1), None
+        return self.tables
+
+    # -- the one entry point ------------------------------------------------
+
+    def view(self, keys) -> Optional[_TablesEntry]:
+        pk = np.ascontiguousarray(keys.pubkeys, dtype=np.uint8)
+        u = int(pk.shape[0])
+        if not 0 < u <= min(MAX_TABLED_VALSET, self.max_keys()):
+            return None
+        with self._lock:
+            cols = self._columns(keys.digest, pk)
+            if cols is not None:
+                return self._operand(cols)
+        missing = self._missing(pk)
+        model = self._model
+        probed = False
+        if self.failed:
+            # fail-stop until the breaker's cooldown, then one probe
+            if not model.tables_breaker.allow():
+                return None
+            probed = True
+        if not model.block_on_compile:
+            with self._lock:
+                if self.building:
+                    if probed:
+                        model.tables_breaker.release_probe()
+                    return None
+                self.building = True
+
+            def work():
+                try:
+                    if self._fetch(missing, pinned=pk):
+                        with self._lock:  # the slab's shape too, off the live path
+                            cols = self._columns(keys.digest, pk)
+                            if cols is not None:
+                                self._operand(cols)
+                except Exception as ex:  # pragma: no cover - defensive
+                    model.logger.error("key table warm failed", err=repr(ex))
+                finally:
+                    self.building = False
+
+            t = threading.Thread(target=work, daemon=True, name="key-tables")
+            _track_compile_thread(t)
+            t.start()
+            return None
+        if not self._fetch(missing, pinned=pk):
+            return None
+        with self._lock:
+            cols = self._columns(keys.digest, pk)
+            return None if cols is None else self._operand(cols)
+
+    # -- columns --------------------------------------------------------------
+
+    def _columns(self, digest: bytes, pk: np.ndarray) -> Optional[np.ndarray]:
+        """The keys' columns (lock held), None while one is missing."""
+        cols = self._memo.get(digest)
+        if cols is None:
+            get = self._col.get
+            cols = np.fromiter(
+                (get(k, -1) for k in self._rows(pk)), dtype=np.int32, count=pk.shape[0]
+            )
+            if cols.size and cols.min() < 0:
+                return None
+            self._memo[digest] = cols
+            self._memo_cols += cols.size
+            while self._memo_cols > self.max_keys() and len(self._memo) > 1:
+                self._memo_cols -= self._memo.popitem(last=False)[1].size
+        else:
+            self._memo.move_to_end(digest)
+        self._counts.add(keys_reused=int(cols.size))
+        return cols
+
+    def _missing(self, pk: np.ndarray) -> np.ndarray:
+        """The distinct keys of pk the pool lacks, in pk's order."""
+        with self._lock:
+            have = self._col
+            seen = set()
+            rows = [
+                i for i, k in enumerate(self._rows(pk))
+                if k not in have and not (k in seen or seen.add(k))
+            ]
+        return pk[rows]
+
+    def _operand(self, cols: np.ndarray) -> _TablesEntry:
+        """The columns as a launch's table operand (lock held)."""
+        self._tick += 1
+        self._stamp[cols] = self._tick
+        u = int(cols.size)
+        u_pad = _bucket(u, 1)
+        cap = self.capacity()
+        if cap == u_pad and cols[0] == 0 and cols[-1] == u - 1 and (np.diff(cols) == 1).all():
+            if self._whole is None:
+                self._whole = _pool_view(
+                    self.tables.reshape(ops_ed._table_shape(cap)), self.a_ok, self.pk, cap
+                )
+            return self._whole
+        padded = np.zeros(u_pad, dtype=np.int32)  # a padding slot reads column 0: never a row's
+        padded[:u] = cols
+        self._counts.add(slabs=1, slab_columns=u_pad)
+        return _pool_view(*self._model._slab(self._flat(), self.a_ok, self.pk, padded), u_pad)
+
+    # -- missing keys -----------------------------------------------------------
+
+    def _fetch(self, pk: np.ndarray, pinned: np.ndarray) -> bool:
+        """Read from the table files, or build in one dispatch, the
+        tables of keys the pool lacks, and append them. The build runs
+        outside the columns' lock: calls for pooled keys go on."""
+        from tendermint_tpu.models import aot_cache
+        from tendermint_tpu.utils.trace import span
+
+        model = self._model
+        with self._build_serial, span("tables.build", keys=int(pk.shape[0])) as sp:
+            pk = self._missing(pk)  # another call's fetch may have brought some
+            try:
+                if pk.shape[0]:
+                    faults.maybe("device.tables")
+                    t0 = time.perf_counter()
+                    tables_dir = aot_cache.tables_dir()  # resolved now: see _build_tables
+                    found = aot_cache.load_tables(pk)
+                    loaded = 0
+                    if found is not None:
+                        mask, tables, a_ok = found
+                        loaded = int(np.count_nonzero(mask))
+                        if loaded:
+                            self._append(pk[mask], tables[mask], a_ok[mask], pinned)
+                        pk = pk[~mask]
+                    k = int(pk.shape[0])
+                    if k:
+                        k_pad = _bucket(k, 1)
+                        tables, a_ok = model._program("t-build")(
+                            jnp.asarray(model._pad(pk, k_pad))
+                        )
+                        self.dispatches += 1
+                        self._append(pk, tables, a_ok, pinned)
+                        aot_cache.save_tables(
+                            pk, np.asarray(tables)[:k], np.asarray(a_ok)[:k],
+                            dir_path=tables_dir,
+                        )
+                    self.a_ok.block_until_ready()
+                    self.build_s += time.perf_counter() - t0
+                    self._counts.add(keys_built=k, keys_loaded=loaded)
+                    sp.set(built=k, loaded=loaded, dispatches=int(k > 0))
+                    model.logger.info(
+                        "key tables ready", built=k, loaded=loaded, pooled=self._used,
+                        seconds=round(time.perf_counter() - t0, 2),
+                    )
+                self.failed = False
+                model.tables_breaker.record_success()
+                return True
+            except Exception as ex:
+                # None-means-fallback, never an exception into commit
+                # verification; fail-stop until the breaker's probe
+                self.failed = True
+                model.tables_breaker.record_failure()
+                model.logger.error("key table build failed", err=repr(ex))
+                return False
+
+    def _append(self, pk: np.ndarray, tables, a_ok, pinned: np.ndarray) -> None:
+        """New keys at the pool's end: tables and a_ok hold their rows
+        first (k of bucket(k) device rows, or k host rows)."""
+        model = self._model
+        k = int(pk.shape[0])
+        k_pad = _bucket(k, 1)
+        if isinstance(tables, np.ndarray):
+            tables, a_ok = model._pad(tables, k_pad), model._pad(a_ok, k_pad)
+        pk_pad = model._pad(pk, k_pad)
+        with self._lock:
+            rows = self._rows(pk)
+            if self._used + k > self.max_keys():
+                self._evict(self._used + k - self.max_keys(), pinned)
+            need = self._used + k
+            if self.a_ok is None and k_pad == _bucket(need, 1):
+                # a set built whole into an empty pool: its build IS the pool
+                self.a_ok, self.pk = jnp.asarray(a_ok), jnp.asarray(pk_pad)
+                self._whole = _pool_view(jnp.asarray(tables), self.a_ok, self.pk, k_pad)
+                cap = k_pad
+            else:
+                cap = self._grow(need)
+                at = np.full(k_pad, cap, dtype=np.int32)  # past the end: dropped
+                at[:k] = np.arange(self._used, need)
+                self.tables, self.a_ok, self.pk = model._program("t-put")(
+                    self.tables, self.a_ok, self.pk, jnp.asarray(at),
+                    jnp.asarray(tables), jnp.asarray(a_ok), jnp.asarray(pk_pad),
+                )
+                self._whole = None
+            if self._keys.shape[0] < cap:
+                self._keys = model._pad(self._keys, cap)
+                self._stamp = model._pad(self._stamp, cap)
+            self._keys[self._used : need] = pk
+            self._stamp[self._used : need] = self._tick
+            for i, key in enumerate(rows):
+                self._col[key] = self._used + i
+            self._used = need
+
+    def _grow(self, need: int) -> int:
+        """The pool's arrays, tables a row a key, at least ``need``
+        columns wide (lock held)."""
+        cap = _bucket(need, 1)
+        if self.a_ok is None:
+            self.tables = jnp.zeros((cap, TABLE_KEY_BYTES // 4), dtype=jnp.int32)
+            self.a_ok = jnp.zeros((cap,), dtype=bool)
+            self.pk = jnp.zeros((cap, 32), dtype=jnp.uint8)
+        have = self.capacity()
+        self._flat()
+        if have >= cap:
+            return have
+        grow = lambda a: jnp.pad(a, [(0, cap - have)] + [(0, 0)] * (a.ndim - 1))  # noqa: E731
+        self.tables, self.a_ok, self.pk = grow(self.tables), grow(self.a_ok), grow(self.pk)
+        return cap
+
+    def _evict(self, n: int, pinned: np.ndarray) -> None:
+        """Drop the n least recently used keys, none of ``pinned``, and
+        move the rest up in their order (lock held)."""
+        free = np.ones(self._used, dtype=bool)
+        for key in self._rows(np.ascontiguousarray(pinned, dtype=np.uint8)):
+            c = self._col.get(key)
+            if c is not None:
+                free[c] = False
+        cand = np.flatnonzero(free)
+        if cand.size < n:
+            raise RuntimeError(f"key pool: {n} keys over MAX_TABLE_BYTES and all in use")
+        gone = cand[np.argsort(self._stamp[cand], kind="stable")[:n]]
+        keep = np.delete(np.arange(self._used, dtype=np.int32), gone)
+        cap = _bucket(max(int(keep.size), 1), 1)
+        padded = np.zeros(cap, dtype=np.int32)
+        padded[: keep.size] = keep
+        tables, self.a_ok, self.pk = self._model._slab(
+            self._flat(), self.a_ok, self.pk, padded
+        )
+        self.tables = tables.reshape(cap, -1)
+        keys, stamp = self._keys[keep], self._stamp[keep]
+        self._keys, self._stamp = self._model._pad(keys, cap), self._model._pad(stamp, cap)
+        self._used = int(keep.size)
+        self._col = {k: i for i, k in enumerate(self._rows(np.ascontiguousarray(keys)))}
+        self._memo.clear()
+        self._memo_cols = 0
+        self._whole = None
+        self._counts.add(keys_evicted=n)
 
 
 # Every device program of the model: AOT tag -> (function, in_specs,
@@ -311,6 +660,8 @@ _PROGRAMS = {
     "t-prepare-s": (ops_ed.verify_stage_prepare_tabled_slots, None, None),
     "t-scan-s": (ops_ed.verify_stage_scan_tabled_slots, None, None),
     "t-scan-sh": (ops_ed.verify_stage_scan_tabled_sharded, None, None),
+    "t-slab": (ops_ed.table_slab, None, None),
+    "t-put": (ops_ed.table_put, None, None),
 }
 # Skip executable persistence on XLA:CPU (aot_cache.AotJit): the
 # materializer is the crash class that motivated splitting it from
@@ -339,7 +690,12 @@ class VerifierModel:
         self._lock = threading.Lock()
         self._entries: Dict[tuple, _Entry] = {}  # see compile_stats
         self._programs: Dict[str, object] = {}  # tag -> AotJit (_program)
-        self._valset_tables: Dict[bytes, _TablesEntry] = {}  # insertion-ordered LRU
+        # key tables: the pool of an unmeshed model (sets up to
+        # MAX_TABLED_VALSET), and whole-set entries for what the pool
+        # does not serve — a mesh (replicated once at build) and sets
+        # past MAX_TABLED_VALSET (sharded) — an insertion-ordered LRU
+        self.key_pool = _KeyPool(self)
+        self._valset_tables: Dict[bytes, _TablesEntry] = {}
         # Table-build failure used to latch `e.failed` FOREVER: one
         # transient device hiccup (OOM during a vote storm, a wedged
         # runtime) downgraded that valset to the generic path until
@@ -605,10 +961,8 @@ class VerifierModel:
         t0 = time.perf_counter()
         v = pubkeys.shape[0]
         v_pad = _bucket(v, 1)
-        pk_pad = self._pad(np.asarray(pubkeys, dtype=np.uint8), v_pad)
-        import hashlib
-
-        pk_digest = hashlib.sha256(pk_pad.tobytes()).digest()
+        pubkeys = np.ascontiguousarray(pubkeys, dtype=np.uint8)
+        pk_pad = self._pad(pubkeys, v_pad)
         # resolve the cache dir NOW: on the async-build path the env
         # var may point somewhere else by the time the thread saves
         tables_dir = aot_cache.tables_dir()
@@ -618,7 +972,11 @@ class VerifierModel:
         # scan instead of one pathological huge-table gather.
         sharded = v_pad > MAX_TABLED_VALSET
         shard_rows = MAX_TABLED_VALSET if sharded else v_pad
-        loaded = aot_cache.load_tables(key, v_pad, pk_digest)
+        found = aot_cache.load_tables(pubkeys)
+        loaded = None
+        if found is not None and found[0].all():
+            # rows of padding are never a row's: zeros do
+            loaded = self._pad(found[1], v_pad), self._pad(found[2], v_pad)
         tables = shards = None
         if loaded is not None:
             # restart path: pure data from disk, no build program at all
@@ -683,8 +1041,7 @@ class VerifierModel:
                 else np.asarray(tables)
             )
             aot_cache.save_tables(
-                key, flat, np.asarray(a_ok), pk_digest,
-                dir_path=tables_dir,
+                pubkeys, flat[:v], np.asarray(a_ok)[:v], dir_path=tables_dir
             )
 
     def sharded_valset_cap(self) -> int:
@@ -701,14 +1058,30 @@ class VerifierModel:
         n_dev = int(np.prod(list(self.mesh.shape.values())))
         return MAX_SHARDED_VALSET // max(1, n_dev)
 
+    def _tables_pending(self, key: bytes, pubkeys: np.ndarray) -> bool:
+        """Whether a background build that could serve these keys runs."""
+        if self.mesh is None and int(pubkeys.shape[0]) <= MAX_TABLED_VALSET:
+            return self.key_pool.building
+        with self._lock:
+            e = self._valset_tables.get(key)
+        return e is not None and e.building
+
     def _tables_entry(self, key: bytes, pubkeys: np.ndarray) -> Optional[_TablesEntry]:
-        """The ready tables entry for `key`, or None when still cold
-        (async build kicked off in non-blocking mode) or the set is too
-        large for the tabled path: past MAX_TABLED_VALSET the tables go
+        """The ready table operand for the keys `pubkeys`, named by
+        `key`, or None when still cold (async build kicked off in
+        non-blocking mode) or the set is too large for the tabled path.
+        One device and a set up to MAX_TABLED_VALSET: the key pool's
+        columns of these keys (_KeyPool.view). A mesh, or a larger set:
+        a whole-set entry — past MAX_TABLED_VALSET the tables go
         SHARDED, past sharded_valset_cap() (the per-device HBM bound
         — MAX_SHARDED_VALSET divided by the mesh size) the generic
-        pipeline takes over."""
+        pipeline takes over. The entries together stay under
+        MAX_TABLE_BYTES, least recently used first out."""
+        from tendermint_tpu.crypto.batch import GroupKeys
+
         v = int(pubkeys.shape[0])
+        if self.mesh is None and v <= MAX_TABLED_VALSET:
+            return self.key_pool.view(GroupKeys(key, pubkeys))
         if v > MAX_TABLED_VALSET and v > self.sharded_valset_cap():
             return None
         with self._lock:
@@ -720,13 +1093,13 @@ class VerifierModel:
                 self._valset_tables.pop(key)
                 self._valset_tables[key] = e
             else:
-                e = _TablesEntry(int(pubkeys.shape[0]))
+                e = _TablesEntry(v)
                 self._valset_tables[key] = e
-                while len(self._valset_tables) > MAX_CACHED_VALSETS:
-                    old = next(iter(self._valset_tables))
-                    if old == key:
+                held = sum(_bucket(x.v, 1) for x in self._valset_tables.values())
+                for old in list(self._valset_tables):
+                    if held * TABLE_KEY_BYTES <= MAX_TABLE_BYTES or old == key:
                         break
-                    del self._valset_tables[old]
+                    held -= _bucket(self._valset_tables.pop(old).v, 1)
         if e.ready:
             return e
         probed = False  # did WE take the half-open probe token below?
@@ -827,22 +1200,37 @@ class VerifierModel:
         cross-height batches (one template pair per height) don't
         compile per T. Same None-means-fallback contract.
 
-        row_idx may be a crypto/batch.RowGroups (whole commits of the
-        set, the other row arguments None): still one call and one
-        sync, the verdicts of every row in row order — but each
-        slot-order launch's worth of commits is taken from the source
-        only after the launch before it is dispatched, so the caller's
-        seam packs it while the device runs (_group_pieces). None, at
-        any point, leaves the source to its owner to finish."""
-        from tendermint_tpu.crypto.batch import RowGroups
+        row_idx may be a crypto/batch.RowGroups (whole commits, the
+        other row arguments None): still one call and one sync, the
+        verdicts of every row in row order — but each slot-order
+        launch's worth of commits is taken from the source only after
+        the launch before it is dispatched, so the caller's seam packs
+        it while the device runs (_group_pieces). Each group brings the
+        keys its rows index (RowGroups.keys; valset_key and all_pubkeys
+        where it gives None), so a chain over many sets stays one call.
+        None, at any point, leaves the source to its owner to finish."""
+        from tendermint_tpu.crypto.batch import GroupKeys, RowGroups
 
         if not isinstance(row_idx, RowGroups):
             src = self._tpl_src(templates, tmpl_idx, ts8)
             return self._rows_cached_arrays(valset_key, all_pubkeys, row_idx, src, sigs)
-        e = self._tables_entry(valset_key, np.asarray(all_pubkeys, dtype=np.uint8))
-        if e is None:
-            return None
-        return self._rows_cached_core(e, self._group_pieces(e, row_idx))
+        own = GroupKeys(valset_key, np.asarray(all_pubkeys, dtype=np.uint8))
+        return self._rows_cached_core(self._group_pieces(own, row_idx))
+
+    def _slab(self, tables, a_ok, pk, cols: np.ndarray):
+        """Columns ``cols`` of the key pool's arrays, gathered on the
+        device (ops_ed.table_slab)."""
+        from tendermint_tpu.utils.trace import span
+
+        with span("tables.slab", columns=int(cols.shape[0])):
+            return self._program("t-slab")(tables, a_ok, pk, jnp.asarray(cols))
+
+    def table_bytes(self) -> int:
+        """Device bytes of key tables this model holds: the pool's
+        columns and the whole-set entries."""
+        with self._lock:
+            sets = sum(_bucket(e.v, 1) for e in self._valset_tables.values() if e.ready)
+        return self.key_pool.nbytes() + sets * TABLE_KEY_BYTES
 
     # -- shared cached-path machinery (mat | tpl message sources) ---------
 
@@ -952,19 +1340,25 @@ class VerifierModel:
         if e is None:
             return None
         piece = (
-            np.asarray(row_idx, dtype=np.int32), src, np.asarray(sigs, dtype=np.uint8), (),
+            e, np.asarray(row_idx, dtype=np.int32), src, np.asarray(sigs, dtype=np.uint8), (),
         )
-        return self._rows_cached_core(e, (piece,))
+        return self._rows_cached_core((piece,))
 
-    def _group_pieces(self, e: _TablesEntry, groups):
+    def _group_pieces(self, own, groups):
         """A RowGroups as pieces for _rows_cached_core: the whole
-        commits one slot-order launch holds (MAX_DEVICE_ROWS // V; the
-        last group is rounded by plan_slots as any batch's tail is),
-        each taken — packed by the seam, on this thread — when the loop
-        comes back for it, the launches before it dispatched. Where
-        slot order never applies (a mesh and sharded tables gather)
-        everything at once: an eager batch. None where the source
-        declines.
+        commits one slot-order launch holds, each group taken — packed
+        by the seam, on this thread — when the loop comes back for it,
+        the launches before it dispatched, and each with the table
+        operand of its own keys (``own`` where the source names none).
+        A group is MAX_DEVICE_ROWS // V commits, V the bucket of its
+        next commit's set (the last group is rounded by plan_slots as
+        any batch's tail is), halved while the distinct keys of its
+        commits' sets together pass that bucket — a wider operand would
+        launch every commit of the group over that many more slots: a
+        chain that changes one key a height launches the shapes of a
+        chain that changes none. Where slot order never applies (a mesh
+        and sharded tables gather) everything at once: an eager batch.
+        None where the source declines or a group's tables are cold.
 
         The first piece names the shape of the last group's launch
         ahead of it, by arithmetic from the commits left (C the power
@@ -972,10 +1366,24 @@ class VerifierModel:
         shorter or longer than the last has another tail shape, and one
         that is cold is found before anything is dispatched, not after
         the launches before it ran."""
-        v = self._slot_table_rows(e)
-        per = _commits_per_launch(v) if 0 < v <= MAX_DEVICE_ROWS else max(1, groups.left)
-        full, rest = divmod(groups.left, per)
+
+        def keys_of(commits: int):
+            return groups.keys(commits) or own
+
+        ahead_due = True
         while groups.left:
+            v1 = _bucket(int(keys_of(1).pubkeys.shape[0]), 1)
+            slotted = self.mesh is None and v1 <= MAX_DEVICE_ROWS
+            per = _commits_per_launch(v1) if slotted else groups.left
+            keys = keys_of(per)
+            while slotted and per > 1 and _bucket(int(keys.pubkeys.shape[0]), 1) > v1:
+                per //= 2
+                keys = keys_of(per)
+            e = self._tables_entry(keys.digest, keys.pubkeys)
+            if e is None:
+                yield None
+                return
+            full, rest = divmod(groups.left, per)
             got = groups.take(per)
             if got is None:
                 yield None
@@ -983,12 +1391,13 @@ class VerifierModel:
             idx, templates, tmpl_idx, ts8, sg = got
             src = self._tpl_src(templates, tmpl_idx, ts8)
             ahead = ()
-            if full and rest:
+            v = self._slot_table_rows(e)
+            if ahead_due and full and rest and v:
                 tail = ("tpl", np.empty((2 * rest, self._src_msg_len(src)), dtype=np.uint8))
                 ahead = ((_tail_commits(rest, per) * v, tail, True),)
-                rest = 0
+            ahead_due = False
             yield (
-                np.asarray(idx, dtype=np.int32), src, np.asarray(sg, dtype=np.uint8), ahead,
+                e, np.asarray(idx, dtype=np.int32), src, np.asarray(sg, dtype=np.uint8), ahead,
             )
 
     def _plan_launches(self, e: _TablesEntry, idx: np.ndarray) -> list:
@@ -1017,11 +1426,12 @@ class VerifierModel:
             launches.append((n - n % window, n, tail, None))
         return launches
 
-    def _rows_cached_core(self, e: _TablesEntry, pieces) -> Optional[np.ndarray]:
-        """Verify a batch that comes in pieces — (row_idx, src, sigs,
-        shapes ahead) each: the one piece of an array call, or a
-        RowGroups' groups (_group_pieces), each pulled only after the
-        launches of the one before it are dispatched. Every launch in
+    def _rows_cached_core(self, pieces) -> Optional[np.ndarray]:
+        """Verify a batch that comes in pieces — (table operand,
+        row_idx into it, src, sigs, shapes ahead) each: the one piece
+        of an array call, or a RowGroups' groups (_group_pieces), each
+        pulled only after the launches of the one before it are
+        dispatched. Every launch in
         flight, one sync, the verdicts in row order; only the real rows
         count as device rows. The per-window decompress and table build
         the generic path pays are already hoisted into the cached
@@ -1033,7 +1443,7 @@ class VerifierModel:
         Every shape of a piece, and every shape it names ahead (the
         last group's), must be warm before any of its launches is
         dispatched: a cold tail found after the windows ran would throw
-        that device work away. NOT latched as e.failed — the tables
+        that device work away. NOT latched as a failed build — the tables
         themselves are fine and the next call may succeed. What was
         dispatched is dropped uncounted."""
         outs = []  # (device verdicts, the rows' places in them) a launch
@@ -1044,7 +1454,7 @@ class VerifierModel:
             for piece in pieces:
                 if piece is None:
                     return None
-                idx, src, sg, ahead = piece
+                e, idx, src, sg, ahead = piece
                 launches = self._plan_launches(e, idx)
                 shapes = [(pad, src, at is not None) for _, _, pad, at in launches]
                 if not self.block_on_compile:
@@ -1136,13 +1546,8 @@ class VerifierModel:
         for a lazy build on the live path). Non-blocking when the model
         is; safe to call for an already-registered set."""
         pk = np.asarray(all_pubkeys, dtype=np.uint8)
-        if self.block_on_compile:
-            e = self._tables_entry(valset_key, pk)
-        else:
-            self._tables_entry(valset_key, pk)  # kicks the async build
-            with self._lock:
-                e = self._valset_tables.get(valset_key)
-        if e is None:
+        e = self._tables_entry(valset_key, pk)  # non-blocking: kicks the async build
+        if e is None and (self.block_on_compile or not self._tables_pending(valset_key, pk)):
             return
         n = int(pk.shape[0])
         # oversized sets dispatch as <=MAX_DEVICE_ROWS windows; warming
@@ -1158,7 +1563,7 @@ class VerifierModel:
             ),
         )
 
-        def warm_bucket():
+        def warm_bucket(e):
             # what a full commit of the set takes (slot order, one
             # commit a launch), and the gathered pair at the set's
             # bucket for vote drains and lookups out of order
@@ -1170,18 +1575,20 @@ class VerifierModel:
                     if not ent.ready:
                         self._compile_tabled_async(ent, e, rows, src, slots=slots)
 
-        if e.ready:
-            warm_bucket()
+        if e is not None:
+            warm_bucket(e)
             return
 
         def warm_when_built():
             deadline = time.monotonic() + 600
             while time.monotonic() < deadline:
-                if e.ready:
-                    warm_bucket()
+                pending = self._tables_pending(valset_key, pk)
+                e = self._tables_entry(valset_key, pk)
+                if e is not None:
+                    warm_bucket(e)
                     return
-                if not e.building:
-                    return  # build failed (logged by _build_tables): stop polling
+                if not pending:
+                    return  # build failed (logged where it failed): stop polling
                 time.sleep(0.25)
 
         t = threading.Thread(target=warm_when_built, daemon=True, name="tabled-warmup")

@@ -105,6 +105,42 @@ def build_valset_tables(pubkeys: jnp.ndarray):
     return curve.build_split_tables(curve.negate(a_point)), a_ok
 
 
+def _table_shape(rows: int) -> tuple:
+    return (rows, curve.SPLITS, curve._TBL, 3 * curve.F.LIMBS)
+
+
+def table_slab(tables, a_ok, pubkeys, cols):
+    """One launch's table operand cut from the key pool
+    (models/verifier._KeyPool): columns ``cols`` (U,) i32 of the pool's
+    tables, (P,) a_ok and (P, 32) pubkeys, in the order given — a copy
+    of ~30 KB a column where the columns lie; nothing is computed. The
+    pool holds a key's table as ONE row of (P, SPLITS*8*3*LIMBS): 7,680
+    int32, sixty lanes-wide vectors, which the gather copies as they
+    lie. Gathered from the (P, SPLITS, 8, 3*LIMBS) form the stages read,
+    the TPU compiler first lays the WHOLE pool out again (268 MB of
+    temporaries for a 4,096-key pool, none this way). The operand goes
+    out in the stages' form. A set that is the pool as it lies takes the
+    pool's arrays themselves and never runs this."""
+    u = cols.shape[0]
+    return (
+        jnp.take(tables, cols, axis=0).reshape(_table_shape(u)),
+        jnp.take(a_ok, cols, axis=0),
+        jnp.take(pubkeys, cols, axis=0),
+    )
+
+
+def table_put(tables, a_ok, pubkeys, cols, new_tables, new_a_ok, new_pubkeys):
+    """The pool (tables a row a key, see table_slab) with freshly built
+    rows written at columns ``cols`` (K,) i32; a column past the pool's
+    end is dropped — the padding of a build bucket."""
+    k = cols.shape[0]
+    return (
+        tables.at[cols].set(new_tables.reshape(k, -1), mode="drop"),
+        a_ok.at[cols].set(new_a_ok, mode="drop"),
+        pubkeys.at[cols].set(new_pubkeys, mode="drop"),
+    )
+
+
 def verify_stage_prepare_tabled(pubkeys, msgs, sigs):
     """Tabled stage 1: challenge hash + canonical-s + signed recode.
     No decompression — the tables already encode -A. pubkeys are still

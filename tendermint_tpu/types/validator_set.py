@@ -27,7 +27,7 @@ from tendermint_tpu.codec.binary import Reader, Writer
 from tendermint_tpu.codec.signbytes import splice_timestamps
 from tendermint_tpu.crypto import merkle
 from tendermint_tpu.crypto.batch import (
-    SEAM_COUNTS, BatchVerifier, RowGroups, get_default_provider,
+    SEAM_COUNTS, BatchVerifier, GroupKeys, RowGroups, get_default_provider,
 )
 from tendermint_tpu.types.block import BLOCK_ID_FLAG_COMMIT, MAX_SIGNATURE_SIZE, first_true
 from tendermint_tpu.types.validator import Validator
@@ -107,6 +107,7 @@ class ValidatorSet:
         self._total_voting_power = total
         self._dev_arrays = None  # membership/power changed: drop the cache
         self._dev_key = None
+        self._addr_col = None
         self._bls_cache = None
         self._hash = None  # (pubkey, power) merkle root changed too
 
@@ -123,6 +124,7 @@ class ValidatorSet:
         # copies in state/execution.py
         new._dev_arrays = getattr(self, "_dev_arrays", None)
         new._dev_key = getattr(self, "_dev_key", None)
+        new._addr_col = getattr(self, "_addr_col", None)
         new._hash = getattr(self, "_hash", None)
         new._bls_cache = getattr(self, "_bls_cache", None)
         return new
@@ -346,6 +348,16 @@ class ValidatorSet:
             key = hashlib.sha256(pk.tobytes()).digest()
             self._dev_key = key
         return key, pk, ed
+
+    def address_column(self) -> np.ndarray:
+        """The validators' 20-byte addresses as one (V,) bytes column,
+        ascending as the set is; cached like batch_cache(). What several
+        sets' keys are merged by (_SpecRows.keys)."""
+        col = getattr(self, "_addr_col", None)
+        if col is None:
+            col = np.array([v.address for v in self.validators], dtype="S20")
+            self._addr_col = col
+        return col
 
     def _commit_batch_arrays(self, chain_id: str, commit, by_address: bool) -> Tuple:
         """Pack a commit's present signatures into device-ready arrays.
@@ -988,8 +1000,9 @@ class _SpecRows(RowGroups):
     match, set size) and its columns gathered into arrays
     (_commit_batch_arrays); it needs nothing from any other spec. A
     spec that fails contributes no rows and leaves its exception in
-    ``results``. ``take`` is a provider's side (crypto/batch.RowGroups),
-    ``finish`` and the parts lists are verify_commits_batched's."""
+    ``results``. ``keys`` and ``take`` are a provider's side
+    (crypto/batch.RowGroups), ``finish`` and the parts lists are
+    verify_commits_batched's."""
 
     def __init__(self, specs, results):
         self.specs, self.results = specs, results
@@ -998,6 +1011,7 @@ class _SpecRows(RowGroups):
         # specs packed so far that passed their pre-checks
         self.segments: list = []
         self.pk, self.mg, self.sg, self.tpl = [], [], [], []
+        self._keys = None  # (first spec, end spec, GroupKeys, set key -> places)
 
     def _pack(self, count: int) -> None:
         """Pack the next ``count`` specs."""
@@ -1029,23 +1043,59 @@ class _SpecRows(RowGroups):
         """Pack every spec not yet packed."""
         self._pack(self.left)
 
-    def one_ed25519_set(self, spec_idxs) -> bool:
-        """Whether these specs check against ONE all-ed25519 validator
-        set: the shape the per-valset cached tables serve."""
-        caches = [self.specs[si].valset.batch_cache() for si in spec_idxs]
-        key0, _, ed0 = caches[0]
-        return bool(ed0.all()) and all(c[0] == key0 for c in caches[1:])
+    def ed25519_sets(self) -> int:
+        """How many distinct validator sets the specs check against
+        where every key of every one is ed25519 — the shape the cached
+        key tables serve, one set or many — else 0."""
+        sets: Dict[bytes, bool] = {}
+        for s in self.specs:
+            key, _, ed = s.valset.batch_cache()
+            if key not in sets:
+                sets[key] = bool(ed.all())
+        return len(sets) if all(sets.values()) else 0
 
-    def stacked(self, lo: int, hi: int) -> Tuple:
+    def keys_of(self, lo: int, hi: int) -> Tuple[GroupKeys, dict]:
+        """The distinct keys of specs lo..hi's sets, and each set's
+        validators' places among them (None: the set as it lies). One
+        set: its own matrix under its batch_cache() key. Several: their
+        keys merged in address order, which is every set's own order,
+        under a digest of the sets' keys."""
+        if self._keys is not None and self._keys[:2] == (lo, hi):
+            return self._keys[2:]
+        sets = {}
+        for s in self.specs[lo:hi]:
+            sets.setdefault(s.valset.batch_cache()[0], s.valset)
+        if len(sets) == 1:
+            (key, vs), = sets.items()
+            keys, places = GroupKeys(key, vs.batch_cache()[1]), {key: None}
+        else:
+            import hashlib
+
+            addrs = [vs.address_column() for vs in sets.values()]
+            merged, first = np.unique(np.concatenate(addrs), return_index=True)
+            pk = np.concatenate([vs.batch_cache()[1] for vs in sets.values()])[first]
+            keys = GroupKeys(hashlib.sha256(b"".join(sets)).digest(), pk)
+            places = {k: np.searchsorted(merged, a) for k, a in zip(sets, addrs)}
+        self._keys = (lo, hi, keys, places)
+        return keys, places
+
+    def keys(self, commits: int) -> GroupKeys:
+        done = len(self.specs) - self.left
+        return self.keys_of(done, done + min(commits, self.left))[0]
+
+    def stacked(self, lo: int, hi: int, places: dict) -> Tuple:
         """Templated row arguments of segments lo..hi but the
         signatures: (row_idx i32, templates (2k, 160), tmpl_idx, ts8),
-        each segment's template pair at its offset in the stacked
-        template matrix."""
+        row_idx each row's validator's place among the keys ``places``
+        came with (keys_of), each segment's template pair at its offset
+        in the stacked template matrix."""
         tpl = self.tpl[lo:hi]
+        idx = []
+        for seg in self.segments[lo:hi]:
+            at = places[self.specs[seg[0]].valset.batch_cache()[0]]
+            idx.append(np.asarray(seg[2] if at is None else at[seg[2]], dtype=np.int32))
         return (
-            np.concatenate(
-                [np.asarray(seg[2], dtype=np.int32) for seg in self.segments[lo:hi]]
-            ),
+            np.concatenate(idx),
             np.concatenate([t[0] for t in tpl], axis=0),
             np.concatenate([t[1] + 2 * k for k, t in enumerate(tpl)]),
             np.concatenate([t[2] for t in tpl], axis=0),
@@ -1054,7 +1104,10 @@ class _SpecRows(RowGroups):
     def take(self, commits: int):
         overlapped = self.left < len(self.specs)
         lo = len(self.segments)
-        self._pack(min(commits, self.left))
+        done = len(self.specs) - self.left
+        count = min(commits, self.left)
+        _, places = self.keys_of(done, done + count)
+        self._pack(count)
         segs = self.segments[lo:]
         if not segs:
             return _NO_ROWS
@@ -1062,7 +1115,9 @@ class _SpecRows(RowGroups):
             return None
         if overlapped:
             SEAM_COUNTS.add(overlapped_rows=sum(seg[5] for seg in segs))
-        return self.stacked(lo, len(self.segments)) + (np.concatenate(self.sg[lo:], axis=0),)
+        return self.stacked(lo, len(self.segments), places) + (
+            np.concatenate(self.sg[lo:], axis=0),
+        )
 
 
 def verify_commits_batched(
@@ -1084,15 +1139,18 @@ def verify_commits_batched(
 
     "One device call" is one synchronous provider call whose launches
     are fed as they are packed: when the specs are full-mode commits of
-    one all-ed25519 validator set (a light client's chain, a fast-sync
-    window, a pipeline bundle) and the provider takes row groups
-    (crypto/batch.RowGroups), it pulls a launch's worth of commits,
-    dispatches the launch and pulls the next, so all but the first
-    group are packed while the device runs (SEAM_COUNTS
-    ``overlapped_rows``). Mixed sets, a trusting spec, a non-ed25519
-    key, a provider that cannot stream: the rows are packed first and
-    go as one eager batch. Either way the provider may decline (None)
-    at any point — cold tables or shape, a failed launch, a non-64-byte
+    all-ed25519 validator sets — one set, or a set a height that
+    changes a key at a time (a light client's chain, a fast-sync
+    window, a pipeline bundle) — and the provider takes row groups
+    (crypto/batch.RowGroups), it pulls a launch's worth of commits with
+    the distinct keys of their sets, dispatches the launch and pulls
+    the next, so all but the first group are packed while the device
+    runs (SEAM_COUNTS ``overlapped_rows``; ``multiset_rows`` where the
+    list spans several sets). A trusting spec or a provider that cannot
+    stream: the rows are packed first and go as one eager batch against
+    the keys of all the list's sets. A non-ed25519 key anywhere: the
+    generic kernel. Either way the provider may decline (None) at any
+    point — cold tables or shape, a failed launch, a non-64-byte
     signature met mid-list: the packing is finished here and the whole
     list goes down the generic path with the rows an eager call would
     have sent, each verified and counted once; the replay runs per spec
@@ -1101,25 +1159,28 @@ def verify_commits_batched(
     results: List[Optional[Exception]] = [None] * len(specs)
     rows = _SpecRows(specs, results)
     v = provider or get_default_provider()
-    # whole commits of one set in validator order: the shape a provider
-    # can take a group at a time
-    chain = (
-        bool(specs)
-        and all(s.mode == "full" for s in specs)
-        and rows.one_ed25519_set(range(len(specs)))
-    )
+    # whole commits of all-ed25519 sets in validator order: the shape a
+    # provider can take a group at a time
+    sets = rows.ed25519_sets()
     ok = None
-    streamed = chain and getattr(v, "takes_row_groups", False)
+    streamed = (
+        sets > 0
+        and all(s.mode == "full" for s in specs)
+        and getattr(v, "takes_row_groups", False)
+    )
     if streamed:
         key0, all_pk0, _ = specs[0].valset.batch_cache()
         ok = v.verify_rows_cached_templated(key0, all_pk0, rows)
+    tabled = ok is not None
     rows.finish()
     segments = rows.segments
     if not segments:
         return results
     if ok is None:
-        ok = _verify_packed(specs, rows, v, same_set=chain, declined=streamed)
+        ok, tabled = _verify_packed(specs, rows, v, ed25519=sets > 0, declined=streamed)
     ok = np.asarray(ok)
+    if tabled and sets > 1:
+        SEAM_COUNTS.add(multiset_rows=len(ok))
 
     off = 0
     for si, idxs, vals_idx, powers, counted, n, _ed in segments:
@@ -1139,12 +1200,11 @@ def verify_commits_batched(
 
 
 def _verify_packed(
-    specs, rows: _SpecRows, v, same_set: bool, declined: bool
-) -> np.ndarray:
-    """Verdicts of a fully packed spec list as ONE eager batch: the
-    per-valset cached tables when every row is ed25519 of one set
-    (``same_set``: already known of the whole list, else asked of the
-    specs that passed their pre-checks; templated form first, unless
+    specs, rows: _SpecRows, v, ed25519: bool, declined: bool
+) -> Tuple[np.ndarray, bool]:
+    """Verdicts of a fully packed spec list as ONE eager batch, and
+    whether the cached key tables gave them: taken when every key of
+    every set is ed25519 (``ed25519``; templated form first, unless
     the provider has just declined these rows as groups), else the
     generic kernel, with non-ed25519 rows verified serially by their
     own key type."""
@@ -1165,27 +1225,29 @@ def _verify_packed(
                 ok, specs[si].commit, idxs, vals_idx, mg, ed, mg_off=off0
             )
             off0 += n
-        return ok
-    # When every spec checks against the SAME validator set (the
-    # fast-sync window / light-client sequential shape: the set is
-    # stable across heights), the whole cross-height batch rides
-    # the per-valset cached tables — per-window decompression and
-    # table builds are hoisted out entirely (eval 3). The templated
-    # form uploads one template pair per HEIGHT plus 12 B/row of
-    # deltas instead of 160 B/row of materialized messages — the
-    # message upload was the measured bottleneck of the whole
-    # multi-height eval (the device sat idle behind H2D).
+        return ok, False
+    # Every row an ed25519 key's (the fast-sync window / light-client
+    # sequential shape: the sets are stable across heights, or change
+    # a key at a time): the whole cross-height batch rides the cached
+    # key tables — per-window decompression and table builds are
+    # hoisted out entirely (eval 3) — against the distinct keys of the
+    # list's sets (_SpecRows.keys_of: the one set's own matrix, or the
+    # sets' keys merged). The templated form uploads one template pair
+    # per HEIGHT plus 12 B/row of deltas instead of 160 B/row of
+    # materialized messages — the message upload was the measured
+    # bottleneck of the whole multi-height eval (the device sat idle
+    # behind H2D).
     ok = None
-    if same_set or rows.one_ed25519_set([seg[0] for seg in segments]):
-        key0, all_pk0, _ = specs[segments[0][0]].valset.batch_cache()
-        all_idx, templates, tmpl_idx, ts8 = rows.stacked(0, len(segments))
+    if ed25519:
+        keys, places = rows.keys_of(0, len(specs))
+        all_idx, templates, tmpl_idx, ts8 = rows.stacked(0, len(segments), places)
         f_t = getattr(v, "verify_rows_cached_templated", None)
         if f_t is not None and not declined:
-            ok = f_t(key0, all_pk0, all_idx, templates, tmpl_idx, ts8, sg)
+            ok = f_t(keys.digest, keys.pubkeys, all_idx, templates, tmpl_idx, ts8, sg)
         if ok is None:
             f = getattr(v, "verify_rows_cached", None)
             if f is not None:
-                ok = f(key0, all_pk0, all_idx, mg, sg)
+                ok = f(keys.digest, keys.pubkeys, all_idx, mg, sg)
     if ok is None:
-        return np.asarray(v.verify_batch(pk, mg, sg))  # ★ ONE device call, all heights
-    return np.asarray(ok)
+        return np.asarray(v.verify_batch(pk, mg, sg)), False  # ★ ONE device call, all heights
+    return np.asarray(ok), True

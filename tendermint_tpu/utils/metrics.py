@@ -335,9 +335,16 @@ class CryptoMetrics:
         ("seam_packed_rows", "seam_packed_rows"),
         ("seam_fixup_rows", "seam_fixup_rows"),
         ("seam_overlapped_rows", "seam_overlapped_rows"),
+        ("seam_multiset_rows", "seam_multiset_rows"),
         ("tabled_slot_rows", "tabled_slot_rows"),
         ("tabled_slot_pad", "tabled_slot_pad"),
         ("tabled_gathered_rows", "tabled_gathered_rows"),
+        ("table_keys_built", "table_keys_built"),
+        ("table_keys_loaded", "table_keys_loaded"),
+        ("table_keys_reused", "table_keys_reused"),
+        ("table_keys_evicted", "table_keys_evicted"),
+        ("table_slabs", "table_slabs"),
+        ("table_slab_columns", "table_slab_columns"),
     )
 
     def __init__(self, registry: Optional[Registry] = None, namespace="tendermint"):
@@ -357,9 +364,16 @@ class CryptoMetrics:
         self.seam_packed_rows = reg(Counter("seam_packed_rows_total", "Commit rows packed from columns for a provider.", namespace, sub))
         self.seam_fixup_rows = reg(Counter("seam_fixup_rows_total", "Packed rows off the common shape: non-64-byte signature, non-ed25519 key, unknown address.", namespace, sub))
         self.seam_overlapped_rows = reg(Counter("seam_overlapped_rows_total", "Packed rows a provider took as a later group of one call: packed while the device ran the launch before them.", namespace, sub))
+        self.seam_multiset_rows = reg(Counter("seam_multiset_rows_total", "Rows of spec lists over more than one validator set that the cached key tables answered.", namespace, sub))
         self.tabled_slot_rows = reg(Counter("tabled_slot_rows_total", "Rows verified in slot order: key tables read in place.", namespace, sub))
         self.tabled_slot_pad = reg(Counter("tabled_slot_pad_total", "Empty slots launched with the slot-order rows.", namespace, sub))
         self.tabled_gathered_rows = reg(Counter("tabled_gathered_rows_total", "Rows verified with their key tables gathered per row.", namespace, sub))
+        self.table_keys_built = reg(Counter("table_keys_built_total", "Validator keys whose table the device built into the key pool.", namespace, sub))
+        self.table_keys_loaded = reg(Counter("table_keys_loaded_total", "Validator keys whose table was read back from the table files.", namespace, sub))
+        self.table_keys_reused = reg(Counter("table_keys_reused_total", "Validator keys a call asked for and found pooled.", namespace, sub))
+        self.table_keys_evicted = reg(Counter("table_keys_evicted_total", "Least-recently-used keys dropped from the key pool under its byte bound.", namespace, sub))
+        self.table_slabs = reg(Counter("table_slabs_total", "Launches whose table operand was gathered from the key pool.", namespace, sub))
+        self.table_slab_columns = reg(Counter("table_slab_columns_total", "Key-table columns those gathers copied.", namespace, sub))
         self._deltas = _SnapshotCounters()
 
     def update(self, stats: dict) -> None:

@@ -29,8 +29,9 @@ def seam_grew(before: dict) -> dict:
 
 
 class GroupStub(BatchVerifier):
-    """``events``: ("take", k, rows or None, column_rows so far) when
-    group k has been taken, ("launch", k) when its rows are on their
+    """``group_keys``: the GroupKeys each group named before it was
+    taken. ``events``: ("take", k, rows or None, column_rows so far)
+    when group k has been taken, ("launch", k) when its rows are on their
     way, ("arrays", n) / ("rows", n) / ("batch", n) for the eager
     calls. ``decline_at=k`` answers None once group k is taken, as a
     provider whose launch k failed. Like the model, it counts device
@@ -43,6 +44,7 @@ class GroupStub(BatchVerifier):
     def __init__(self, per: int, decline_at=None):
         self.per, self.decline_at = per, decline_at
         self.events: list = []
+        self.group_keys: list = []
         self.row_counts = RowCounts()
         self._host = CPUBatchVerifier()
         self._columns0 = seam_counts()["seam_column_rows"]
@@ -59,6 +61,10 @@ class GroupStub(BatchVerifier):
             return None
         outs, k = [], 0
         while row_idx.left:
+            keys = row_idx.keys(self.per)  # the group's own keys, asked before it is taken
+            if keys is not None:
+                all_pubkeys = keys.pubkeys
+                self.group_keys.append(keys)
             got = row_idx.take(self.per)
             columns = seam_counts()["seam_column_rows"] - self._columns0
             self.events.append(("take", k, None if got is None else len(got[0]), columns))

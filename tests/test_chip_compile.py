@@ -57,13 +57,18 @@ def no_persistent_cache():
 def compile_for(topo, no_persistent_cache):
     """compile_for(fn_or_jitted, limit_s, *shapes) -> Compiled, failing
     the test when the compile outlives its limit or the program does
-    not fit one chip's memory."""
+    not fit one chip's memory. The limit is held against the lesser of
+    the wall clock and the process's CPU seconds: a compile that shares
+    its cores with five other test workers takes several times its own
+    work on the wall, and one that spreads over threads more CPU than
+    wall — a program that has grown too large for the compiler is long
+    by both."""
 
     def run(fn, limit_s, *args):
-        t0 = time.perf_counter()
+        t0, c0 = time.perf_counter(), time.process_time()
         jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
         compiled = jitted.lower(*args).compile()
-        dt = time.perf_counter() - t0
+        dt = min(time.perf_counter() - t0, time.process_time() - c0)
         m = compiled.memory_analysis()
         need = (
             m.generated_code_size_in_bytes + m.temp_size_in_bytes
@@ -127,3 +132,27 @@ def test_shard_map_scan_compiles_for_four_chips(topo, compile_for):
     compiled = compile_for(scan._jit, 90, *pre[:6])
     out = compiled.output_shardings
     assert all(s.spec == P(BATCH_AXIS) for s in jax.tree_util.tree_leaves(out)), out
+
+
+def test_table_slab_and_build_bucket_compile_for_v5e(topo, compile_for):
+    """What a chain whose validator set changes adds to the device: the
+    slab that cuts a launch's 1,024-column table operand out of a
+    4,096-column key pool — a copy of ~31 MB that plans NO temporary:
+    gathered from the (P, 16, 8, 60) form the stages read, the compiler
+    lays the whole pool out again first (268 MB at this size), which is
+    why the pool holds a key's table as one row —, the write of a
+    build's rows into the pool, and the table build at its smallest
+    bucket (one new key a height builds 16 rows)."""
+    S, like = shapes(SingleDeviceSharding(topo.devices[0]))
+    pool = S((4096, 16 * 8 * 60), i32), S((4096,), jnp.bool_), S((4096, 32), u8)
+    cols = S((1024,), i32)
+    slab = compile_for(E.table_slab, 30, *pool, cols)
+    out_t, out_ok, out_pk = like(jax.eval_shape(E.table_slab, *pool, cols))
+    tables, _ = like(jax.eval_shape(E.build_valset_tables, S((1024, 32), u8)))
+    assert (out_t.shape, out_t.dtype) == (tables.shape, tables.dtype)  # what stage 2 takes
+    assert out_ok.shape == (1024,) and out_pk.shape == (1024, 32)
+    assert slab.memory_analysis().temp_size_in_bytes <= 1e6
+    new_t, new_ok = like(jax.eval_shape(E.build_valset_tables, S((16, 32), u8)))
+    compile_for(E.build_valset_tables, 120, S((16, 32), u8))
+    put = compile_for(E.table_put, 30, *pool, S((16,), i32), new_t, new_ok, S((16, 32), u8))
+    assert put.memory_analysis().temp_size_in_bytes <= 1e6

@@ -238,6 +238,34 @@ def test_pipeline_publishes_overlapped_rows():
         assert cm.seam_overlapped_rows.value == now["seam_overlapped_rows"]
 
 
+@pytest.mark.parametrize(
+    "name",
+    ["seam_multiset_rows", "table_keys_built", "table_keys_loaded", "table_keys_reused",
+     "table_keys_evicted", "table_slabs", "table_slab_columns"],
+)
+def test_pipeline_publishes_key_pool_and_multiset_counts(name):
+    """What a node reads to tell "my window straddled a set change and
+    still rode the tables" and what the key pool built, reused and
+    evicted: the process's counts (crypto/batch.SEAM_COUNTS,
+    TABLE_COUNTS) under engine_stats()["counters"], stats() and
+    tendermint_crypto_<name>_total, one value everywhere."""
+    from tendermint_tpu.crypto.batch import SEAM_COUNTS, TABLE_COUNTS, CPUBatchVerifier
+    from tendermint_tpu.crypto.pipeline import PipelinedVerifier, SigCache
+    from tendermint_tpu.utils.metrics import CryptoMetrics, Registry
+
+    counts = SEAM_COUNTS if name.startswith("seam_") else TABLE_COUNTS
+    counts.add(**{name.split("_", 1)[1]: 3})
+    want = counts.snapshot()[name]
+    assert want >= 3
+    with PipelinedVerifier(CPUBatchVerifier(), cache=SigCache()) as pv:
+        assert pv.engine_stats()["counters"][name] == pv.stats()[name] == want
+        reg = Registry()
+        cm = CryptoMetrics(reg)
+        cm.update(pv.stats())
+        assert getattr(cm, name).value == want
+        assert f"tendermint_crypto_{name}_total {want}" in reg.expose_text()
+
+
 def _row_case_warm_blocking(v, batch):
     pk, mg, sg = batch
     assert v.verify_batch(pk, mg, sg).all()
@@ -333,6 +361,39 @@ def test_pipeline_engine_stats_mixed_arity_bucket_keys():
     }
     for b in st["buckets"].values():
         assert b == bucket_entry(_E())
+
+
+@pytest.mark.parametrize(
+    "ready,building,failed,want",
+    [(True, False, False, "ready"), (False, True, False, "compiling"), (False, False, True, "failed"),
+     (False, False, False, None)],
+)
+def test_pipeline_publishes_the_key_pool_as_a_tables_bucket(ready, building, failed, want):
+    """A node's boot is warm when its tables are: the key pool
+    (models/verifier._KeyPool) shows as one ``tables:pool`` bucket —
+    ready once it holds keys and no background build runs — beside the
+    whole-set entries of a mesh; an untouched pool shows nothing."""
+    from tendermint_tpu.crypto.batch import CPUBatchVerifier
+    from tendermint_tpu.crypto.pipeline import PipelinedVerifier, SigCache
+    from tendermint_tpu.models.verifier import _KeyPool
+
+    class _Model:
+        _entries = {}
+        _valset_tables = {}
+        tables_breaker = None
+        block_on_compile = True
+
+    model = _Model()
+    pool = model.key_pool = _KeyPool(model)
+    pool._used, pool.building, pool.failed, pool.build_s = int(ready), building, failed, 1.5
+    inner = CPUBatchVerifier()
+    inner.model = model
+    with PipelinedVerifier(inner, cache=SigCache()) as pv:
+        buckets = pv.engine_stats()["buckets"]
+    if want is None:
+        assert buckets == {}
+    else:
+        assert buckets == {"tables:pool": {"state": want, "compile_s": 1.5}}
 
 
 def test_txhash_engine_stats_device_and_host_split():

@@ -73,7 +73,7 @@ def test_mesh_parity_verify_only_path(models):
 AOT_TAGS = (
     "prepare", "scan", "finish",
     "t-prepare-g", "t-scan", "t-finish", "t-build", "t-materialize",
-    "t-prepare-s", "t-scan-s", "t-scan-sh",
+    "t-prepare-s", "t-scan-s", "t-scan-sh", "t-slab", "t-put",
 )
 
 
@@ -112,5 +112,5 @@ def test_mesh_provider_commit_tally_and_program_tags(models):
     assert {
         "verify_stage_prepare_tabled_slots", "verify_stage_scan_tabled_slots",
         "verify_stage_prepare_tabled_gathered", "verify_stage_scan_tabled",
-        "verify_stage_finish_blocked", "materialize_sign_bytes",
+        "verify_stage_finish_blocked", "materialize_sign_bytes", "table_slab",
     } <= names

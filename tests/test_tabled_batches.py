@@ -100,7 +100,7 @@ def test_cross_height_batch_rides_cached_tables():
     tpu = TPUBatchVerifier(block_on_compile=True, min_device_batch=2)
     res_tpu = verify_commits_batched(specs(), provider=tpu)
     res_cpu = verify_commits_batched(specs(), provider=CPUBatchVerifier())
-    assert len(tpu.model._valset_tables) == 1, "cached tables not used"
+    assert len(tpu.model.key_pool) == len(valsets[1]), "cached tables not used"
     for h, (a, b) in enumerate(zip(res_tpu, res_cpu), start=1):
         assert (a is None) == (b is None), (h, a, b)
     assert res_tpu[3] is not None  # height 4 rejected

@@ -34,10 +34,10 @@ def test_register_valset_prewarms_tabled_path():
 
     m = VerifierModel(block_on_compile=True)
     m.register_valset(b"boot-valset", pk)
-    assert len(m._valset_tables) == 1
+    assert len(m.key_pool) == 12 and m.key_pool.dispatches == 1
     ok = m.verify_rows_cached(b"boot-valset", pk, idx, mg, sg)
     assert ok is not None and ok.all()
-    assert len(m._valset_tables) == 1  # no rebuild
+    assert len(m.key_pool) == 12 and m.key_pool.dispatches == 1  # no rebuild
 
     # Non-blocking: the warmup ALONE (no live traffic) must build the
     # tables and warm the valset-size bucket — awaited on the warm-up's
@@ -59,9 +59,8 @@ def test_register_valset_prewarms_tabled_path():
         for t in mine:
             t.join(timeout=max(0.0, deadline - _time.monotonic()))
         assert _time.monotonic() < deadline, f"warm-up still running: {mine}"
-    e = m2._valset_tables.get(b"boot-valset-2")
-    assert e is not None and e.ready, "warmup alone never built the tables"
-    rows = int(e.tables.shape[0])
+    assert len(m2.key_pool) == 12, "warmup alone never built the tables"
+    rows = m2.key_pool.capacity()
     # a full commit's slot-order shape (one commit of `rows` slots) and
     # the gathered pair at the set's bucket, both message flavors
     for k in (
@@ -442,7 +441,7 @@ def test_validator_set_verify_commit_uses_cached_tables():
 
     tpu = TPUBatchVerifier(block_on_compile=True, min_device_batch=2)
     vals.verify_commit(genesis.chain_id, bid, 3, commit, provider=tpu)  # no raise
-    assert len(tpu.model._valset_tables) == 1  # cached path exercised
+    assert len(tpu.model.key_pool) == len(vals)  # cached path exercised
     cpu = CPUBatchVerifier()
     vals.verify_commit(genesis.chain_id, bid, 3, commit, provider=cpu)
 

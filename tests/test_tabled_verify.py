@@ -13,8 +13,13 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
+from tendermint_tpu.crypto.batch import TABLE_COUNTS
 from tendermint_tpu.ops import ref_ed25519 as ref
 from tests.tabled_helpers import arrs, sign_rows
+
+
+def _grew(before: dict, after: dict, name: str) -> int:
+    return after[f"table_{name}"] - before[f"table_{name}"]
 
 
 def test_verifier_model_rows_cached_and_fallback():
@@ -69,15 +74,19 @@ def test_failed_table_build_latches_to_generic_fallback(monkeypatch):
 
     m = VerifierModel(block_on_compile=True)
     calls = []
+    program = m._program
 
-    def boom(e, key, pubkeys):
+    def boom(tag):
+        if tag != "t-build":
+            return program(tag)
         calls.append(1)
         raise RuntimeError("RESOURCE_EXHAUSTED (simulated)")
 
-    monkeypatch.setattr(m, "_build_tables", boom)
+    monkeypatch.setattr(m, "_program", boom)
     assert m.verify_rows_cached(b"doomed", pk, idx, mg, sg) is None
     assert m.verify_rows_cached(b"doomed", pk, idx, mg, sg) is None
     assert len(calls) == 1, "doomed build retried"
+    assert m.key_pool.failed and len(m.key_pool) == 0
 
 
 def test_windowed_cached_path_boundary_controls(monkeypatch):
@@ -127,13 +136,17 @@ def test_tables_persist_to_disk_and_reload(tmp_path, monkeypatch):
     key = b"persist-valset"
 
     m1 = VerifierModel(block_on_compile=True)
+    c0 = TABLE_COUNTS.snapshot()
     ok1 = m1.verify_rows_cached(key, pk, idx, mg, sg)
-    assert m1._valset_tables[key].source == "build"
+    c1 = TABLE_COUNTS.snapshot()
+    assert (_grew(c0, c1, "keys_built"), _grew(c0, c1, "keys_loaded")) == (12, 0)
     assert any(f.endswith(".npz") for f in os.listdir(tmp_path))
 
     m2 = VerifierModel(block_on_compile=True)
     ok2 = m2.verify_rows_cached(key, pk, idx, mg, sg)
-    assert m2._valset_tables[key].source == "disk"
+    c2 = TABLE_COUNTS.snapshot()
+    assert (_grew(c1, c2, "keys_built"), _grew(c1, c2, "keys_loaded")) == (0, 12)
+    assert m2.key_pool.dispatches == 0  # pure data from disk, no build program
     np.testing.assert_array_equal(ok1, want)
     np.testing.assert_array_equal(ok2, want)
 
@@ -155,14 +168,15 @@ def test_tables_disk_corruption_falls_back_to_build(tmp_path, monkeypatch):
 
     m2 = VerifierModel(block_on_compile=True)
     ok = m2.verify_rows_cached(key, pk, idx, mg, sg)
-    assert m2._valset_tables[key].source == "build"  # rebuilt, not crashed
+    assert m2.key_pool.dispatches == 1 and len(m2.key_pool) == 8  # rebuilt, not crashed
     assert ok is not None and ok.all()
 
 
 def test_tables_disk_pubkey_mismatch_rebuilds(tmp_path, monkeypatch):
     """A persisted blob under a reused valset key must NOT be trusted
-    when the pubkeys differ: the stored sha256(pubkeys) gates the load
-    (a wrong table silently flips signature-verification results)."""
+    when the pubkeys differ: a row is read back only from under its own
+    key bytes (a wrong table silently flips signature-verification
+    results)."""
     from tendermint_tpu.models.verifier import VerifierModel
 
     monkeypatch.setenv("TM_TABLES_CACHE_DIR", str(tmp_path))
@@ -173,14 +187,16 @@ def test_tables_disk_pubkey_mismatch_rebuilds(tmp_path, monkeypatch):
 
     m1 = VerifierModel(block_on_compile=True)
     assert m1.verify_rows_cached(key, pk1, idx, mg1, sg1).all()
-    assert m1._valset_tables[key].source == "build"
+    assert m1.key_pool.dispatches == 1
 
     # same key, DIFFERENT pubkeys: the persisted blob must be rejected
     pks2, msgs2, sigs2 = sign_rows(8, seed=43)
     pk2, mg2, sg2 = arrs(pks2, msgs2, sigs2)
     m2 = VerifierModel(block_on_compile=True)
+    c0 = TABLE_COUNTS.snapshot()
     ok = m2.verify_rows_cached(key, pk2, idx, mg2, sg2)
-    assert m2._valset_tables[key].source == "build"  # rebuilt, not loaded
+    c1 = TABLE_COUNTS.snapshot()
+    assert (_grew(c0, c1, "keys_built"), _grew(c0, c1, "keys_loaded")) == (8, 0)  # rebuilt, not loaded
     assert ok is not None and ok.all()
 
 
@@ -199,7 +215,7 @@ def test_oversized_valset_skips_tabled_path(monkeypatch):
     m = vmod.VerifierModel(block_on_compile=True)
     out = m.verify_rows_cached(b"big-valset", pk, np.arange(12, dtype=np.int32), mg, sg)
     assert out is None  # caller falls back to the generic path
-    assert b"big-valset" not in m._valset_tables  # nothing was built
+    assert b"big-valset" not in m._valset_tables and len(m.key_pool) == 0  # nothing was built
 
 
 def test_small_sparse_batch_against_one_table_rides_gathered_pair():
@@ -239,9 +255,15 @@ def test_tables_disk_cache_bounded(tmp_path, monkeypatch):
     monkeypatch.setenv("TM_TABLES_CACHE_DIR", str(tmp_path))
     monkeypatch.setenv("TM_TABLES_CACHE_KEEP", "2")
     monkeypatch.setattr(aot_cache, "_TABLES_KEEP", 2)
-    t = np.zeros((4, 2, 8, 60), dtype=np.int32)
-    a = np.ones(4, dtype=bool)
+    t = np.zeros((64, 2, 8, 60), dtype=np.int32)
+    a = np.ones(64, dtype=bool)
     for i in range(4):
-        aot_cache.save_tables(bytes([i]) * 8, t, a, b"\x00" * 32)
+        aot_cache.save_tables(np.full((64, 32), i, dtype=np.uint8), t, a)
     left = [f for f in os.listdir(tmp_path) if f.endswith(".npz")]
     assert len(left) == 2
+    # by bytes, not by count: a few one-key files of later changes do
+    # not push the set's own file out
+    for i in range(4, 8):
+        aot_cache.save_tables(np.full((1, 32), i, dtype=np.uint8), t[:1], a[:1])
+    left = [f for f in os.listdir(tmp_path) if f.endswith(".npz")]
+    assert sum(f.endswith("-64.npz") for f in left) >= 1 and sum(f.endswith("-1.npz") for f in left) == 4

@@ -1102,10 +1102,9 @@ def _two_sets():
     vs, by_addr = make_vals([10] * 7)
     privs = [Ed25519PrivKey.from_secret(f"other{i}".encode()) for i in range(7)]
     other = ValidatorSet([Validator(p.pub_key(), 10) for p in privs])
-    specs = _chain_specs(4, {1: ("forge", 2)}, vs, by_addr) + _chain_specs(
+    return _chain_specs(4, {1: ("forge", 2)}, vs, by_addr) + _chain_specs(
         4, {2: ("forge", 0)}, other, {p.pub_key().address(): p for p in privs}
     )
-    return specs, [("batch", sum(_rows_of(specs, [None] * 8)))]
 
 
 def _with_trusting_spec():
@@ -1133,9 +1132,27 @@ def _with_secp_key():
     return specs, [("batch", sum(_rows_of(specs, [None] * 4)) - secp_rows)]
 
 
+def test_a_list_over_two_ed25519_sets_is_taken_as_groups_with_their_own_keys():
+    """A list that straddles a change of set stays a chain: each group
+    names the distinct keys of its commits' sets (7, both sets' 14, 7)
+    and its rows index them; the results are the direct calls'."""
+    specs = _two_sets()
+    want = [_direct(s) for s in specs]
+    assert [w is not None for w in want] == [j in (1, 6) for j in range(8)]
+    stub = GroupStub(_PER)
+    before = seam_counts()
+    assert _texts(verify_commits_batched(_fresh(specs), provider=stub)) == want
+    assert [e[0] for e in stub.events] == ["take", "launch"] * 3
+    assert [len(k.pubkeys) for k in stub.group_keys] == [7, 14, 7]
+    rows = sum(_rows_of(specs, want))
+    grew = seam_grew(before)
+    assert grew["multiset_rows"] == rows == sum(t[2] for t in stub.of("take"))
+    assert grew["overlapped_rows"] == sum(t[2] for t in stub.of("take")[1:])
+
+
 @pytest.mark.parametrize(
-    "build", [_two_sets, _with_trusting_spec, _with_secp_key],
-    ids=["mixed sets", "a trusting spec", "a non-ed25519 key"],
+    "build", [_with_trusting_spec, _with_secp_key],
+    ids=["a trusting spec", "a non-ed25519 key"],
 )
 def test_lists_that_are_no_same_set_chain_take_the_eager_path(build):
     """(d): nothing is taken as groups, nothing counts as overlapped,
