@@ -102,8 +102,13 @@ SEAM_COUNTS = NamedCounts(
 # plan_slots): ``slot_rows`` real rows verified in slot order (tables
 # read in place), ``slot_pad`` the empty slots launched with them,
 # ``gathered_rows`` rows whose ~30 KB key tables were gathered (sparse
-# or unordered batches, a mesh, sharded tables). Process-wide too.
-TABLED_COUNTS = NamedCounts("tabled", ("slot_rows", "slot_pad", "gathered_rows"))
+# or unordered batches, a mesh, sharded tables), ``kernel_slots`` the
+# slots (rows and pad) launched into a stage-2 program whose body is the
+# Pallas kernel form (ops/stage2_kernel.kernel_form: 0 on the CPU and
+# for a table operand off its shape rule). Process-wide too.
+TABLED_COUNTS = NamedCounts(
+    "tabled", ("slot_rows", "slot_pad", "gathered_rows", "kernel_slots")
+)
 
 # The key pool behind those tables (models/verifier._KeyPool): one key
 # table a validator key, whichever sets it appears in. ``keys_built``

@@ -62,6 +62,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from tendermint_tpu.ops import curve as ops_curve
 from tendermint_tpu.ops import ed25519 as ops_ed
+from tendermint_tpu.ops import stage2_kernel as ops_stage2
 from tendermint_tpu.parallel import pad_to_multiple
 from tendermint_tpu.parallel.mesh import BATCH_AXIS
 from tendermint_tpu.utils import faultinject as faults
@@ -1448,7 +1449,7 @@ class VerifierModel:
         dispatched is dropped uncounted."""
         outs = []  # (device verdicts, the rows' places in them) a launch
         cold = {}  # id -> entry this call compiles inline
-        rows = slot_rows = slots = 0
+        rows = slot_rows = slots = kernel_slots = 0
         t0 = None
         try:
             for piece in pieces:
@@ -1483,6 +1484,8 @@ class VerifierModel:
                     if at is not None:
                         slot_rows += hi - lo
                         slots += pad
+                        if ops_stage2.kernel_form(self._slot_table_rows(e), jax.default_backend()):
+                            kernel_slots += pad
                 rows += int(idx.shape[0])
             out = (
                 np.concatenate([np.asarray(o)[take] for o, take in outs])
@@ -1491,7 +1494,7 @@ class VerifierModel:
             self.row_counts.add(device=rows)
             self._tabled_counts.add(
                 slot_rows=slot_rows, slot_pad=slots - slot_rows,
-                gathered_rows=rows - slot_rows,
+                gathered_rows=rows - slot_rows, kernel_slots=kernel_slots,
             )
         except faults.InjectedFault:
             raise

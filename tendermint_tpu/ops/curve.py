@@ -356,12 +356,11 @@ def _comb256_halves() -> Tuple[np.ndarray, np.ndarray]:
     return (t >> 7).astype(np.float32), (t & 127).astype(np.float32)
 
 
-def _select_comb256(digits: jnp.ndarray) -> AffineCached:
-    """All 32 base-comb selections at once: digits (N, 32) signed in
-    [-128, 128) -> AffineCached of (N, 32, 20) (one selected entry per
-    digit position). One batched bf16 one-hot matmul per 7-bit half —
-    (N, 32, 128) x (32, 128, 60) rides the MXU."""
-    mag = jnp.abs(digits)  # (N, 32), values 0..128
+def _comb256_entries(mag: jnp.ndarray) -> jnp.ndarray:
+    """The base-comb entries of magnitudes mag (N, 32) in 0..128, one a
+    digit position: (N, 32, 60) int32, zeros for magnitude 0. One
+    batched bf16 one-hot matmul per 7-bit half — (N, 32, 128) x
+    (32, 128, 60) rides the MXU."""
     onehot = (
         mag[..., None] == jnp.arange(1, _COMB256 + 1, dtype=jnp.int32)
     ).astype(jnp.bfloat16)  # (N, 32, 128)
@@ -374,7 +373,14 @@ def _select_comb256(digits: jnp.ndarray) -> AffineCached:
         "npk,pkc->npc", onehot, jnp.asarray(lo_t, dtype=jnp.bfloat16),
         preferred_element_type=jnp.float32,
     )
-    sel = (hi.astype(jnp.int32) << 7) | lo.astype(jnp.int32)  # (N, 32, 60)
+    return (hi.astype(jnp.int32) << 7) | lo.astype(jnp.int32)
+
+
+def _select_comb256(digits: jnp.ndarray) -> AffineCached:
+    """All 32 base-comb selections at once: digits (N, 32) signed in
+    [-128, 128) -> AffineCached of (N, 32, 20) (one selected entry per
+    digit position)."""
+    sel = _comb256_entries(jnp.abs(digits))  # (N, 32, 60)
     sel = sel.reshape(*sel.shape[:-1], 3, F.LIMBS)
     ypx, ymx, t2d = sel[..., 0, :], sel[..., 1, :], sel[..., 2, :]
     zero = digits == 0

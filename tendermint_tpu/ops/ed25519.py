@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from tendermint_tpu.ops import curve
 from tendermint_tpu.ops import sc
+from tendermint_tpu.ops import stage2_kernel
 from tendermint_tpu.ops.sha512 import sha512
 
 
@@ -267,9 +268,11 @@ def verify_stage_scan_tabled_slots(sd, kd, tables, a_ok):
     slot on the host instead, and curve._tree_select broadcasts the
     table over the commit axis. Same digits, same additions, same
     verdict bit per row; empty slots (zero signatures) compute a
-    verdict nobody reads."""
+    verdict nobody reads. Lowered for a TPU with V a multiple of 1,024
+    the point arithmetic runs in the rows-on-lanes Pallas kernels
+    (stage2_kernel.kernel_form), bit-equal coordinates."""
     c = kd.shape[0] // tables.shape[0]
-    p = curve.double_scalar_mul_tabled(sd, kd, tables)
+    p = stage2_kernel.double_scalar_mul_slots(sd, kd, tables)
     return p.x, p.y, p.z, p.t, jnp.tile(a_ok, c)
 
 

@@ -339,6 +339,7 @@ class CryptoMetrics:
         ("tabled_slot_rows", "tabled_slot_rows"),
         ("tabled_slot_pad", "tabled_slot_pad"),
         ("tabled_gathered_rows", "tabled_gathered_rows"),
+        ("tabled_kernel_slots", "tabled_kernel_slots"),
         ("table_keys_built", "table_keys_built"),
         ("table_keys_loaded", "table_keys_loaded"),
         ("table_keys_reused", "table_keys_reused"),
@@ -368,6 +369,7 @@ class CryptoMetrics:
         self.tabled_slot_rows = reg(Counter("tabled_slot_rows_total", "Rows verified in slot order: key tables read in place.", namespace, sub))
         self.tabled_slot_pad = reg(Counter("tabled_slot_pad_total", "Empty slots launched with the slot-order rows.", namespace, sub))
         self.tabled_gathered_rows = reg(Counter("tabled_gathered_rows_total", "Rows verified with their key tables gathered per row.", namespace, sub))
+        self.tabled_kernel_slots = reg(Counter("tabled_kernel_slots_total", "Slots launched into a stage-2 program whose point arithmetic is the Pallas kernel form.", namespace, sub))
         self.table_keys_built = reg(Counter("table_keys_built_total", "Validator keys whose table the device built into the key pool.", namespace, sub))
         self.table_keys_loaded = reg(Counter("table_keys_loaded_total", "Validator keys whose table was read back from the table files.", namespace, sub))
         self.table_keys_reused = reg(Counter("table_keys_reused_total", "Validator keys a call asked for and found pooled.", namespace, sub))

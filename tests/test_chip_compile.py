@@ -104,18 +104,38 @@ def test_templated_tabled_prepare_compiles_for_v5e(topo, compile_for):
     compile_for(E.verify_stage_prepare_tabled_slots, 90, S((N, 32), u8), mg, S((N, 64), u8))
 
 
-def test_tabled_slot_scan_compiles_for_v5e(topo, compile_for):
-    """Stage 2, the dominant kernel, in slot order at C = 1 (one commit
-    of 10,240 slots) against a 10,240-key table (~315 MB resident
-    beside the program, read in place): it plans no copy of the table —
-    the gathered form plans 1,563 MB of temporaries, this at most 700."""
+# One launch of the XLA stage 2 at 10,240 slots was 21,469 fusions, ~28
+# a field multiplication, each about a microsecond of mostly fixed cost
+# (compile-only reading, ISSUE 36): the number the kernel form starts from.
+XLA_STAGE2_FUSIONS = 21_469
+
+
+@pytest.mark.parametrize("v,c", [(N, 1), (1024, 16)], ids=["commit-10240x1", "light-1024x16"])
+def test_tabled_slot_scan_compiles_for_v5e(topo, compile_for, v, c):
+    """Stage 2, the dominant kernel, in slot order at both cells'
+    shapes — one commit of 10,240 slots against a 10,240-key table
+    (~315 MB resident beside the program), 16 commits of 1,024 —
+    lowered for the TPU: the point arithmetic is the two Pallas kernels
+    (ops/stage2_kernel.py), and what XLA keeps around them (the digit
+    and table hand-over, the comb's MXU select) is at most a tenth of
+    the fusions the XLA body was, so the arithmetic cannot slide back
+    into XLA unseen. It plans one transposed copy of the table and of
+    the comb's entries, no more."""
+    import re
+
     S, like = shapes(SingleDeviceSharding(topo.devices[0]))
-    tables, a_ok = like(jax.eval_shape(E.build_valset_tables, S((N, 32), u8)))
+    tables, a_ok = like(jax.eval_shape(E.build_valset_tables, S((v, 32), u8)))
+    n = v * c
     sd, kd, _ = like(
-        jax.eval_shape(E.verify_stage_prepare_tabled_slots, S((N, 32), u8), S((N, 160), u8), S((N, 64), u8))
+        jax.eval_shape(E.verify_stage_prepare_tabled_slots, S((v, 32), u8), S((n, 160), u8), S((n, 64), u8))
     )
-    compiled = compile_for(E.verify_stage_scan_tabled_slots, 240, sd, kd, tables, a_ok)
+    compiled = compile_for(E.verify_stage_scan_tabled_slots, 120, sd, kd, tables, a_ok)
     assert compiled.memory_analysis().temp_size_in_bytes <= 700e6
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "stage2_window" in text and "stage2_comb" in text
+    fusions = len(re.findall(r" fusion\(", text))
+    assert fusions <= XLA_STAGE2_FUSIONS // 10, fusions
 
 
 def test_shard_map_scan_compiles_for_four_chips(topo, compile_for):
