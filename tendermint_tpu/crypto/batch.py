@@ -123,6 +123,15 @@ TABLE_COUNTS = NamedCounts(
     ("keys_built", "keys_loaded", "keys_reused", "keys_evicted", "slabs", "slab_columns"),
 )
 
+# The generic verify family (models/verifier.VerifierModel.verify: rows
+# with no validator set — key decompression, a per-row table, 256
+# doublings on the device): ``rows`` real rows verified, ``pad_rows`` the
+# empty rows launched with them up to a bucket, ``windows`` the full
+# MAX_DEVICE_ROWS windows a batch past that size streamed, ``launches``
+# every three-stage launch, a streamed batch's tail included. Counted
+# once the verdicts are read back; process-wide.
+GENERIC_COUNTS = NamedCounts("generic", ("rows", "pad_rows", "windows", "launches"))
+
 
 class GroupKeys(NamedTuple):
     """The distinct ed25519 keys some whole commits are checked

@@ -54,14 +54,17 @@ import time
 import numpy as np
 
 from tendermint_tpu.crypto.batch import (
-    SEAM_COUNTS, TABLE_COUNTS, TABLED_COUNTS, BatchVerifier, CPUBatchVerifier,
+    GENERIC_COUNTS, SEAM_COUNTS, TABLE_COUNTS, TABLED_COUNTS, BatchVerifier,
+    CPUBatchVerifier,
 )
 from tendermint_tpu.utils import faultinject as faults
 from tendermint_tpu.utils import trace
 
-# Largest single dispatch the grouper will build; matches the verifier
-# model's streaming window (models/verifier.py MAX_DEVICE_ROWS) so one
-# bundle never forces the windowed path.
+# Most rows the grouper coalesces into one bundle; matches the verifier
+# model's streaming window (models/verifier.py MAX_DEVICE_ROWS) so that
+# coalescing never forces the windowed path. A single item past it is a
+# bundle of its own and streams as windows and a tail (a DeliverBatch of
+# a full block's SigCache misses does).
 MAX_BUNDLE_ROWS = 16384
 
 # Template stacking cap per bundle (mirrors vote_set's byzantine-flood
@@ -628,6 +631,7 @@ class PipelinedVerifier(BatchVerifier):
         s.update(SEAM_COUNTS.snapshot())
         s.update(TABLED_COUNTS.snapshot())
         s.update(TABLE_COUNTS.snapshot())
+        s.update(GENERIC_COUNTS.snapshot())
         return s
 
     def engine_stats(self) -> Dict[str, object]:
@@ -662,11 +666,12 @@ class PipelinedVerifier(BatchVerifier):
         counters["cache_misses"] = cache["misses"]
         # the verify seam packs before it reaches any provider, so its
         # counts are the process's (crypto/batch.SEAM_COUNTS), as are the
-        # cached-table path's slot-order / gathered row counts and the
-        # key pool's
+        # cached-table path's slot-order / gathered row counts, the key
+        # pool's and the generic family's
         counters.update(SEAM_COUNTS.snapshot())
         counters.update(TABLED_COUNTS.snapshot())
         counters.update(TABLE_COUNTS.snapshot())
+        counters.update(GENERIC_COUNTS.snapshot())
         buckets: Dict[str, dict] = {}
         breakers: Dict[str, dict] = {}
         model = self.model  # the wrapped VerifierModel (None for CPU inner)

@@ -68,6 +68,7 @@ from tendermint_tpu.parallel.mesh import BATCH_AXIS
 from tendermint_tpu.utils import faultinject as faults
 from tendermint_tpu.utils.jaxenv import enable_compile_cache
 from tendermint_tpu.utils.log import get_logger
+from tendermint_tpu.utils.trace import span
 
 # Persistent compilation cache: the verifier graph is large; pay compile
 # once per machine, not per process.
@@ -503,7 +504,6 @@ class _KeyPool:
         tables of keys the pool lacks, and append them. The build runs
         outside the columns' lock: calls for pooled keys go on."""
         from tendermint_tpu.models import aot_cache
-        from tendermint_tpu.utils.trace import span
 
         model = self._model
         with self._build_serial, span("tables.build", keys=int(pk.shape[0])) as sp:
@@ -675,7 +675,9 @@ class VerifierModel:
         self, mesh=None, block_on_compile: bool = True, logger=None,
         row_counts=None,
     ):
-        from tendermint_tpu.crypto.batch import TABLED_COUNTS, CPUBatchVerifier, RowCounts
+        from tendermint_tpu.crypto.batch import (
+            GENERIC_COUNTS, TABLED_COUNTS, CPUBatchVerifier, RowCounts,
+        )
         from tendermint_tpu.utils.watchdog import CircuitBreaker
 
         self.mesh = mesh
@@ -688,6 +690,7 @@ class VerifierModel:
         self.row_counts = row_counts if row_counts is not None else RowCounts()
         self._cpu = CPUBatchVerifier(row_counts=self.row_counts)
         self._tabled_counts = TABLED_COUNTS
+        self._generic_counts = GENERIC_COUNTS
         self._lock = threading.Lock()
         self._entries: Dict[tuple, _Entry] = {}  # see compile_stats
         self._programs: Dict[str, object] = {}  # tag -> AotJit (_program)
@@ -887,13 +890,15 @@ class VerifierModel:
         if fn is None:  # cold bucket, non-blocking: host fallback
             return self._cpu.verify_batch(pubkeys, msgs, sigs)
         faults.maybe("device.verify")
-        ok = fn(
-            jnp.asarray(self._pad(np.asarray(pubkeys, dtype=np.uint8), n_pad)),
-            jnp.asarray(self._pad(np.asarray(msgs, dtype=np.uint8), n_pad)),
-            jnp.asarray(self._pad(np.asarray(sigs, dtype=np.uint8), n_pad)),
-        )
+        with span("generic.launch", rows=n, bucket=n_pad):
+            ok = fn(
+                jnp.asarray(self._pad(np.asarray(pubkeys, dtype=np.uint8), n_pad)),
+                jnp.asarray(self._pad(np.asarray(msgs, dtype=np.uint8), n_pad)),
+                jnp.asarray(self._pad(np.asarray(sigs, dtype=np.uint8), n_pad)),
+            )
         out = np.asarray(ok)[:n]
         self.row_counts.add(device=n)
+        self._generic_counts.add(rows=n, pad_rows=n_pad - n, launches=1)
         return out
 
     def _verify_windowed(self, pubkeys, msgs, sigs, msg_len: int) -> np.ndarray:
@@ -910,12 +915,13 @@ class VerifierModel:
         sg = np.asarray(sigs, dtype=np.uint8)
         # every full window in flight, exactly `window` rows a slice
         tail_start = (n // window) * window
-        outs = [
-            fn(*(jnp.asarray(a[off : off + window]) for a in (pk, mg, sg)))
-            for off in range(0, tail_start, window)
-        ]
+        outs = []
+        for off in range(0, tail_start, window):
+            with span("generic.launch", rows=window, bucket=window):
+                outs.append(fn(*(jnp.asarray(a[off : off + window]) for a in (pk, mg, sg))))
         parts = [np.asarray(o) for o in outs]
         self.row_counts.add(device=tail_start)
+        self._generic_counts.add(rows=tail_start, windows=len(outs), launches=len(outs))
         if tail_start < n:
             parts.append(self.verify(pk[tail_start:], mg[tail_start:], sg[tail_start:]))
         return np.concatenate(parts)
@@ -1221,8 +1227,6 @@ class VerifierModel:
     def _slab(self, tables, a_ok, pk, cols: np.ndarray):
         """Columns ``cols`` of the key pool's arrays, gathered on the
         device (ops_ed.table_slab)."""
-        from tendermint_tpu.utils.trace import span
-
         with span("tables.slab", columns=int(cols.shape[0])):
             return self._program("t-slab")(tables, a_ok, pk, jnp.asarray(cols))
 

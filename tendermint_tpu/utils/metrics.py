@@ -346,6 +346,10 @@ class CryptoMetrics:
         ("table_keys_evicted", "table_keys_evicted"),
         ("table_slabs", "table_slabs"),
         ("table_slab_columns", "table_slab_columns"),
+        ("generic_rows", "generic_rows"),
+        ("generic_pad_rows", "generic_pad_rows"),
+        ("generic_windows", "generic_windows"),
+        ("generic_launches", "generic_launches"),
     )
 
     def __init__(self, registry: Optional[Registry] = None, namespace="tendermint"):
@@ -376,6 +380,10 @@ class CryptoMetrics:
         self.table_keys_evicted = reg(Counter("table_keys_evicted_total", "Least-recently-used keys dropped from the key pool under its byte bound.", namespace, sub))
         self.table_slabs = reg(Counter("table_slabs_total", "Launches whose table operand was gathered from the key pool.", namespace, sub))
         self.table_slab_columns = reg(Counter("table_slab_columns_total", "Key-table columns those gathers copied.", namespace, sub))
+        self.generic_rows = reg(Counter("generic_rows_total", "Rows the generic verify family verified on the device: no validator set, the key decompressed and tabled per row.", namespace, sub))
+        self.generic_pad_rows = reg(Counter("generic_pad_rows_total", "Empty rows launched with the generic rows up to their bucket.", namespace, sub))
+        self.generic_windows = reg(Counter("generic_windows_total", "Full 16,384-row windows that generic batches past one launch streamed.", namespace, sub))
+        self.generic_launches = reg(Counter("generic_launches_total", "Generic three-stage launches, a streamed batch's tail included.", namespace, sub))
         self._deltas = _SnapshotCounters()
 
     def update(self, stats: dict) -> None:
