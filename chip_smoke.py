@@ -316,7 +316,9 @@ def phase_commit(seed: int, prov, errors: ErrorLog, n_vals: int = COMMIT_VALS, k
             f"templated {t_tpl:.3f}s generic {t_gen:.3f}s (first case includes compile and table build)"
         )
     check_device_did_the_work(prov, errors, rows0, submitted, "slots-tpl")
-    check_stage2_forms(prov, key, all_pk, *vals._commit_batch_arrays(chain_id, good, by_address=False)[3:5])
+    rows = vals._commit_batch_arrays(chain_id, good, by_address=False)
+    check_stage2_forms(prov, key, all_pk, *rows[3:5])
+    check_generic_forms(prov, *rows[2:5])
 
 
 def check_stage2_forms(prov, key, all_pk, msgs, sigs):
@@ -345,6 +347,36 @@ def check_stage2_forms(prov, key, all_pk, msgs, sigs):
     say(
         f"stage 2 in both forms over {v} slots: x, y, z, t equal (canonical), "
         f"tabled_kernel_slots={kernel_slots} ({time.perf_counter() - t0:.1f}s, both compiles included)"
+    )
+
+
+def check_generic_forms(prov, pk, msgs, sigs):
+    """The generic stage 2 in both forms on the same rows, on the chip:
+    the Pallas kernel form (stage2_kernel.generic_scan) that the generic
+    verify_commit_batch launches above ran, against the XLA body
+    curve.double_scalar_mul_signed that stays as its oracle, over the
+    valid commit's rows padded to their 10,240-row bucket. Bit-equal:
+    every limb of x, y, z, t."""
+    import jax
+    import numpy as np
+
+    from tendermint_tpu.crypto.batch import GENERIC_COUNTS
+    from tendermint_tpu.models.verifier import _bucket
+    from tendermint_tpu.ops import curve, ed25519
+
+    n = _bucket(len(pk), 1)
+    pad = lambda a: np.pad(np.asarray(a, dtype=np.uint8), ((0, n - len(a)), (0, 0)))  # noqa: E731
+    sd, kd, nx, ny, nz, nt, _, _ = prov.model._program("prepare")(pad(pk), pad(msgs), pad(sigs))
+    t0 = time.perf_counter()
+    kern = jax.jit(ed25519.verify_stage_scan)(sd, kd, nx, ny, nz, nt)
+    xla = jax.jit(curve.double_scalar_mul_signed)(sd, kd, curve.Point(nx, ny, nz, nt))
+    equal = [bool((np.asarray(x) == np.asarray(y)).all()) for x, y in zip(kern, xla)]
+    check(all(equal), f"generic stage 2: kernel form and XLA body differ in coordinates {equal} of x, y, z, t")
+    kernel_rows = GENERIC_COUNTS.snapshot()["generic_kernel_rows"]
+    check(kernel_rows > 0, "generic_kernel_rows is 0: no generic launch had the kernel form")
+    say(
+        f"generic stage 2 in both forms over {n} rows: x, y, z, t bit-equal, "
+        f"generic_kernel_rows={kernel_rows} ({time.perf_counter() - t0:.1f}s, both compiles included)"
     )
 
 

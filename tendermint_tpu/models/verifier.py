@@ -854,6 +854,12 @@ class VerifierModel:
         mult = self._pad_multiple()
         return max((cap // mult) * mult, mult)
 
+    def _kernel_rows(self, n_pad: int) -> int:
+        """n_pad where a generic launch of that many rows has the kernel
+        form of stage 2 (each device's rows under a mesh), else 0."""
+        per_device = n_pad // self._pad_multiple()
+        return n_pad if ops_stage2.kernel_form(per_device, jax.default_backend()) else 0
+
     def _pad(self, arr: np.ndarray, n_pad: int) -> np.ndarray:
         n = arr.shape[0]
         if n == n_pad:
@@ -898,7 +904,9 @@ class VerifierModel:
             )
         out = np.asarray(ok)[:n]
         self.row_counts.add(device=n)
-        self._generic_counts.add(rows=n, pad_rows=n_pad - n, launches=1)
+        self._generic_counts.add(
+            rows=n, pad_rows=n_pad - n, launches=1, kernel_rows=self._kernel_rows(n_pad)
+        )
         return out
 
     def _verify_windowed(self, pubkeys, msgs, sigs, msg_len: int) -> np.ndarray:
@@ -921,7 +929,10 @@ class VerifierModel:
                 outs.append(fn(*(jnp.asarray(a[off : off + window]) for a in (pk, mg, sg))))
         parts = [np.asarray(o) for o in outs]
         self.row_counts.add(device=tail_start)
-        self._generic_counts.add(rows=tail_start, windows=len(outs), launches=len(outs))
+        self._generic_counts.add(
+            rows=tail_start, windows=len(outs), launches=len(outs),
+            kernel_rows=self._kernel_rows(window) * len(outs),
+        )
         if tail_start < n:
             parts.append(self.verify(pk[tail_start:], mg[tail_start:], sg[tail_start:]))
         return np.concatenate(parts)

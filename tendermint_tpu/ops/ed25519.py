@@ -72,8 +72,11 @@ def verify_stage_prepare(pubkeys, msgs, sigs):
 
 
 def verify_stage_scan(sd, kd, nx, ny, nz, nt):
-    """Stage 2: the Straus double-scalar-mult scan (the dominant cost)."""
-    p = curve.double_scalar_mul_signed(sd, kd, curve.Point(nx, ny, nz, nt))
+    """Stage 2: the Straus double-scalar-mult scan (the dominant cost).
+    Lowered for a TPU with rows a multiple of 1,024 the table build and
+    the scan run in the rows-on-lanes Pallas kernel
+    (stage2_kernel.generic_scan), bit-equal coordinates."""
+    p = stage2_kernel.double_scalar_mul_rows(sd, kd, curve.Point(nx, ny, nz, nt))
     return p.x, p.y, p.z, p.t
 
 

@@ -138,20 +138,60 @@ def test_tabled_slot_scan_compiles_for_v5e(topo, compile_for, v, c):
     assert fusions <= XLA_STAGE2_FUSIONS // 10, fusions
 
 
-def test_shard_map_scan_compiles_for_four_chips(topo, compile_for):
-    """The mesh form of the generic scan: rows shard over four devices,
-    and the per-device program is the single-device one at N/4 rows."""
+# The generic stage 2 as the XLA body at 16,384 rows: a rolled loop of
+# 1,478 fusions and 130,903,040 bytes of temporaries (26,178,560 at
+# 4,096 rows), compile-only readings of curve.double_scalar_mul_signed
+# for v5e: what the kernel form starts from.
+XLA_GENERIC_FUSIONS = 1_478
+XLA_GENERIC_TEMP_BYTES = {16_384: 130_903_040, 4_096: 26_178_560}
+
+
+@pytest.mark.parametrize("n", sorted(XLA_GENERIC_TEMP_BYTES, reverse=True))
+def test_generic_scan_compiles_for_v5e(topo, compile_for, n):
+    """Stage 2 of the generic family ("scan") at the batch cell's two
+    buckets, lowered for the TPU: the table build and the 64 windows
+    are ONE Pallas kernel, generic_scan (ops/stage2_kernel.py); what XLA
+    keeps around it (the digits and points laid rows on lanes and back)
+    is at most a tenth of the XLA body's fusions, and it plans no more
+    temporaries than the XLA body did."""
+    import re
+
+    S, _ = shapes(SingleDeviceSharding(topo.devices[0]))
+    args = (S((n, 64), i32),) * 2 + (S((n, 20), i32),) * 4
+    compiled = compile_for(E.verify_stage_scan, 90, *args)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "generic_scan" in text
+    fusions = len(re.findall(r" fusion\(", text))
+    assert fusions <= XLA_GENERIC_FUSIONS // 10, fusions
+    assert compiled.memory_analysis().temp_size_in_bytes <= XLA_GENERIC_TEMP_BYTES[n]
+
+
+def _mesh_scan(topo, compile_for, n, kernel):
     from tendermint_tpu.models.verifier import VerifierModel
 
     mesh = Mesh(np.array(topo.devices[:4]), (BATCH_AXIS,))
     S, like = shapes(NamedSharding(mesh, P(BATCH_AXIS)))
     pre = like(
-        jax.eval_shape(E.verify_stage_prepare, S((N, 32), u8), S((N, 160), u8), S((N, 64), u8))
+        jax.eval_shape(E.verify_stage_prepare, S((n, 32), u8), S((n, 160), u8), S((n, 64), u8))
     )
     _, scan = VerifierModel(mesh=mesh)._stages()
     compiled = compile_for(scan._jit, 90, *pre[:6])
     out = compiled.output_shardings
     assert all(s.spec == P(BATCH_AXIS) for s in jax.tree_util.tree_leaves(out)), out
+    assert ("generic_scan" in compiled.as_text()) == kernel
+
+
+def test_shard_map_scan_compiles_for_four_chips(topo, compile_for):
+    """The mesh form of the generic scan: rows shard over four devices,
+    and the per-device program is the single-device one at N/4 rows —
+    2,560, off the kernel form's rule: the XLA body."""
+    _mesh_scan(topo, compile_for, N, kernel=False)
+
+
+def test_shard_map_scan_on_the_rule_compiles_for_four_chips(topo, compile_for):
+    """16,384 rows over four devices: 4,096 a device, on the rule, so
+    each device's program holds the kernel form."""
+    _mesh_scan(topo, compile_for, 16_384, kernel=True)
 
 
 def test_table_slab_and_build_bucket_compile_for_v5e(topo, compile_for):

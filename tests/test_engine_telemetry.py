@@ -242,7 +242,7 @@ def test_pipeline_publishes_overlapped_rows():
     "name",
     ["seam_multiset_rows", "table_keys_built", "table_keys_loaded", "table_keys_reused",
      "table_keys_evicted", "table_slabs", "table_slab_columns", "generic_rows",
-     "generic_pad_rows", "generic_windows", "generic_launches"],
+     "generic_pad_rows", "generic_windows", "generic_launches", "generic_kernel_rows"],
 )
 def test_pipeline_publishes_key_pool_and_multiset_counts(name):
     """What a node reads to tell "my window straddled a set change and

@@ -65,8 +65,15 @@ def test_windowed_verdicts_equal_the_reference_and_counters_say_what_ran(batch, 
     np.testing.assert_array_equal(np.asarray(got, dtype=bool), want)
 
     after = provider.engine_stats()["counters"]
-    grew = {k: after[k] - before[k] for k in ("generic_rows", "generic_pad_rows", "generic_windows", "generic_launches")}
-    assert grew == {"generic_rows": 40, "generic_pad_rows": 8, "generic_windows": 2, "generic_launches": 3}
+    grew = {
+        k: after[k] - before[k]
+        for k in ("generic_rows", "generic_pad_rows", "generic_windows", "generic_launches", "generic_kernel_rows")
+    }
+    # the CPU lowers stage 2 to the XLA body: no row reaches the kernel form
+    assert grew == {
+        "generic_rows": 40, "generic_pad_rows": 8, "generic_windows": 2, "generic_launches": 3,
+        "generic_kernel_rows": 0,
+    }
     assert provider.stats()["generic_launches"] == after["generic_launches"]
 
     launches = [e for e in tracer._snapshot() if e[1] == "generic.launch"]

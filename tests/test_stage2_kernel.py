@@ -95,6 +95,77 @@ def test_point_bodies_match_curve(want_t):
         )
 
 
+_GENERIC_OPS = {
+    "add": lambda p, q: (K.point_add(p, q), curve.add(_pt(p), _pt(q))),
+    "to_cached": lambda p, q: (K.to_cached(p), curve.to_cached(_pt(p))),
+    "add_cached_t": lambda p, q: (
+        K.add_cached(p, q), curve.add_cached(_pt(p), curve.CachedPoint(*_jnp(q)))
+    ),
+    "add_cached_no_t": lambda p, q: (
+        K.add_cached(p, q, want_t=False),
+        curve.add_cached(_pt(p), curve.CachedPoint(*_jnp(q)), want_t=False),
+    ),
+}
+
+
+def _jnp(p):
+    return tuple(jnp.stack(c, axis=-1) for c in p)
+
+
+def _pt(p):
+    return curve.Point(*_jnp(p))
+
+
+@pytest.mark.parametrize("op", sorted(_GENERIC_OPS))
+def test_generic_point_ops_match_curve(op):
+    """The generic family's point operations on limb lists against
+    ops/curve.py's, on random weak points and on the identity."""
+    rng = np.random.default_rng(43)
+    ident = np.stack([np.asarray(c) for c in curve.identity((ROWS,))])
+    for p in (_weak(rng, 4, ROWS), ident):
+        got, want = _GENERIC_OPS[op](_point_lists(p), _point_lists(_weak(rng, 4, ROWS)))
+        _assert_point(got, want)
+
+
+def test_constant_operand_folds_and_stays_bit_equal():
+    """A field multiplication by a constant of Python-int limbs (2d,
+    the base table's 2Z = 2) equals ops/field.py's by the same constant
+    as an array, zero limbs folded away at trace time."""
+    rng = np.random.default_rng(44)
+    a = _weak(rng, ROWS)
+    for c in (K._D2, K._BASE_CACHED[3][2 * L : 3 * L]):
+        got = _stack(K.mul(_lists(a), list(c)))
+        want = np.asarray(F.mul(jnp.asarray(a), jnp.asarray(np.asarray(c, np.int32))))
+        np.testing.assert_array_equal(got, want)
+
+
+_SIGNED_DIGITS = {  # what signed_digits gives: [-8, 8)
+    "zero": lambda rng, n: np.zeros(n, dtype=np.int32),
+    "minus8": lambda rng, n: np.full(n, -8, dtype=np.int32),
+    "plus7": lambda rng, n: np.full(n, 7, dtype=np.int32),
+    "mixed": lambda rng, n: rng.integers(-8, 8, size=n).astype(np.int32),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(_SIGNED_DIGITS))
+def test_cached_select_matches_select_signed(kind):
+    """The 80-limb cached select — a table a row, and the constant base
+    table whose shared limbs fold — against curve._select_signed."""
+    rng = np.random.default_rng(45)
+    table = rng.integers(0, F.WEAK_MAX + 1, size=(ROWS, 8, 4 * L), dtype=np.int32)
+    digit = _SIGNED_DIGITS[kind](rng, ROWS)
+    d, mag = jnp.asarray(digit), jnp.abs(jnp.asarray(digit))
+    base = np.asarray(curve._BASE_TABLE, np.int32).reshape(8, 4 * L)
+    for entry, want in (
+        (lambda e, l: jnp.asarray(table[:, e, l]), curve._select_signed(jnp.asarray(table), d)),
+        (lambda e, l: K._BASE_CACHED[e][l], curve._select_signed(jnp.asarray(base), d)),
+    ):
+        got = K.signed_operand(K.tree_select(entry, mag, 4 * L), d)
+        for g, w, name in zip(got, want, ("ypx", "ymx", "z2", "t2d")):
+            g = [jnp.broadcast_to(x, (ROWS,)) for x in g]
+            np.testing.assert_array_equal(_stack(g), np.asarray(w), err_msg=name)
+
+
 def test_doubling_run_matches_window_doublings():
     """What the window kernel does before a window's first split: three
     doublings without T, one with — on the identity too."""
@@ -229,6 +300,15 @@ def test_kernel_form_rule():
     assert not K.kernel_form(128, "tpu") and not K.kernel_form(1000, "tpu")
 
 
+def test_generic_buckets_on_the_rule():
+    """The generic family's buckets: 1,024 rows and up take the kernel
+    form on a TPU, the three below it keep the XLA body."""
+    from tendermint_tpu.models.verifier import _BUCKETS
+
+    assert [b for b in _BUCKETS if K.kernel_form(b, "tpu")] == [1024, 4096, 10240, 16384]
+    assert not any(K.kernel_form(b, "cpu") for b in _BUCKETS)
+
+
 def _lowered(fn, platform, *args):
     return jax.jit(fn).trace(*args).lower(lowering_platforms=(platform,)).as_text()
 
@@ -264,7 +344,7 @@ def test_body_is_chosen_by_lowering_platform_and_shape():
 
 def test_gathered_sharded_and_generic_programs_hold_no_kernel():
     """The other stage-2 families keep the XLA body whatever platform
-    they are lowered for."""
+    they are lowered for, and the generic one off its rule (16 rows)."""
     S, i32 = jax.ShapeDtypeStruct, jnp.int32
     n = 16
     sd, kd, idx = S((n, 32), i32), S((n, 64), i32), S((n,), i32)
@@ -276,6 +356,49 @@ def test_gathered_sharded_and_generic_programs_hold_no_kernel():
         (E.verify_stage_scan, (kd, kd, limbs, limbs, limbs, limbs)),
     ):
         assert "tpu_custom_call" not in _lowered(fn, "tpu", *args), fn.__name__
+
+
+def _scan_args(n):
+    S, i32 = jax.ShapeDtypeStruct, jnp.int32
+    return (S((n, 64), i32),) * 2 + (S((n, L), i32),) * 4
+
+
+def test_generic_body_is_chosen_by_lowering_platform_and_shape():
+    """verify_stage_scan at 1,024 rows holds exactly one Mosaic call,
+    generic_scan, lowered for a TPU, and none lowered for the CPU; at 16
+    rows, lowered for a TPU, it IS the XLA body: the same StableHLO text
+    as curve.double_scalar_mul_signed's own lowering."""
+    traced = jax.jit(E.verify_stage_scan).trace(*_scan_args(K.BLOCK_ROWS))
+    tpu = traced.lower(lowering_platforms=("tpu",)).as_text()
+    assert tpu.count("tpu_custom_call") == 1 and "generic_scan" in tpu
+    cpu = traced.lower(lowering_platforms=("cpu",)).as_text()
+    assert "tpu_custom_call" not in cpu and "generic_scan" not in cpu
+
+    def xla_body(sd, kd, nx, ny, nz, nt):
+        p = curve.double_scalar_mul_signed(sd, kd, curve.Point(nx, ny, nz, nt))
+        return p.x, p.y, p.z, p.t
+
+    def verify_stage_scan(*args):
+        return E.verify_stage_scan(*args)
+
+    got = _lowered(verify_stage_scan, "tpu", *_scan_args(16)).replace("verify_stage_scan", "xla_body")
+    assert got == _lowered(xla_body, "tpu", *_scan_args(16))
+
+
+def test_generic_kernel_rows_follow_the_rule(monkeypatch):
+    """What a generic launch adds to kernel_rows: its rows where each
+    device's share is on the rule, else 0 — on the CPU always 0."""
+    from tendermint_tpu.models import verifier as mv
+
+    class Mesh4:
+        shape = {"batch": 4}
+
+    m = mv.VerifierModel(block_on_compile=True)
+    assert [m._kernel_rows(b) for b in (256, 1024, 16384)] == [0, 0, 0]
+    monkeypatch.setattr(mv.jax, "default_backend", lambda: "tpu")
+    assert [m._kernel_rows(b) for b in (256, 1024, 4096, 16384)] == [0, 1024, 4096, 16384]
+    m.mesh = Mesh4()
+    assert [m._kernel_rows(b) for b in (1024, 4096, 10240, 16384)] == [0, 4096, 0, 16384]
 
 
 def test_kernel_slots_stay_zero_on_the_cpu():
@@ -297,3 +420,4 @@ def test_kernel_slots_stay_zero_on_the_cpu():
     assert after["tabled_slot_rows"] - before["tabled_slot_rows"] == 16
     assert after["tabled_kernel_slots"] == before["tabled_kernel_slots"] == 0
     assert ("tabled_kernel_slots", "tabled_kernel_slots") in CryptoMetrics._COUNTERS
+    assert ("generic_kernel_rows", "generic_kernel_rows") in CryptoMetrics._COUNTERS
