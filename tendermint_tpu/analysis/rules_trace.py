@@ -7,8 +7,9 @@ slice name back to code and meaning. PR 12's cross-node propagation
 review found link/flow names that existed only in code; this rule is
 the metrics-coherence discipline applied to the flight recorder: a
 literal name passed to ``span()``/``instant()``/``flow_start()``/
-``flow_end()``/``link()`` — on the ``trace`` module or any tracer
-object — must appear in docs/tracing.md. Dynamically built names
+``flow_end()``/``link()`` — on the ``trace`` module, on any tracer
+object, or called bare after ``from tendermint_tpu.utils.trace import
+span`` (absolute or relative) — must appear in docs/tracing.md. Dynamically built names
 (``"consensus." + step``) are out of static reach and are skipped; the
 step-span names they produce are documented as the per-step rows.
 """
@@ -17,7 +18,7 @@ from __future__ import annotations
 
 import ast
 import re
-from typing import Iterable, List, Optional, Set
+from typing import Dict, Iterable, List, Optional, Set, Tuple
 
 from tendermint_tpu.analysis.core import (
     FileContext,
@@ -37,22 +38,33 @@ _METHODS = {"span": 0, "instant": 0, "flow_start": 0, "flow_end": 0, "link": 1}
 _NAME_RE = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z0-9_]+)+$")
 
 
-def _trace_aliases(tree: ast.AST) -> Set[str]:
-    """Local names bound to the trace module in this file."""
+def _is_trace_module(node: ast.ImportFrom) -> bool:
+    """``from tendermint_tpu.utils.trace import ...`` or a relative
+    ``from ..utils.trace import ...``."""
+    mod = node.module or ""
+    return mod == _TRACE_MODULE or (node.level > 0 and (mod == "utils.trace" or mod == "trace"))
+
+
+def _trace_aliases(tree: ast.AST) -> Tuple[Set[str], Dict[str, str]]:
+    """Local names bound to the trace module in this file, and local
+    names bound to its recording functions (local name -> function)."""
     out: Set[str] = set()
+    funcs: Dict[str, str] = {}
     for node in ast.walk(tree):
         if isinstance(node, ast.ImportFrom):
-            if node.module == "tendermint_tpu.utils":
+            if node.module == "tendermint_tpu.utils" or (node.level > 0 and node.module == "utils"):
                 for a in node.names:
                     if a.name == "trace":
                         out.add(a.asname or a.name)
-            elif node.module == _TRACE_MODULE:
-                pass  # direct-function imports handled by name grammar
+            elif _is_trace_module(node):
+                for a in node.names:
+                    if a.name in _METHODS:
+                        funcs[a.asname or a.name] = a.name
         elif isinstance(node, ast.Import):
             for a in node.names:
                 if a.name == _TRACE_MODULE and a.asname:
                     out.add(a.asname)
-    return out
+    return out, funcs
 
 
 def _literal_name(call: ast.Call, idx: int) -> Optional[str]:
@@ -75,20 +87,22 @@ class TraceCoherence(Rule):
         if ctx.tree is None or not ctx.in_package:
             return ()
         docs = project.docs_text(_DOCS)
-        aliases = _trace_aliases(ctx.tree)
+        aliases, funcs = _trace_aliases(ctx.tree)
         out: List[Violation] = []
         for node in ctx.nodes:
-            if not (
-                isinstance(node, ast.Call)
-                and isinstance(node.func, ast.Attribute)
-                and node.func.attr in _METHODS
-            ):
+            if not isinstance(node, ast.Call):
                 continue
-            name = _literal_name(node, _METHODS[node.func.attr])
+            if isinstance(node.func, ast.Attribute) and node.func.attr in _METHODS:
+                recv = node.func.value
+                on_module = isinstance(recv, ast.Name) and recv.id in aliases
+                method = node.func.attr
+            elif isinstance(node.func, ast.Name) and node.func.id in funcs:
+                on_module, method = True, funcs[node.func.id]
+            else:
+                continue
+            name = _literal_name(node, _METHODS[method])
             if name is None:
                 continue
-            recv = node.func.value
-            on_module = isinstance(recv, ast.Name) and recv.id in aliases
             if not on_module and not _NAME_RE.match(name):
                 continue  # not span-name shaped and not our module: skip
             if name not in docs:

@@ -137,6 +137,13 @@ GENERIC_COUNTS = NamedCounts(
     "generic", ("rows", "pad_rows", "windows", "launches", "kernel_rows")
 )
 
+# Host-to-device bytes of served launches (models/verifier.py
+# VerifierModel._to_device): every array a tabled or generic launch, or a
+# launch's table slab, copies to the device, signatures, messages or
+# their templates, indices and pad slots alike. Table builds and the
+# warm passes on zeros are set-up and not counted. Process-wide.
+H2D_COUNTS = NamedCounts("h2d", ("bytes",))
+
 
 class GroupKeys(NamedTuple):
     """The distinct ed25519 keys some whole commits are checked

@@ -54,7 +54,7 @@ import time
 import numpy as np
 
 from tendermint_tpu.crypto.batch import (
-    GENERIC_COUNTS, SEAM_COUNTS, TABLE_COUNTS, TABLED_COUNTS, BatchVerifier,
+    GENERIC_COUNTS, H2D_COUNTS, SEAM_COUNTS, TABLE_COUNTS, TABLED_COUNTS, BatchVerifier,
     CPUBatchVerifier,
 )
 from tendermint_tpu.utils import faultinject as faults
@@ -498,7 +498,7 @@ class PipelinedVerifier(BatchVerifier):
         return fut
 
     def _enqueue(self, item: _Item) -> None:
-        with self._cv:
+        with trace.span("pipeline.submit"), self._cv:
             if not self._stopped:
                 self._q.append(item)
                 self.submitted_calls += 1
@@ -525,7 +525,8 @@ class PipelinedVerifier(BatchVerifier):
 
     def _await_or_serial(self, fut: Future, serial):
         try:
-            return fut.result()
+            with trace.span("pipeline.wait"):
+                return fut.result()
         except Exception as e:
             if not _is_liveness_error(e):
                 raise
@@ -632,6 +633,7 @@ class PipelinedVerifier(BatchVerifier):
         s.update(TABLED_COUNTS.snapshot())
         s.update(TABLE_COUNTS.snapshot())
         s.update(GENERIC_COUNTS.snapshot())
+        s.update(H2D_COUNTS.snapshot())
         return s
 
     def engine_stats(self) -> Dict[str, object]:
@@ -667,11 +669,12 @@ class PipelinedVerifier(BatchVerifier):
         # the verify seam packs before it reaches any provider, so its
         # counts are the process's (crypto/batch.SEAM_COUNTS), as are the
         # cached-table path's slot-order / gathered row counts, the key
-        # pool's and the generic family's
+        # pool's, the generic family's and the launches' H2D bytes
         counters.update(SEAM_COUNTS.snapshot())
         counters.update(TABLED_COUNTS.snapshot())
         counters.update(TABLE_COUNTS.snapshot())
         counters.update(GENERIC_COUNTS.snapshot())
+        counters.update(H2D_COUNTS.snapshot())
         buckets: Dict[str, dict] = {}
         breakers: Dict[str, dict] = {}
         model = self.model  # the wrapped VerifierModel (None for CPU inner)

@@ -24,6 +24,7 @@ from typing import List, Optional, Tuple
 from tendermint_tpu.light.types import DEFAULT_TRUST_LEVEL, SignedHeader
 from tendermint_tpu.lightserve import core
 from tendermint_tpu.types.validator_set import CommitVerifySpec, ValidatorSet
+from tendermint_tpu.utils.trace import span
 
 DEFAULT_CLOCK_DRIFT_NS = 10 * 10**9  # 10s (reference defaultClockDrift)
 
@@ -248,18 +249,19 @@ def verify_chain(
     specs: List[CommitVerifySpec] = []
     spec_links: List[Tuple[int, str]] = []  # (link_idx, kind) parallel to specs
     cur_sh, cur_vals = trusted, trusted_vals
-    for li, (sh, vals) in enumerate(chain):
-        try:
-            link = link_specs(
-                chain_id, cur_sh, cur_vals, sh, vals,
-                trusting_period_ns, trust_level, now, clock_drift_ns,
-            )
-        except ErrInvalidHeader as e:
-            raise ErrInvalidHeader(f"link {li}: {e}") from None
-        for kind, s in link:
-            specs.append(s)
-            spec_links.append((li, kind))
-        cur_sh, cur_vals = sh, vals
+    with span("verify.links"):
+        for li, (sh, vals) in enumerate(chain):
+            try:
+                link = link_specs(
+                    chain_id, cur_sh, cur_vals, sh, vals,
+                    trusting_period_ns, trust_level, now, clock_drift_ns,
+                )
+            except ErrInvalidHeader as e:
+                raise ErrInvalidHeader(f"link {li}: {e}") from None
+            for kind, s in link:
+                specs.append(s)
+                spec_links.append((li, kind))
+            cur_sh, cur_vals = sh, vals
 
     results = core.verify_specs(specs, provider=provider)  # ★ one device call
     for (li, kind), err in zip(spec_links, results):

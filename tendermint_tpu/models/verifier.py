@@ -676,7 +676,7 @@ class VerifierModel:
         row_counts=None,
     ):
         from tendermint_tpu.crypto.batch import (
-            GENERIC_COUNTS, TABLED_COUNTS, CPUBatchVerifier, RowCounts,
+            GENERIC_COUNTS, H2D_COUNTS, TABLED_COUNTS, CPUBatchVerifier, RowCounts,
         )
         from tendermint_tpu.utils.watchdog import CircuitBreaker
 
@@ -691,6 +691,7 @@ class VerifierModel:
         self._cpu = CPUBatchVerifier(row_counts=self.row_counts)
         self._tabled_counts = TABLED_COUNTS
         self._generic_counts = GENERIC_COUNTS
+        self._h2d_counts = H2D_COUNTS
         self._lock = threading.Lock()
         self._entries: Dict[tuple, _Entry] = {}  # see compile_stats
         self._programs: Dict[str, object] = {}  # tag -> AotJit (_program)
@@ -867,6 +868,12 @@ class VerifierModel:
         pad = np.zeros((n_pad - n,) + arr.shape[1:], dtype=arr.dtype)
         return np.concatenate([arr, pad], axis=0)
 
+    def _to_device(self, *arrays: np.ndarray) -> tuple:
+        """Host arrays of a served launch on the device, their bytes
+        counted (crypto/batch.H2D_COUNTS)."""
+        self._h2d_counts.add(bytes=sum(int(a.nbytes) for a in arrays))
+        return tuple(jnp.asarray(a) for a in arrays)
+
     # -- public API --------------------------------------------------------
 
     def verify(self, pubkeys, msgs, sigs, msg_lens=None) -> np.ndarray:
@@ -897,12 +904,14 @@ class VerifierModel:
             return self._cpu.verify_batch(pubkeys, msgs, sigs)
         faults.maybe("device.verify")
         with span("generic.launch", rows=n, bucket=n_pad):
-            ok = fn(
-                jnp.asarray(self._pad(np.asarray(pubkeys, dtype=np.uint8), n_pad)),
-                jnp.asarray(self._pad(np.asarray(msgs, dtype=np.uint8), n_pad)),
-                jnp.asarray(self._pad(np.asarray(sigs, dtype=np.uint8), n_pad)),
-            )
-        out = np.asarray(ok)[:n]
+            with span("launch.stage"):
+                args = self._to_device(*(
+                    self._pad(np.asarray(a, dtype=np.uint8), n_pad) for a in (pubkeys, msgs, sigs)
+                ))
+            with span("launch.dispatch"):
+                ok = fn(*args)
+        with span("launch.readback"):
+            out = np.asarray(ok)[:n]
         self.row_counts.add(device=n)
         self._generic_counts.add(
             rows=n, pad_rows=n_pad - n, launches=1, kernel_rows=self._kernel_rows(n_pad)
@@ -926,8 +935,12 @@ class VerifierModel:
         outs = []
         for off in range(0, tail_start, window):
             with span("generic.launch", rows=window, bucket=window):
-                outs.append(fn(*(jnp.asarray(a[off : off + window]) for a in (pk, mg, sg))))
-        parts = [np.asarray(o) for o in outs]
+                with span("launch.stage"):
+                    args = self._to_device(*(a[off : off + window] for a in (pk, mg, sg)))
+                with span("launch.dispatch"):
+                    outs.append(fn(*args))
+        with span("launch.readback"):
+            parts = [np.asarray(o) for o in outs]
         self.row_counts.add(device=tail_start)
         self._generic_counts.add(
             rows=tail_start, windows=len(outs), launches=len(outs),
@@ -1239,7 +1252,7 @@ class VerifierModel:
         """Columns ``cols`` of the key pool's arrays, gathered on the
         device (ops_ed.table_slab)."""
         with span("tables.slab", columns=int(cols.shape[0])):
-            return self._program("t-slab")(tables, a_ok, pk, jnp.asarray(cols))
+            return self._program("t-slab")(tables, a_ok, pk, *self._to_device(cols))
 
     def table_bytes(self) -> int:
         """Device bytes of key tables this model holds: the pool's
@@ -1305,35 +1318,42 @@ class VerifierModel:
             "tpl", src[1], _to_slots(src[2], at, n_slots), _to_slots(src[3], at, n_slots),
         )
 
-    def _src_messages(self, src, n_pad: int):
-        """The source's (n_pad, W) u8 messages on the device, rows
-        padded to n_pad here. Both sources converge on the SAME prepare
+    def _src_rows(self, src, n_pad: int) -> tuple:
+        """The source's host arrays for one launch, rows padded to n_pad:
+        (messages,) or (templates, tmpl_idx, ts8), the templates padded
+        to their bucket."""
+        if src[0] == "mat":
+            return (self._pad(src[1], n_pad),)
+        _, templates, tmpl_idx, ts8 = src
+        return (
+            self._pad(templates, self._src_tpl_pad(src)),
+            self._pad(tmpl_idx, n_pad), self._pad(ts8, n_pad),
+        )
+
+    def _messages(self, msg_dev: tuple):
+        """The (n_pad, W) u8 messages on the device from _src_rows'
+        arrays there. Both sources converge on the SAME prepare
         executables: the templated source materializes its messages on
         device first (one tiny extra dispatch; the H2D saving is the
         point)."""
-        if src[0] == "mat":
-            return jnp.asarray(self._pad(src[1], n_pad))
-        _, templates, tmpl_idx, ts8 = src
-        return self._materialize_fn()(
-            jnp.asarray(self._pad(templates, self._src_tpl_pad(src))),
-            jnp.asarray(self._pad(tmpl_idx, n_pad)),
-            jnp.asarray(self._pad(ts8, n_pad)),
-        )
+        if len(msg_dev) == 1:
+            return msg_dev[0]
+        return self._materialize_fn()(*msg_dev)
 
-    def _gathered_launch(self, e: _TablesEntry, src, n_pad: int, idx_dev, sg_dev):
+    def _gathered_launch(self, e: _TablesEntry, msg_dev: tuple, idx_dev, sg_dev):
         """Stages 1-3 of the gathered pair over one padded launch;
         returns the device verdicts."""
         sd, kd, s_ok = self._program("t-prepare-g")(
-            e.pk_dev, idx_dev, self._src_messages(src, n_pad), sg_dev
+            e.pk_dev, idx_dev, self._messages(msg_dev), sg_dev
         )
         px, py, pz, pt, a_ok = self._scan_rows(e, sd, kd, idx_dev)
         return self._program("t-finish")(px, py, pz, pt, sg_dev, a_ok, s_ok)
 
-    def _slot_launch(self, e: _TablesEntry, src, n_slots: int, sg_dev):
-        """Stages 1-3 in slot order over one launch of n_slots = C*V
-        slots (src and sg_dev already scattered to them)."""
+    def _slot_launch(self, e: _TablesEntry, msg_dev: tuple, sg_dev):
+        """Stages 1-3 in slot order over one launch of C*V slots
+        (messages and sg_dev already scattered to them)."""
         s1, s2 = self._slot_stage_fns()
-        sd, kd, s_ok = s1(e.pk_dev, self._src_messages(src, n_slots), sg_dev)
+        sd, kd, s_ok = s1(e.pk_dev, self._messages(msg_dev), sg_dev)
         px, py, pz, pt, a_ok = s2(sd, kd, e.tables, e.a_ok)
         return self._program("t-finish")(px, py, pz, pt, sg_dev, a_ok, s_ok)
 
@@ -1352,7 +1372,8 @@ class VerifierModel:
         """A batch handed over as arrays: one piece."""
         if len(row_idx) == 0:
             return np.zeros(0, dtype=bool)
-        e = self._tables_entry(valset_key, np.asarray(all_pubkeys, dtype=np.uint8))
+        with span("launch.plan"):
+            e = self._tables_entry(valset_key, np.asarray(all_pubkeys, dtype=np.uint8))
         if e is None:
             return None
         piece = (
@@ -1388,14 +1409,15 @@ class VerifierModel:
 
         ahead_due = True
         while groups.left:
-            v1 = _bucket(int(keys_of(1).pubkeys.shape[0]), 1)
-            slotted = self.mesh is None and v1 <= MAX_DEVICE_ROWS
-            per = _commits_per_launch(v1) if slotted else groups.left
-            keys = keys_of(per)
-            while slotted and per > 1 and _bucket(int(keys.pubkeys.shape[0]), 1) > v1:
-                per //= 2
+            with span("launch.plan"):
+                v1 = _bucket(int(keys_of(1).pubkeys.shape[0]), 1)
+                slotted = self.mesh is None and v1 <= MAX_DEVICE_ROWS
+                per = _commits_per_launch(v1) if slotted else groups.left
                 keys = keys_of(per)
-            e = self._tables_entry(keys.digest, keys.pubkeys)
+                while slotted and per > 1 and _bucket(int(keys.pubkeys.shape[0]), 1) > v1:
+                    per //= 2
+                    keys = keys_of(per)
+                e = self._tables_entry(keys.digest, keys.pubkeys)
             if e is None:
                 yield None
                 return
@@ -1470,21 +1492,22 @@ class VerifierModel:
             for piece in pieces:
                 if piece is None:
                     return None
-                e, idx, src, sg, ahead = piece
-                launches = self._plan_launches(e, idx)
-                shapes = [(pad, src, at is not None) for _, _, pad, at in launches]
-                if not self.block_on_compile:
-                    shapes.extend(ahead)
-                # the entry key includes the table's padded row count
-                # (_tabled_bucket_entry): a valset that grows past its
-                # pad bucket must re-warm, not run a synchronous
-                # compile on the live path
-                fresh = [
-                    (ent, pad, of, in_slots)
-                    for pad, of, in_slots in shapes
-                    for ent in [self._tabled_bucket_entry(e, pad, of, slots=in_slots)]
-                    if not ent.ready
-                ]
+                with span("launch.plan"):
+                    e, idx, src, sg, ahead = piece
+                    launches = self._plan_launches(e, idx)
+                    shapes = [(pad, src, at is not None) for _, _, pad, at in launches]
+                    if not self.block_on_compile:
+                        shapes.extend(ahead)
+                    # the entry key includes the table's padded row count
+                    # (_tabled_bucket_entry): a valset that grows past its
+                    # pad bucket must re-warm, not run a synchronous
+                    # compile on the live path
+                    fresh = [
+                        (ent, pad, of, in_slots)
+                        for pad, of, in_slots in shapes
+                        for ent in [self._tabled_bucket_entry(e, pad, of, slots=in_slots)]
+                        if not ent.ready
+                    ]
                 if fresh and not self.block_on_compile:
                     for ent, pad, of, in_slots in fresh:
                         self._compile_tabled_async(ent, e, pad, of, slots=in_slots)
@@ -1502,8 +1525,10 @@ class VerifierModel:
                         if ops_stage2.kernel_form(self._slot_table_rows(e), jax.default_backend()):
                             kernel_slots += pad
                 rows += int(idx.shape[0])
+            with span("launch.readback"):
+                got = [np.asarray(o) for o, _ in outs]
             out = (
-                np.concatenate([np.asarray(o)[take] for o, take in outs])
+                np.concatenate([ok[take] for ok, (_, take) in zip(got, outs)])
                 if outs else np.zeros(0, dtype=bool)
             )
             self.row_counts.add(device=rows)
@@ -1527,16 +1552,17 @@ class VerifierModel:
         """Dispatch one launch over the rows given: in slot order when
         ``at`` gives their slots of the n_pad = C*V, else gathered and
         padded to n_pad. (device verdicts, the rows' places in them)."""
-        if at is None:
-            n = int(idx.shape[0])
-            return self._gathered_launch(
-                e, src, n_pad,
-                jnp.asarray(self._pad(idx, n_pad)), jnp.asarray(self._pad(sg, n_pad)),
-            ), slice(0, n)
-        return self._slot_launch(
-            e, self._src_to_slots(src, at, n_pad), n_pad,
-            jnp.asarray(_to_slots(sg, at, n_pad)),
-        ), at
+        with span("launch.stage"):
+            if at is None:
+                idx_dev, sg_dev = self._to_device(self._pad(idx, n_pad), self._pad(sg, n_pad))
+            else:
+                src = self._src_to_slots(src, at, n_pad)
+                sg_dev, = self._to_device(_to_slots(sg, at, n_pad))
+            msg_dev = self._to_device(*self._src_rows(src, n_pad))
+        with span("launch.dispatch"):
+            if at is None:
+                return self._gathered_launch(e, msg_dev, idx_dev, sg_dev), slice(0, int(idx.shape[0]))
+            return self._slot_launch(e, msg_dev, sg_dev), at
 
     def _tabled_bucket_entry(
         self, e: _TablesEntry, n_pad: int, src, slots: bool = False
@@ -1639,11 +1665,12 @@ class VerifierModel:
         def one_pass():
             t0 = time.perf_counter()
             sg = jnp.asarray(np.zeros((n_pad, 64), dtype=np.uint8))
+            msg_dev = tuple(jnp.asarray(a) for a in self._src_rows(zsrc, n_pad))
             if slots:
-                ok = self._slot_launch(e, zsrc, n_pad, sg)
+                ok = self._slot_launch(e, msg_dev, sg)
             else:
                 idx = jnp.asarray(np.zeros(n_pad, dtype=np.int32))
-                ok = self._gathered_launch(e, zsrc, n_pad, idx, sg)
+                ok = self._gathered_launch(e, msg_dev, idx, sg)
             np.asarray(ok)
             ent.compile_s = time.perf_counter() - t0
             ent.ready = True

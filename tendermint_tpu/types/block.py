@@ -19,6 +19,7 @@ from tendermint_tpu.codec.signbytes import PRECOMMIT_TYPE
 from tendermint_tpu.crypto import merkle
 from tendermint_tpu.crypto.batch import SEAM_COUNTS
 from tendermint_tpu.types.tx import Txs
+from tendermint_tpu.utils.trace import span
 from tendermint_tpu.version import BLOCK_PROTOCOL
 
 MAX_HEADER_BYTES = 653
@@ -307,7 +308,8 @@ class Commit:
         ``_parts_cache``; a deep copy starts without it)."""
         cols = getattr(self, "_cols_cache", None)
         if cols is None:
-            cols = self._cols_cache = CommitColumns(self.signatures)
+            with span("verify.columns"):
+                cols = self._cols_cache = CommitColumns(self.signatures)
         return cols
 
     def vote_sign_bytes(self, chain_id: str, idx: int) -> bytes:

@@ -31,6 +31,7 @@ from tendermint_tpu.crypto.batch import (
 )
 from tendermint_tpu.types.block import BLOCK_ID_FLAG_COMMIT, MAX_SIGNATURE_SIZE, first_true
 from tendermint_tpu.types.validator import Validator
+from tendermint_tpu.utils.trace import span
 
 MAX_TOTAL_VOTING_POWER = (1 << 63) // 8
 PRIORITY_WINDOW_SIZE_FACTOR = 2
@@ -646,9 +647,10 @@ class ValidatorSet:
 
         if self._cached_commit_replay(chain_id, commit, sig_cache):
             return
-        idxs, vals_idx, pk, mg, sg, powers, counted, ed, tpl = (
-            self._commit_batch_arrays(chain_id, commit, by_address=False)
-        )
+        with span("verify.pack"):
+            idxs, vals_idx, pk, mg, sg, powers, counted, ed, tpl = (
+                self._commit_batch_arrays(chain_id, commit, by_address=False)
+            )
         v = provider or get_default_provider()
         # reuse the memoized per-row keys the fast path just derived
         # (None when any row is non-ed25519 or no cache is in play)
@@ -661,7 +663,8 @@ class ValidatorSet:
             commit, idxs, vals_idx, pk, mg, sg, ed, v, tpl,
             sig_cache=sig_cache, row_keys=row_keys,
         )
-        self._replay_commit_full(commit, ok, idxs, powers, counted)
+        with span("verify.replay"):
+            self._replay_commit_full(commit, ok, idxs, powers, counted)
 
     def _commit_row_keys(self, chain_id: str, commit) -> Optional[list]:
         """Per-signature SigCache keys for a commit whose rows map
@@ -866,12 +869,14 @@ class ValidatorSet:
         self._validate_trust_level(trust_level)
         self._verify_commit_basic(commit, height, block_id)
 
-        idxs, vals_idx, pk, mg, sg, powers_arr, counted_arr, ed, tpl = (
-            self._commit_batch_arrays(chain_id, commit, by_address=True)
-        )
+        with span("verify.pack"):
+            idxs, vals_idx, pk, mg, sg, powers_arr, counted_arr, ed, tpl = (
+                self._commit_batch_arrays(chain_id, commit, by_address=True)
+            )
         v = provider or get_default_provider()
         ok = self._verify_rows(commit, idxs, vals_idx, pk, mg, sg, ed, v, tpl)
-        self._replay_commit_trusting(ok, idxs, vals_idx, powers_arr, counted_arr, trust_level)
+        with span("verify.replay"):
+            self._replay_commit_trusting(ok, idxs, vals_idx, powers_arr, counted_arr, trust_level)
 
     def _replay_commit_trusting(
         self, ok, idxs, vals_idx, powers_arr, counted_arr, trust_level: Fraction
@@ -1025,11 +1030,12 @@ class _SpecRows(RowGroups):
                 else:
                     s.valset._check_commit_size(s.commit)
                 s.valset._verify_commit_basic(s.commit, s.height, s.block_id)
-                idxs, vals_idx, pk, mg, sg, powers, counted, ed, tpl = (
-                    s.valset._commit_batch_arrays(
-                        s.chain_id, s.commit, by_address=(s.mode == "trusting")
+                with span("verify.pack"):
+                    idxs, vals_idx, pk, mg, sg, powers, counted, ed, tpl = (
+                        s.valset._commit_batch_arrays(
+                            s.chain_id, s.commit, by_address=(s.mode == "trusting")
+                        )
                     )
-                )
             except Exception as e:
                 self.results[si] = e
                 continue
@@ -1115,9 +1121,10 @@ class _SpecRows(RowGroups):
             return None
         if overlapped:
             SEAM_COUNTS.add(overlapped_rows=sum(seg[5] for seg in segs))
-        return self.stacked(lo, len(self.segments), places) + (
-            np.concatenate(self.sg[lo:], axis=0),
-        )
+        with span("verify.pack"):
+            return self.stacked(lo, len(self.segments), places) + (
+                np.concatenate(self.sg[lo:], axis=0),
+            )
 
 
 def verify_commits_batched(
@@ -1182,20 +1189,21 @@ def verify_commits_batched(
     if tabled and sets > 1:
         SEAM_COUNTS.add(multiset_rows=len(ok))
 
-    off = 0
-    for si, idxs, vals_idx, powers, counted, n, _ed in segments:
-        s = specs[si]
-        ok_slice = ok[off : off + n]
-        off += n
-        try:
-            if s.mode == "trusting":
-                s.valset._replay_commit_trusting(
-                    ok_slice, idxs, vals_idx, powers, counted, s.trust_level
-                )
-            else:
-                s.valset._replay_commit_full(s.commit, ok_slice, idxs, powers, counted)
-        except Exception as e:
-            results[si] = e
+    with span("verify.replay"):
+        off = 0
+        for si, idxs, vals_idx, powers, counted, n, _ed in segments:
+            s = specs[si]
+            ok_slice = ok[off : off + n]
+            off += n
+            try:
+                if s.mode == "trusting":
+                    s.valset._replay_commit_trusting(
+                        ok_slice, idxs, vals_idx, powers, counted, s.trust_level
+                    )
+                else:
+                    s.valset._replay_commit_full(s.commit, ok_slice, idxs, powers, counted)
+            except Exception as e:
+                results[si] = e
     return results
 
 

@@ -351,6 +351,7 @@ class CryptoMetrics:
         ("generic_windows", "generic_windows"),
         ("generic_launches", "generic_launches"),
         ("generic_kernel_rows", "generic_kernel_rows"),
+        ("h2d_bytes", "h2d_bytes"),
     )
 
     def __init__(self, registry: Optional[Registry] = None, namespace="tendermint"):
@@ -386,6 +387,7 @@ class CryptoMetrics:
         self.generic_windows = reg(Counter("generic_windows_total", "Full 16,384-row windows that generic batches past one launch streamed.", namespace, sub))
         self.generic_launches = reg(Counter("generic_launches_total", "Generic three-stage launches, a streamed batch's tail included.", namespace, sub))
         self.generic_kernel_rows = reg(Counter("generic_kernel_rows_total", "Rows (real and pad) launched into a generic stage-2 program whose point arithmetic is the Pallas kernel form.", namespace, sub))
+        self.h2d_bytes = reg(Counter("h2d_bytes_total", "Bytes copied host to device for served verify launches (tabled and generic; table builds excluded).", namespace, sub))
         self._deltas = _SnapshotCounters()
 
     def update(self, stats: dict) -> None:

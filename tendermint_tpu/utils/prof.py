@@ -16,6 +16,8 @@ import sys
 import traceback
 from typing import Optional
 
+from tendermint_tpu.utils import trace
+
 
 def dump_thread_stacks() -> str:
     out = io.StringIO()
@@ -50,8 +52,11 @@ def jax_trace(action: str, trace_dir: str = "") -> str:
     """Start/stop a JAX profiler trace (xprof/tensorboard format) —
     the device-side analog of the reference's pprof CPU profiles
     (SURVEY §5.1: 'JAX profiler + xprof traces around kernel
-    dispatch'). Lazy import: a node without device work never touches
-    jax here."""
+    dispatch'). While it runs the tracer's profiler sink is on, so the
+    node's own spans (utils/trace.py: the verify seam's, the pipeline's,
+    each launch's) are host events beside the device's operations in
+    the same profile. Lazy import: a node without device work never
+    touches jax here."""
     global _jax_trace_dir
     try:
         import jax
@@ -70,12 +75,14 @@ def jax_trace(action: str, trace_dir: str = "") -> str:
             jax.profiler.start_trace(trace_dir)
         except Exception as e:
             return f"start_trace failed: {e!r}\n"
+        trace.profiler_sink(True)
         _jax_trace_dir = trace_dir
         return f"tracing -> {trace_dir}\n"
     if action == "stop":
         if _jax_trace_dir is None:
             return "no trace running\n"
         out, _jax_trace_dir = _jax_trace_dir, None
+        trace.profiler_sink(False)
         try:
             # clear the marker FIRST: if stop raises (e.g. someone used
             # jax.profiler directly), start stays retryable instead of

@@ -31,6 +31,15 @@ Design constraints, in order:
 The global tracer is wired from config (``trace_enabled``,
 ``trace_buffer_events``) at node construction; ``TM_TRACE=0``/``1`` is
 the ops kill switch overriding config without editing toml.
+
+**The profiler sink.** While a device profile is being taken
+(``jax.profiler.start_trace`` .. ``stop_trace``), ``profiler_sink(True)``
+makes every span also a ``jax.profiler.TraceAnnotation``: a host event
+in the same ``.xplane.pb`` as the device's ``XLA Ops``, on the same
+clock, so each device-idle gap lines up with the host step that held
+the chip. The sink is independent of the ring (``enabled``): with the
+ring off a span is then the annotation alone. With both off ``span()``
+is still one flag check (``active``).
 """
 
 from __future__ import annotations
@@ -112,6 +121,28 @@ class _NoopSpan:
 
 NOOP_SPAN = _NoopSpan()
 
+
+class _Annotated:
+    """A span while the profiler sink is on and the ring is off: the
+    profiler annotation alone (``set`` keeps nothing; the ring does)."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, annotation):
+        self._ann = annotation
+
+    def __enter__(self) -> "_Annotated":
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._ann.__exit__(None, None, None)
+        return False
+
+    def set(self, **args) -> None:
+        pass
+
+
 # per-thread span stack for nesting attribution
 _tls = threading.local()
 
@@ -124,14 +155,16 @@ def _stack() -> list:
 
 
 class _Span:
-    """One live span. Records a Chrome 'X' (complete) event on exit."""
+    """One live span. Records a Chrome 'X' (complete) event on exit, and
+    is a profiler annotation too while the tracer's sink is on."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0", "_tid")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_tid", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self._ann = None
 
     def set(self, **args) -> None:
         """Attach/overwrite args after entry (e.g. a routing outcome
@@ -146,11 +179,17 @@ class _Span:
             # interleave on one thread, so only the NAME is recorded
             self.args.setdefault("parent", st[-1].name)
         st.append(self)
+        annotation = self._tracer._annotation
+        if annotation is not None:
+            self._ann = annotation(self.name)
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         dur = time.perf_counter_ns() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         st = _stack()
         if st and st[-1] is self:
             st.pop()
@@ -172,6 +211,8 @@ class Tracer:
         enabled: bool = True,
         node_id: str = "",
     ):
+        # the annotation class while the profiler sink is on, else None
+        self._annotation = None
         self.enabled = bool(enabled)
         self._cap = max(int(buffer_events), 1)
         self._ring: "deque[tuple]" = deque()
@@ -199,13 +240,43 @@ class Tracer:
         self.node_id = str(node_id)
         self._span_salt = (zlib.crc32(self.node_id.encode()) & 0xFFFFFFFF) << 20
 
+    # -- switches ------------------------------------------------------------
+
+    @property
+    def enabled(self) -> bool:
+        """Whether spans and instants are recorded into the ring."""
+        return self._recording
+
+    @enabled.setter
+    def enabled(self, on: bool) -> None:
+        self._recording = bool(on)
+        self.active = self._recording or self._annotation is not None
+
+    def set_profiler_sink(self, on: bool) -> None:
+        """Make every span also a ``jax.profiler.TraceAnnotation`` (on)
+        or stop (off). Goes with starting and stopping a device profile:
+        outside one an annotation records nothing and costs ~1 µs."""
+        if on:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation
+        else:
+            self._annotation = None
+        self.active = self._recording or self._annotation is not None
+
     # -- recording ---------------------------------------------------------
 
     def span(self, name: str, **args):
-        """Context manager timing a stage. Returns a shared no-op when
-        the tracer is disabled."""
-        if not self.enabled:
+        """Context manager timing a stage. Returns a shared no-op while
+        neither the ring nor the profiler sink is on."""
+        if not self.active:
             return NOOP_SPAN
+        return self._open(name, args)
+
+    def _open(self, name: str, args: Dict[str, Any]):
+        if not self._recording:  # the profiler sink alone
+            annotation = self._annotation  # another thread may switch it off
+            return NOOP_SPAN if annotation is None else _Annotated(annotation(name))
         return _Span(self, name, args)
 
     def instant(self, name: str, **args) -> None:
@@ -548,13 +619,20 @@ def enabled() -> bool:
     return _tracer.enabled
 
 
+def profiler_sink(on: bool) -> None:
+    """Mirror the global tracer's spans into a device profile: call with
+    True right after ``jax.profiler.start_trace`` and with False right
+    before ``stop_trace`` (``utils/prof.py``'s profiler route does)."""
+    _tracer.set_profiler_sink(on)
+
+
 def span(name: str, **args):
     """``with trace.span("stage", height=h):`` — the hot-path entry
-    point. One flag check when disabled."""
+    point. One flag check while the ring and the profiler sink are off."""
     t = _tracer
-    if not t.enabled:
+    if not t.active:
         return NOOP_SPAN
-    return _Span(t, name, args)
+    return t._open(name, args)
 
 
 def instant(name: str, **args) -> None:

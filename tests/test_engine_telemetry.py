@@ -242,22 +242,26 @@ def test_pipeline_publishes_overlapped_rows():
     "name",
     ["seam_multiset_rows", "table_keys_built", "table_keys_loaded", "table_keys_reused",
      "table_keys_evicted", "table_slabs", "table_slab_columns", "generic_rows",
-     "generic_pad_rows", "generic_windows", "generic_launches", "generic_kernel_rows"],
+     "generic_pad_rows", "generic_windows", "generic_launches", "generic_kernel_rows",
+     "h2d_bytes"],
 )
 def test_pipeline_publishes_key_pool_and_multiset_counts(name):
     """What a node reads to tell "my window straddled a set change and
     still rode the tables" and what the key pool built, reused and
-    evicted, and what the generic family launched: the process's counts
-    (crypto/batch.SEAM_COUNTS, TABLE_COUNTS, GENERIC_COUNTS) under
+    evicted, what the generic family launched and the launches' H2D
+    bytes: the process's counts (crypto/batch.SEAM_COUNTS, TABLE_COUNTS,
+    GENERIC_COUNTS, H2D_COUNTS) under
     engine_stats()["counters"], stats() and tendermint_crypto_<name>_total,
     one value everywhere."""
     from tendermint_tpu.crypto.batch import (
-        GENERIC_COUNTS, SEAM_COUNTS, TABLE_COUNTS, CPUBatchVerifier,
+        GENERIC_COUNTS, H2D_COUNTS, SEAM_COUNTS, TABLE_COUNTS, CPUBatchVerifier,
     )
     from tendermint_tpu.crypto.pipeline import PipelinedVerifier, SigCache
     from tendermint_tpu.utils.metrics import CryptoMetrics, Registry
 
-    counts = {"seam": SEAM_COUNTS, "table": TABLE_COUNTS, "generic": GENERIC_COUNTS}[name.split("_")[0]]
+    counts = {
+        "seam": SEAM_COUNTS, "table": TABLE_COUNTS, "generic": GENERIC_COUNTS, "h2d": H2D_COUNTS,
+    }[name.split("_")[0]]
     counts.add(**{name.split("_", 1)[1]: 3})
     want = counts.snapshot()[name]
     assert want >= 3

@@ -2,8 +2,9 @@
 the node's provider stack (``PipelinedVerifier`` over ``TPUBatchVerifier``):
 a batch past ``MAX_DEVICE_ROWS`` streams as full windows and a bucketed
 tail, every row's verdict equals ``cryptography``'s, and the family's
-counters (``crypto/batch.GENERIC_COUNTS``) and ``generic.launch`` spans
-say what was launched. ``MAX_DEVICE_ROWS`` is shrunk to 16, so 40 rows
+counters (``crypto/batch.GENERIC_COUNTS``, ``H2D_COUNTS``) and
+``generic.launch`` spans (each around its ``launch.stage`` and
+``launch.dispatch``) say what was launched. ``MAX_DEVICE_ROWS`` is shrunk to 16, so 40 rows
 are 2 windows and an 8-row tail padded to the same 16-row bucket: one
 compile, at the payments message width of 92 bytes.
 """
@@ -78,6 +79,16 @@ def test_windowed_verdicts_equal_the_reference_and_counters_say_what_ran(batch, 
 
     launches = [e for e in tracer._snapshot() if e[1] == "generic.launch"]
     assert [(e[5]["rows"], e[5]["bucket"]) for e in launches] == [(16, 16), (16, 16), (8, 16)]
+    # each launch stages and dispatches inside its generic.launch span; the
+    # windows' verdicts are read back once, the tail's once
+    inner = [
+        (e[1], e[5].get("parent")) for e in tracer._snapshot()
+        if e[1].startswith("launch.") and e[5].get("parent") == "generic.launch"
+    ]
+    assert inner == [("launch.stage", "generic.launch"), ("launch.dispatch", "generic.launch")] * 3
+    assert sum(e[1] == "launch.readback" for e in tracer._snapshot()) == 2
+    # every row copied to the device, pad rows included: 48 rows of key, message, signature
+    assert after["h2d_bytes"] - before["h2d_bytes"] == 48 * (32 + 92 + 64)
 
 
 def test_the_launch_span_costs_nothing_with_the_tracer_off(batch, provider):

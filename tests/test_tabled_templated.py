@@ -228,8 +228,15 @@ def test_slot_order_equals_gathered_equals_host(monkeypatch, name, n_commits, ab
     plan = vmod.plan_slots(idx, v)
     assert plan is not None and sum(c for _, _, c in plan.launches) * v == slots
 
+    from tendermint_tpu.crypto.batch import H2D_COUNTS
+
     before = _tabled_counts()
+    h2d = H2D_COUNTS.snapshot()["h2d_bytes"]
     ok_tpl = m.verify_rows_cached_templated(key, pks, idx, templates, ti, t8, sg)
+    # every slot's signature, template index and timestamp, and the
+    # templates padded to their bucket, copied once; nothing else
+    tpl_pad = m._src_tpl_pad(("tpl", np.asarray(templates)))
+    assert H2D_COUNTS.snapshot()["h2d_bytes"] - h2d == slots * (64 + 4 + 8) + tpl_pad * 160
     ok_mat = m.verify_rows_cached(key, pks, idx, mg, sg)
     _grew(before, slot_rows=2 * n, slot_pad=2 * (slots - n))
     assert any(k[0] == "slots-tpl" and k[1] == slots for k in m._entries)

@@ -116,7 +116,17 @@ def test_windowed_cached_path_boundary_controls(monkeypatch):
     # non-blocking with a cold tail bucket: nothing dispatches, the
     # caller falls back (no wasted window work)
     m2 = vmod.VerifierModel(block_on_compile=False)
+    with vmod._compile_threads_lock:
+        before = set(vmod._compile_threads)
     assert m2.verify_rows_cached(b"win-test-2", pk16, idx, mg, sg) is None
+    # the background key fetch that call started reads the 16 keys back
+    # from the table files: joined here, so that its process-wide
+    # TABLE_COUNTS never land inside a later test's reading
+    with vmod._compile_threads_lock:
+        mine = [t for t in vmod._compile_threads if t not in before]
+    for t in mine:
+        t.join(timeout=300)
+    assert not any(t.is_alive() for t in mine)
 
 
 def test_tables_persist_to_disk_and_reload(tmp_path, monkeypatch):

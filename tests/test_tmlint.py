@@ -243,6 +243,28 @@ def test_trace_coherence_documented_and_dynamic_names_pass():
     assert_only(v, "trace-coherence", 1)
 
 
+@pytest.mark.parametrize(
+    "imports, call",
+    [
+        ("from tendermint_tpu.utils.trace import span", "span"),
+        ("from tendermint_tpu.utils.trace import span as sp", "sp"),
+        ("from .utils.trace import instant", "instant"),
+    ],
+)
+def test_trace_coherence_sees_the_bare_function_import(imports, call):
+    """``from ...utils.trace import span`` then a bare ``span(...)``: an
+    undocumented name fires whatever its shape, a documented one passes."""
+    code = (
+        f"{imports}\n"
+        "def f():\n"
+        f"    {call}('launch.stage')\n"
+        f"    {call}('undocumented')\n"
+    )
+    v = lint_snippet(code)
+    assert_only(v, "trace-coherence", 1)
+    assert "undocumented" in v[0].message
+
+
 def test_golden_flightrec_coherence():
     code = (
         "def f(self, h, r):\n"
