@@ -378,6 +378,43 @@ def check_generic_forms(prov, pk, msgs, sigs):
         f"generic stage 2 in both forms over {n} rows: x, y, z, t bit-equal, "
         f"generic_kernel_rows={kernel_rows} ({time.perf_counter() - t0:.1f}s, both compiles included)"
     )
+    check_chain_forms(seed=n)
+
+
+def check_chain_forms(seed: int, sizes=(16_384, 4_096)):
+    """The per-row exponentiation chains of the generic stages 1 and 3 in
+    both forms, on the chip, at the batch cell's two launch shapes: the
+    Pallas kernel field_chain (ops/field_kernel.py) against F.pow22523
+    and F.invert, which stay as its oracle. Random weak limbs, every
+    limb at the weak bound, and 0, 1, p - 1, p as non-canonical limbs:
+    bit-equal, and 0 inverts to 0."""
+    import jax
+    import numpy as np
+
+    from tendermint_tpu.ops import field as F
+    from tendermint_tpu.ops import field_kernel as FK
+
+    rng = np.random.default_rng(seed)
+    for n in sizes:
+        check(FK.kernel_form(n, jax.default_backend()), f"{n} rows are off the chain kernel's rule")
+        z = rng.integers(0, F.WEAK_MAX + 1, size=(n, F.LIMBS), dtype=np.int32)
+        z[0] = 0
+        z[1] = F.to_limbs(1)
+        z[2] = F.to_limbs(F.P - 1)
+        z[3] = [(F.P >> (F.SHIFT * i)) & (F.MASK if i < F.LIMBS - 1 else 0xFF) for i in range(F.LIMBS)]
+        z[4] = F.WEAK_MAX
+        t0 = time.perf_counter()
+        for name, kernel, xla in (("pow22523", FK.pow22523, F.pow22523), ("invert", FK.invert, F.invert)):
+            lowered = jax.jit(kernel).lower(z)
+            check("field_chain" in lowered.as_text(), f"{name} at {n} rows did not lower to the kernel")
+            got, want = np.asarray(lowered.compile()(z)), np.asarray(jax.jit(xla)(z))
+            rows = np.nonzero((got != want).any(axis=1))[0]
+            check(rows.size == 0, f"{name} at {n} rows: kernel and XLA chain differ in rows {rows[:10]}")
+            check(name != "invert" or not got[0].any(), "the chain kernel's invert(0) is not 0")
+        say(
+            f"chains pow22523 and invert in both forms over {n} rows: bit-equal "
+            f"({time.perf_counter() - t0:.1f}s, compiles included)"
+        )
 
 
 # -- light-1k -----------------------------------------------------------------

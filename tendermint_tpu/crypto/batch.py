@@ -129,9 +129,11 @@ TABLE_COUNTS = NamedCounts(
 # empty rows launched with them up to a bucket, ``windows`` the full
 # MAX_DEVICE_ROWS windows a batch past that size streamed, ``launches``
 # every three-stage launch, a streamed batch's tail included,
-# ``kernel_rows`` the rows (real and pad) launched into a stage 2 whose
-# body is the Pallas kernel form (ops/stage2_kernel.kernel_form: 0 on
-# the CPU and in the buckets below 1,024 rows). Counted once the
+# ``kernel_rows`` the rows (real and pad) launched into a launch whose
+# three stages take the Pallas kernel form — stage 2's generic_scan,
+# stage 1's and stage 3's field_chain (decompression's square root, the
+# encode's inversion) — by the one rule (ops/field_kernel.kernel_form: 0
+# on the CPU and in the buckets below 1,024 rows). Counted once the
 # verdicts are read back; process-wide.
 GENERIC_COUNTS = NamedCounts(
     "generic", ("rows", "pad_rows", "windows", "launches", "kernel_rows")

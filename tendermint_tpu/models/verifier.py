@@ -857,7 +857,9 @@ class VerifierModel:
 
     def _kernel_rows(self, n_pad: int) -> int:
         """n_pad where a generic launch of that many rows has the kernel
-        form of stage 2 (each device's rows under a mesh), else 0."""
+        form of its three stages (each device's rows under a mesh), else
+        0: stage 2's generic_scan and the chains of stages 1 and 3 follow
+        the one rule."""
         per_device = n_pad // self._pad_multiple()
         return n_pad if ops_stage2.kernel_form(per_device, jax.default_backend()) else 0
 

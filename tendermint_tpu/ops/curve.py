@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tendermint_tpu.ops import field as F
+from tendermint_tpu.ops import field_kernel as FK
 from tendermint_tpu.ops import ref_ed25519 as ref
 
 
@@ -143,8 +144,12 @@ def encode(p: Point, blocked: bool = False) -> jnp.ndarray:
     finish-stage XLA compile from ~6s to >530s (associative_scan's
     odd/even slicing tree lowers terribly at (N, 20) int32). The
     BLOCKED variant (round 3) gets the same arithmetic saving with a
-    plain lax.scan over 64-row blocks, which compiles fine."""
-    zi = F.invert_blocked(p.z) if blocked else F.invert(p.z)
+    plain lax.scan over 64-row blocks, which compiles fine.
+
+    The per-row chain is the Pallas kernel field_chain where the program
+    is lowered for a TPU with rows on its rule (FK.kernel_form), bit-equal
+    to F.invert, which every other lowering runs."""
+    zi = F.invert_blocked(p.z) if blocked else FK.invert(p.z)
     x = F.mul(p.x, zi)
     y = F.mul(p.y, zi)
     out = F.to_bytes(y)
@@ -158,7 +163,9 @@ def decompress(data: jnp.ndarray) -> Tuple[Point, jnp.ndarray]:
 
     Go x/crypto parity (edwards25519 FeFromBytes + sqrt): the sign bit is
     masked (y >= p accepted, reduced mod p); returns (point, ok) with ok
-    False where x^2 has no square root.
+    False where x^2 has no square root. The square root's chain is the
+    Pallas kernel field_chain where the program is lowered for a TPU
+    with rows on its rule (FK.kernel_form), bit-equal to F.pow22523.
     """
     sign = (data[..., 31].astype(jnp.int32) >> 7) & 1
     y = F.from_bytes(data)  # masks bit 255
@@ -168,7 +175,7 @@ def decompress(data: jnp.ndarray) -> Tuple[Point, jnp.ndarray]:
     # x = u v^3 (u v^7)^((p-5)/8)
     v3 = F.mul(F.square(v), v)
     v7 = F.mul(F.square(v3), v)
-    x = F.mul(F.mul(u, v3), F.pow22523(F.mul(u, v7)))
+    x = F.mul(F.mul(u, v3), FK.pow22523(F.mul(u, v7)))
     # check vx^2 == +-u
     vxx = F.mul(v, F.square(x))
     ok_plus = F.eq(vxx, u)

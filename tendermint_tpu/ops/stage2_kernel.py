@@ -10,7 +10,8 @@ so a limb shift is a renaming, no value between the multiplications of
 a point addition leaves VMEM, and the window loop keeps its accumulator
 in the kernel's resident output block.
 
-The integer operations are those of ``ops/field.py`` and ``ops/curve.py``
+The field arithmetic on limb lists is ``ops/field_kernel.py``'s. The
+integer operations are those of ``ops/field.py`` and ``ops/curve.py``
 in the same order (every product column and carry pass; sums of
 non-negative terms below 2^31 are exact in any order), the weak-limb
 invariant (limbs in [0, WEAK_MAX], columns < 2^31) is unchanged and no
@@ -31,8 +32,6 @@ window into VMEM scratch, where they stay for its 64 windows.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -40,91 +39,20 @@ from jax.experimental.pallas import tpu as pltpu
 
 from tendermint_tpu.ops import curve
 from tendermint_tpu.ops import field as F
-
-LANES = 128
-SUBLANES = 8  # (8, 128) int32 = one vector register a limb
-BLOCK_ROWS = SUBLANES * LANES
+from tendermint_tpu.ops.field_kernel import (
+    LANES,
+    SUBLANES,
+    add,
+    kernel_form,
+    mul,
+    neg,
+    square,
+    sub,
+)
 
 _L = F.LIMBS
 _AFF = 3 * _L  # an affine-cached table entry: ypx, ymx, t2d
 _COMB_DIGITS = 32
-
-
-def kernel_form(n: int, platform: str) -> bool:
-    """Whether a stage 2 lowered for ``platform`` has the kernel body: a
-    TPU, and n — the slot-order family's table keys, the generic
-    family's rows (a device's, under a mesh) — tiling onto whole (8, 128)
-    blocks. Nothing else is consulted."""
-    return platform == "tpu" and n % BLOCK_ROWS == 0
-
-
-# -- field arithmetic on limb lists ------------------------------------------
-#
-# ops/field.py on lists of 20 same-shaped int32 arrays instead of a
-# trailing axis of 20: the same integer operations, limb for limb.
-
-
-def vpass(a):
-    lo = [x & F.MASK for x in a]
-    hi = [x >> F.SHIFT for x in a]
-    return [lo[0] + F.FOLD * hi[_L - 1]] + [lo[k] + hi[k - 1] for k in range(1, _L)]
-
-
-def vpasses(a, n):
-    for _ in range(n):
-        a = vpass(a)
-    return a
-
-
-def _zero(x):
-    return isinstance(x, int) and x == 0
-
-
-def mul(a, b):
-    """field._mul_cols + field._reduce_cols. A limb may be a Python int
-    (a constant operand): a product by a zero limb is folded away at
-    trace time, which adds nothing to an exact column."""
-    cols = []
-    for k in range(2 * _L - 1):
-        terms = [
-            a[i] * b[k - i]
-            for i in range(max(0, k - _L + 1), min(_L, k + 1))
-            if not (_zero(a[i]) or _zero(b[k - i]))
-        ]
-        cols.append(functools.reduce(lambda x, y: x + y, terms) if terms else 0)
-    # two passes over the 39 columns and two zero columns above them,
-    # the zeros folded at trace time
-    lo = [x & F.MASK for x in cols]
-    hi = [x >> F.SHIFT for x in cols]
-    ext = [lo[0]] + [lo[k] + hi[k - 1] for k in range(1, 2 * _L - 1)] + [hi[2 * _L - 2]]
-    lo = [x & F.MASK for x in ext]
-    hi = [x >> F.SHIFT for x in ext]
-    ext = [lo[0]] + [lo[k] + hi[k - 1] for k in range(1, 2 * _L)] + [hi[2 * _L - 1]]
-    r = [ext[k] + F.FOLD * ext[_L + k] for k in range(_L)]
-    r[0] = r[0] + F.FOLD * F.FOLD * ext[2 * _L]
-    return vpasses(r, 4)
-
-
-def square(a):
-    return mul(a, a)
-
-
-def add(a, b):
-    return vpasses([x + y for x, y in zip(a, b)], 2)
-
-
-def _resolve_negatives(x):
-    return vpasses([v + k for v, k in zip(x, F._2P_LIMBS)], 2)
-
-
-def sub(a, b):
-    return _resolve_negatives(
-        vpasses([x + k - y for x, y, k in zip(a, b, F._64P_LIMBS)], 3)
-    )
-
-
-def neg(a):
-    return _resolve_negatives(vpasses([k - x for x, k in zip(a, F._64P_LIMBS)], 3))
 
 
 # -- point operations on limb lists ------------------------------------------

@@ -386,7 +386,7 @@ class CryptoMetrics:
         self.generic_pad_rows = reg(Counter("generic_pad_rows_total", "Empty rows launched with the generic rows up to their bucket.", namespace, sub))
         self.generic_windows = reg(Counter("generic_windows_total", "Full 16,384-row windows that generic batches past one launch streamed.", namespace, sub))
         self.generic_launches = reg(Counter("generic_launches_total", "Generic three-stage launches, a streamed batch's tail included.", namespace, sub))
-        self.generic_kernel_rows = reg(Counter("generic_kernel_rows_total", "Rows (real and pad) launched into a generic stage-2 program whose point arithmetic is the Pallas kernel form.", namespace, sub))
+        self.generic_kernel_rows = reg(Counter("generic_kernel_rows_total", "Rows (real and pad) launched into a generic launch whose three stages take the Pallas kernel form (stage 2's point arithmetic, stages 1 and 3's exponentiation chains).", namespace, sub))
         self.h2d_bytes = reg(Counter("h2d_bytes_total", "Bytes copied host to device for served verify launches (tabled and generic; table builds excluded).", namespace, sub))
         self._deltas = _SnapshotCounters()
 

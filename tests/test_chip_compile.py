@@ -166,6 +166,40 @@ def test_generic_scan_compiles_for_v5e(topo, compile_for, n):
     assert compiled.memory_analysis().temp_size_in_bytes <= XLA_GENERIC_TEMP_BYTES[n]
 
 
+# The generic prepare and finish at 16,384 rows with the chains as rolled
+# XLA loops: 1,047 and 501 fusions, 17,717,760 and 8,170,496 bytes of
+# temporaries (compile-only readings of the parent for v5e): what the
+# kernel form of the chains starts from.
+XLA_CHAIN_STAGES = {"prepare": (1_047, 17_717_760), "finish": (501, 8_170_496)}
+
+
+@pytest.mark.parametrize("stage", sorted(XLA_CHAIN_STAGES))
+def test_generic_chain_stages_compile_for_v5e(topo, compile_for, stage):
+    """Stages 1 and 3 of the generic family at the batch cell's window,
+    lowered for the TPU: decompression's square root and the encode's
+    inversion are each ONE Pallas kernel, field_chain
+    (ops/field_kernel.py). The chain's loops leave XLA — the finish
+    keeps at most a fifth of its fusions, the prepare (SHA-512, the
+    recode, the rest of decompression) two thirds — and neither plans
+    more temporaries than before."""
+    import re
+
+    n = 16_384
+    S, _ = shapes(SingleDeviceSharding(topo.devices[0]))
+    if stage == "prepare":
+        compiled = compile_for(E.verify_stage_prepare, 180, S((n, 32), u8), S((n, 92), u8), S((n, 64), u8))
+    else:
+        limbs = S((n, 20), i32)
+        flags = S((n,), jnp.bool_)
+        compiled = compile_for(E.verify_stage_finish, 60, *(limbs,) * 4, S((n, 64), u8), flags, flags)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "field_chain" in text
+    xla_fusions, xla_temp = XLA_CHAIN_STAGES[stage]
+    fusions = len(re.findall(r" fusion\(", text))
+    assert fusions <= xla_fusions // (5 if stage == "finish" else 1.5), fusions
+    assert compiled.memory_analysis().temp_size_in_bytes <= xla_temp
+
+
 def _mesh_scan(topo, compile_for, n, kernel):
     from tendermint_tpu.models.verifier import VerifierModel
 

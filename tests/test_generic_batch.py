@@ -70,7 +70,7 @@ def test_windowed_verdicts_equal_the_reference_and_counters_say_what_ran(batch, 
         k: after[k] - before[k]
         for k in ("generic_rows", "generic_pad_rows", "generic_windows", "generic_launches", "generic_kernel_rows")
     }
-    # the CPU lowers stage 2 to the XLA body: no row reaches the kernel form
+    # the CPU lowers the three stages to their XLA bodies: no row reaches the kernel form
     assert grew == {
         "generic_rows": 40, "generic_pad_rows": 8, "generic_windows": 2, "generic_launches": 3,
         "generic_kernel_rows": 0,

@@ -18,6 +18,7 @@ import jax.numpy as jnp  # noqa: E402
 from tendermint_tpu.ops import curve  # noqa: E402
 from tendermint_tpu.ops import ed25519 as E  # noqa: E402
 from tendermint_tpu.ops import field as F  # noqa: E402
+from tendermint_tpu.ops import field_kernel as FK  # noqa: E402
 from tendermint_tpu.ops import stage2_kernel as K  # noqa: E402
 
 L = F.LIMBS
@@ -321,7 +322,7 @@ def test_body_is_chosen_by_lowering_platform_and_shape():
     own lowering."""
     S, i32 = jax.ShapeDtypeStruct, jnp.int32
     table = lambda v: S((v, curve.SPLITS, 8, 3 * L), i32)  # noqa: E731
-    v = K.BLOCK_ROWS
+    v = FK.BLOCK_ROWS
     traced = jax.jit(E.verify_stage_scan_tabled_slots).trace(
         S((v, 32), i32), S((v, 64), i32), table(v), S((v,), jnp.bool_)
     )
@@ -368,7 +369,7 @@ def test_generic_body_is_chosen_by_lowering_platform_and_shape():
     generic_scan, lowered for a TPU, and none lowered for the CPU; at 16
     rows, lowered for a TPU, it IS the XLA body: the same StableHLO text
     as curve.double_scalar_mul_signed's own lowering."""
-    traced = jax.jit(E.verify_stage_scan).trace(*_scan_args(K.BLOCK_ROWS))
+    traced = jax.jit(E.verify_stage_scan).trace(*_scan_args(FK.BLOCK_ROWS))
     tpu = traced.lower(lowering_platforms=("tpu",)).as_text()
     assert tpu.count("tpu_custom_call") == 1 and "generic_scan" in tpu
     cpu = traced.lower(lowering_platforms=("cpu",)).as_text()
